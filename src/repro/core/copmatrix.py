@@ -51,10 +51,12 @@ arithmetic* -- the minimum over the locality classes any holder offers
 path's ``min(topo.weight(h, node) for h in holders)`` for arbitrary
 user-set class weights.
 
-An optional ``jax.jit`` twin of the winner reduction (``use_jax``)
-finally connects the scheduler half of the repo to its jax half: inputs
-are padded to the next power of two to bound recompilations and
-``jax_enable_x64`` is required (f32 would break tie parity).  A
+An optional ``jax.jit`` twin of the winner reduction (``use_jax``,
+:class:`JaxWinner`) finally connects the scheduler half of the repo to
+its jax half: inputs are padded to the next power of two to bound
+recompilations, and the device reduces order-preserving int64 keys under
+a call-scoped x64 setting (f32 would break tie parity, and the TPU's
+emulated f64 merges keys one ulp apart).  A
 ``lax.scan`` over whole task blocks is documented as impossible without
 breaking parity -- COP starts interleave with candidate masks and every
 probe consumes stateful RNG/COP ids -- so the jax path batches the same
@@ -65,6 +67,8 @@ imports fine, ``HAVE_NUMPY`` is False, and the scheduler keeps the
 per-task dict oracle.
 """
 from __future__ import annotations
+
+import functools
 
 from .types import NodeId
 
@@ -327,7 +331,7 @@ class BlockedDrainKernel:
         self._tier_key: tuple | None = None
         self._racks: "np.ndarray" | None = None
         self._sites: "np.ndarray" | None = None
-        self._winner_jit = _jax_winner() if use_jax else None
+        self.jax_winner = JaxWinner() if use_jax else None
 
     # ---------------------------------------------------------- per event
     def begin(self) -> None:
@@ -471,8 +475,8 @@ class BlockedDrainKernel:
             key = np.where(mask, tb - self.mx.pbytes[row].take(self._colv[:n]),
                            big)
         ids = cap._node_of[:n]
-        if self._winner_jit is not None:
-            return int(self._winner_jit(key, ids))
+        if self.jax_winner is not None:
+            return self.jax_winner(key, ids)
         # staged reduction, ordered like _greedy_uniform_vec: min key
         # first, then min node id among the ties -- exactly the dict
         # tuple-compare (cost, node)
@@ -494,45 +498,67 @@ class BlockedDrainKernel:
 
 
 # --------------------------------------------------------------- jax twin
-_JAX_WINNER = None
-
-
-def _jax_winner():
-    """Lazy jitted winner reduction (same staged min-key / min-id select).
-
-    Requires x64: the cost keys are float64 sums and f32 rounding would
-    merge ties the dict tuple-compare keeps apart.  Inputs are padded to
-    the next power of two (pad key = +inf / int64 max, pad id = int64 max)
-    so recompilation is bounded at one trace per (dtype, log2 size).
-    """
-    global _JAX_WINNER
+@functools.cache
+def _jax_select():
+    """Lazily built jitted staged reduction: min key, then min id among
+    the ties.  Traced and called under a scoped ``jax.enable_x64``."""
     import jax
-    # (re-)assert x64 even on the cached path: a caller may have restored
-    # the flag since the last kernel was built, and the jitted reduction
-    # would silently downcast the int64-max pad ids without it
-    jax.config.update("jax_enable_x64", True)
-    if _JAX_WINNER is not None:
-        return _JAX_WINNER
     import jax.numpy as jnp
 
     @jax.jit
-    def _select(key, ids):
+    def select(key, ids):
         m0 = key.min()
-        tie = key == m0
-        big = jnp.iinfo(jnp.int64).max
-        return jnp.where(tie, ids, big).min()
+        return jnp.where(key == m0, ids, jnp.iinfo(ids.dtype).max).min()
 
-    big = np.iinfo(np.int64).max
+    return select
 
-    def winner(key, ids):
+
+def ordered_int64(key: "np.ndarray") -> "np.ndarray":
+    """Map float64 keys to int64 keys with the same order and the same ties.
+
+    The TPU has no native float64: its emulated f64 ``min``/``==`` merge
+    keys one ulp apart, which breaks tie parity with the host oracle.  The
+    IEEE bit pattern of a non-NaN double, with the magnitude bits flipped
+    for negatives, is a signed integer of the same order; ``+ 0.0`` first
+    folds ``-0.0`` into ``+0.0`` so the two zeros stay one tie."""
+    bits = (key + 0.0).view(np.int64)
+    return np.where(bits < 0, bits ^ np.int64(0x7FFFFFFFFFFFFFFF), bits)
+
+
+class JaxWinner:
+    """Device twin of the staged winner reduction in
+    :meth:`BlockedDrainKernel.step2_winner`.
+
+    The device reduces int64 only: float keys go through
+    :func:`ordered_int64` on the host, so the result is exact on every
+    backend.  64-bit types are enabled for the call alone
+    (``jax.enable_x64`` as a context), leaving the process-wide flag as
+    it was.  Inputs are padded to the next power of two (pad key and pad
+    id = int64 max), bounding recompilation at one trace per log2 size.
+
+    ``dispatches`` counts device calls and ``platforms`` holds the
+    platform of every result, so a run can show that the reduction really
+    ran on the accelerator.
+    """
+
+    def __init__(self) -> None:
+        import jax
+        self._enable_x64 = jax.enable_x64
+        self._select = _jax_select()
+        self.dispatches = 0
+        self.platforms: set[str] = set()
+
+    def __call__(self, key: "np.ndarray", ids: "np.ndarray") -> int:
+        if key.dtype.kind == "f":
+            key = ordered_int64(key)
         n = len(key)
         padded = 1 << max(0, (n - 1).bit_length())
         if padded != n:
-            pad = padded - n
-            fill = np.inf if key.dtype.kind == "f" else big
-            key = np.concatenate([key, np.full(pad, fill, dtype=key.dtype)])
-            ids = np.concatenate([ids, np.full(pad, big, dtype=ids.dtype)])
-        return int(_select(key, ids))
-
-    _JAX_WINNER = winner
-    return winner
+            big = np.iinfo(np.int64).max
+            key = np.concatenate([key, np.full(padded - n, big, np.int64)])
+            ids = np.concatenate([ids, np.full(padded - n, big, np.int64)])
+        with self._enable_x64(True):
+            out = self._select(key, ids)
+        self.dispatches += 1
+        self.platforms.update(d.platform for d in out.devices())
+        return int(out)

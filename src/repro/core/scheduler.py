@@ -137,7 +137,7 @@ class WowScheduler:
         self.vectorized = bool(vectorized)
         # batched step-2/3 drain (DESIGN.md "Batched COP drain"): None =
         # auto (on exactly when the node state is vectorized), "jax" = the
-        # jitted winner-reduction twin (requires jax + x64).  The per-task
+        # jitted winner-reduction twin (copmatrix.JaxWinner).  The per-task
         # dict machinery is the retained oracle; decisions are bit-identical
         # either way (property-tested in tests/test_copmatrix.py).
         if batched is None:
@@ -376,6 +376,16 @@ class WowScheduler:
     def solver_stats(self) -> dict:
         """Counters/timings of the incremental step-1 solver (benchmarks)."""
         return self._solver.stats
+
+    @property
+    def device_stats(self) -> dict:
+        """``{"dispatches", "platforms"}`` of the jax winner twin
+        (``batched="jax"``); empty when the twin is off."""
+        twin = self._kernel.jax_winner if self._kernel is not None else None
+        if twin is None:
+            return {}
+        return {"dispatches": twin.dispatches,
+                "platforms": sorted(twin.platforms)}
 
     def _refresh_candidates(self) -> tuple[set[int], set[int]]:
         """Recompute cached start candidates for exactly the dirty tasks.

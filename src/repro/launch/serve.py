@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from ..configs import ARCHS, get_config, get_smoke
 from ..models import Model
+from .compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -24,6 +25,7 @@ def main() -> None:
     ap.add_argument("--gen", type=int, default=16)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     model = Model(cfg)
     params = model.init(jax.random.PRNGKey(0))

@@ -9,6 +9,7 @@ import argparse
 
 from ..configs import ARCHS, get_config, get_smoke
 from ..runtime import TrainConfig, Trainer
+from .compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -25,6 +26,7 @@ def main() -> None:
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     tcfg = TrainConfig(batch=args.batch, seq_len=args.seq, steps=args.steps,
                        microbatches=args.microbatches,

@@ -89,18 +89,6 @@ def _moe_expert_parallel_a2a(params, x, cfg: ArchConfig, mesh):
     returns results -- data moves to compute (the paper's insight on-chip),
     two a2a's per layer instead of replicated-token psums."""
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-
-        def smap(f, in_specs, out_specs):
-            return shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    except (ImportError, TypeError):
-        from jax.experimental.shard_map import shard_map as _sm
-
-        def smap(f, in_specs, out_specs):
-            return _sm(f, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
 
     b, s, d = x.shape
     e, k = cfg.n_experts, cfg.top_k
@@ -163,12 +151,12 @@ def _moe_expert_parallel_a2a(params, x, cfg: ArchConfig, mesh):
         y = y_tok.reshape(bl, sl, k, d).sum(axis=2)
         return y, jax.lax.pmean(aux, "model")
 
-    fn = smap(
-        shard_fn,
+    fn = jax.shard_map(
+        shard_fn, mesh=mesh,
         in_specs=(P(bspec, "model", None), P(None, None),
                   P("model", None, None), P("model", None, None),
                   P("model", None, None)),
-        out_specs=(P(bspec, "model", None), P()),
+        out_specs=(P(bspec, "model", None), P()), check_vma=False,
     )
     return fn(x, params["router"].astype(jnp.float32), params["w_in"],
               params["w_gate"], params["w_out"])
@@ -176,18 +164,6 @@ def _moe_expert_parallel_a2a(params, x, cfg: ArchConfig, mesh):
 
 def _moe_expert_parallel(params, x, cfg: ArchConfig, mesh):
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-
-        def smap(f, in_specs, out_specs):
-            return shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    except (ImportError, TypeError):
-        from jax.experimental.shard_map import shard_map as _sm
-
-        def smap(f, in_specs, out_specs):
-            return _sm(f, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
 
     b, s, d = x.shape
     e, k = cfg.n_experts, cfg.top_k
@@ -243,12 +219,12 @@ def _moe_expert_parallel(params, x, cfg: ArchConfig, mesh):
         y = jax.lax.psum(y, "model")
         return y, jax.lax.pmean(aux, "model")
 
-    fn = smap(
-        shard_fn,
+    fn = jax.shard_map(
+        shard_fn, mesh=mesh,
         in_specs=(P(bspec, None, None), P(None, None),
                   P("model", None, None), P("model", None, None),
                   P("model", None, None)),
-        out_specs=(P(bspec, None, None), P()),
+        out_specs=(P(bspec, None, None), P()), check_vma=False,
     )
     return fn(x, params["router"].astype(jnp.float32), params["w_in"],
               params["w_gate"], params["w_out"])

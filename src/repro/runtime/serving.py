@@ -13,6 +13,7 @@ the CPU smoke configs (tests) and shards like serve_step at scale.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import heapq
 
 import jax
@@ -51,6 +52,8 @@ class ServingEngine:
         self.max_len = max_len
         self.cache = self.model.init_decode_cache(slots, max_len)
         from ..launch.steps import make_serve_step
+        self._prefill = jax.jit(functools.partial(self.model.prefill,
+                                                  pad_to=max_len))
         self._decode = jax.jit(make_serve_step(self.model))
         self._queue: list[Request] = []
         self._active: dict[int, dict] = {}      # slot -> request state
@@ -111,8 +114,7 @@ class ServingEngine:
             # the batch cache at `slot` (the COP analogue: preparing the
             # slot overlaps with other slots' decoding at engine level)
             batch = {"tokens": jnp.asarray(req.prompt[None, :])}
-            logits, cache1 = self.model.prefill(self.params, batch,
-                                                pad_to=self.max_len)
+            logits, cache1 = self._prefill(self.params, batch)
             self._splice(slot, cache1)
             first = int(np.asarray(jnp.argmax(logits, -1))[0])
             self._last_tok[slot, 0] = first

@@ -16,8 +16,8 @@ Layers of proof that ``batched=True`` changes nothing but speed:
   with churn (failure + elastic join), under a hierarchical topology, and
   against the frozen reference core; plus a randomized property sweep.
 * **jax twin** -- the jitted winner reduction picks the same nodes as the
-  staged numpy reduction (skipped when jax is unavailable; the x64 flag it
-  requires is restored afterwards).
+  staged numpy reduction, one-ulp near ties included, and leaves the
+  process-wide x64 flag unchanged (skipped when jax is unavailable).
 """
 from __future__ import annotations
 
@@ -277,30 +277,80 @@ def test_blocked_parity_property(seed):
 
 # ----------------------------------------------------------------- jax twin
 def test_jax_winner_matches_numpy():
+    """Whole-sim parity of the jax twin, which must also leave the
+    process-wide x64 flag as it found it (x64 is scoped to each call)."""
     jax = pytest.importorskip("jax")
-    prev_x64 = jax.config.jax_enable_x64
-    try:
-        a = _sim_run(True, workflow="group", scale=0.4, n_nodes=10)
-        b = _sim_run("jax", workflow="group", scale=0.4, n_nodes=10)
-        assert a == b
-    finally:
-        jax.config.update("jax_enable_x64", prev_x64)
+    from repro.sim import SimConfig, Simulation
+    from repro.workloads import make_workflow
+
+    before = jax.config.jax_enable_x64
+    a = _sim_run(True, workflow="group", scale=0.4, n_nodes=10)
+    b = _sim_run("jax", workflow="group", scale=0.4, n_nodes=10)
+    assert a == b
+    assert jax.config.jax_enable_x64 == before
+    sim = Simulation(make_workflow("group", scale=0.4),
+                     SimConfig(n_nodes=10, batched="jax"), "wow")
+    sim.run()
+    stats = sim.strategy.sched.device_stats
+    assert stats["dispatches"] > 0
+    assert stats["platforms"] == [jax.devices()[0].platform]
+    assert jax.config.jax_enable_x64 == before
+
+
+def _winner_oracle(key, ids):
+    import numpy as np
+    m0 = key.min()
+    return int(np.where(key == m0, ids, np.iinfo(np.int64).max).min())
 
 
 def test_jax_winner_padding_unit():
-    jax = pytest.importorskip("jax")
+    pytest.importorskip("jax")
     import numpy as np
-    from repro.core.copmatrix import _jax_winner
-    prev_x64 = jax.config.jax_enable_x64
-    try:
-        winner = _jax_winner()
-        rng = np.random.default_rng(0)
-        big = np.iinfo(np.int64).max
-        for n in (1, 3, 7, 16, 33):
-            key = rng.integers(0, 5, n).astype(np.float64)
-            ids = rng.permutation(n).astype(np.int64)
-            m0 = key.min()
-            expect = int(np.where(key == m0, ids, big).min())
-            assert winner(key, ids) == expect
-    finally:
-        jax.config.update("jax_enable_x64", prev_x64)
+    from repro.core.copmatrix import JaxWinner
+    winner = JaxWinner()
+    rng = np.random.default_rng(0)
+    for n in (1, 3, 7, 16, 33):
+        key = rng.integers(0, 5, n).astype(np.float64)
+        ids = rng.permutation(n).astype(np.int64)
+        assert winner(key, ids) == _winner_oracle(key, ids)
+        ikey = rng.integers(0, 5, n).astype(np.int64)
+        assert winner(ikey, ids) == _winner_oracle(ikey, ids)
+    assert winner.dispatches == 10
+
+
+def test_jax_winner_near_ties():
+    """Keys one ulp apart stay apart and exact ties split by id -- the
+    cases the TPU's emulated f64 comparison got wrong."""
+    pytest.importorskip("jax")
+    import numpy as np
+    from repro.core.copmatrix import JaxWinner
+    winner = JaxWinner()
+    rng = np.random.default_rng(1)
+    for trial in range(20):
+        n = 64
+        base = rng.uniform(1e9, 1e11)
+        key = np.full(n, 3 * base)
+        idx = rng.choice(n, 6, replace=False)
+        key[idx[:3]] = base
+        key[idx[3:]] = np.nextafter(base, np.inf)
+        if trial % 2:
+            key[idx[0]] = np.nextafter(base, -np.inf)
+        key[rng.choice(n, 8, replace=False)] = np.inf
+        ids = rng.permutation(n).astype(np.int64)
+        assert winner(key, ids) == _winner_oracle(key, ids)
+
+
+def test_ordered_int64_keeps_order_and_ties():
+    import numpy as np
+    from repro.core.copmatrix import ordered_int64
+    rng = np.random.default_rng(2)
+    base = rng.normal(size=200) * 10.0 ** rng.integers(-5, 12, 200)
+    vals = np.concatenate([
+        base, np.nextafter(base, np.inf), np.nextafter(base, -np.inf),
+        [0.0, -0.0, np.inf, -np.inf, 5e-324, -5e-324, 1.0, 1.0]])
+    ik = ordered_int64(vals)
+    assert ik.dtype == np.int64
+    assert ((ik[:, None] < ik[None, :])
+            == (vals[:, None] < vals[None, :])).all()
+    assert ((ik[:, None] == ik[None, :])
+            == (vals[:, None] == vals[None, :])).all()
