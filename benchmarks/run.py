@@ -12,10 +12,7 @@ framework-side benches.  Prints ``name,...`` CSV lines and collects every
 top-``--profile-top`` functions by cumulative time to
 ``BENCH_profile.json`` (picked up by the report aggregator like every
 other ``BENCH_*.json``), so "what is the top non-fill cost now?" is one
-flag away instead of an ad-hoc script.  The same flag also appends the
-per-arrival scheduler-churn counters (``traffic_churn`` rows: dirty-set
-sizes, solver events and flow recomputes per arrival from a small
-multi-tenant run per strategy).
+flag away instead of an ad-hoc script.
 """
 from __future__ import annotations
 
@@ -68,35 +65,6 @@ def profile_call(name: str, fn, top_n: int = 15) -> list[dict]:
         rows.append({"scenario": name, "function": loc, "ncalls": nc,
                      "tottime_s": round(tt, 4), "cumtime_s": round(ct, 4)})
         print(f"profile,{name},{nc},{tt:.3f},{ct:.3f},{loc}")
-    return rows
-
-
-def traffic_churn_profile() -> list[dict]:
-    """Per-arrival scheduler-churn counters (cross-workflow dirty-set
-    sizes, cumulative solver events, flow recomputes per arrival) from one
-    small multi-tenant run per strategy -- the engine-side complement to
-    the cProfile rows, surfaced by the same ``--profile`` flag."""
-    from repro.sim import run_traffic
-
-    from .scheduler_scale import MT_SMOKE_SIZES, _mt_traffic
-
-    n_nodes = MT_SMOKE_SIZES[0]
-    rows: list[dict] = []
-    print("profile,traffic_churn,strategy,arrivals_sampled,"
-          "dirty_tasks_mean,dirty_tasks_max,solver_events_per_arrival,"
-          "flow_recomputes_per_arrival")
-    for strat in ("orig", "cws", "wow"):
-        _, tres = run_traffic(_mt_traffic(n_nodes), strategy=strat,
-                              n_nodes=n_nodes, dfs="ceph")
-        churn = {k: v for k, v in tres.churn.items() if k != "samples"}
-        rows.append({"scenario": "traffic_churn", "strategy": strat,
-                     "nodes": n_nodes, **churn})
-        print(f"profile,traffic_churn,{strat},"
-              f"{churn.get('arrivals_sampled', 0)},"
-              f"{churn.get('dirty_tasks_mean', '')},"
-              f"{churn.get('dirty_tasks_max', '')},"
-              f"{churn.get('solver_events_per_arrival', '')},"
-              f"{churn.get('flow_recomputes_per_arrival', '')}")
     return rows
 
 
@@ -257,8 +225,6 @@ def main() -> None:
         run_scenario("kernels", km)
     if want("roofline"):
         roofline_summary()
-    if args.profile:
-        profile_rows.extend(traffic_churn_profile())
     if args.profile and profile_rows:
         from .common import write_json
         write_json("profile", {

@@ -15,7 +15,9 @@ Each measurement separates two phases: the **step-1 solver**
 (``solver_ms_per_iter`` / ``cold_solver_ms``, plus the indexed solver's own
 counters) and **steps 2-3** (``step23_ms_per_iter`` -- the COP-placement /
 speculative-ordering share this PR's indexed ready set targets).  The
-incremental scheduler reports its own ``phase_s`` clocks; the frozen
+incremental scheduler's phases are its own ``repro.core.trace`` spans
+(``sched.step1.solve``, ``sched.step2`` + ``sched.step3``; each measuring
+function switches the trace on); the frozen
 reference scheduler is measured by temporarily wrapping its ``solve``
 symbol and step-2/3 methods.
 
@@ -90,7 +92,7 @@ import time
 import repro.core.reference as _reference
 from repro.core import (HAVE_NUMPY, DataPlacementService, FileSpec,
                         NodeState, ReferenceWowScheduler, StartTask,
-                        TaskSpec, WowScheduler)
+                        TaskSpec, WowScheduler, trace)
 
 from .common import emit, write_json
 
@@ -156,15 +158,36 @@ def _timed_reference_steps23():
         ReferenceWowScheduler._step3_speculative_prepare = orig3
 
 
+@contextlib.contextmanager
+def _traced():
+    """The program's trace on for the block or decorated function (and off
+    again after, if it was off): the indexed scheduler's phase times are
+    its spans."""
+    was_on = trace.on
+    if not was_on:
+        trace.enable()
+    try:
+        yield
+    finally:
+        if not was_on:
+            trace.disable()
+
+
+def _span_seconds(*names: str) -> float:
+    """Seconds in the named trace spans since the trace was enabled."""
+    totals = trace.totals()
+    return sum(totals.get(n, 0.0) for n in names)
+
+
 def _solver_seconds(sched, acc) -> float:
     if isinstance(sched, WowScheduler):
-        return sched.solver_stats["solve_s"]
+        return _span_seconds("sched.step1.solve")
     return acc["s"]
 
 
 def _step23_seconds(sched, acc23) -> float:
     if isinstance(sched, WowScheduler):
-        return sched.phase_s["step23_s"]
+        return _span_seconds("sched.step2", "sched.step3")
     return acc23["s"]
 
 
@@ -216,16 +239,20 @@ def drive_event(sched, dps, rng, n_nodes: int, next_id: int,
     return sched.schedule()
 
 
+@_traced()
 def run_cold(n_nodes: int, n_ready: int, cls, seed: int = 0):
     """Returns (total ms, solver ms, #actions) for one cold schedule()."""
     sched, _, _ = build(n_nodes, n_ready, cls, seed)
     with _timed_reference_solver() as acc:
+        solver_s0 = _solver_seconds(sched, acc)
         t0 = time.perf_counter()
         actions = sched.schedule()
         total_ms = (time.perf_counter() - t0) * 1000
-    return total_ms, _solver_seconds(sched, acc) * 1000, len(actions)
+        solver_ms = (_solver_seconds(sched, acc) - solver_s0) * 1000
+    return total_ms, solver_ms, len(actions)
 
 
+@_traced()
 def run_sustained(n_nodes: int, n_ready: int, cls, iters: int,
                   seed: int = 0, inputless: bool = False) -> dict:
     """Warm scheduler, then `iters` event rounds: finish one task, finish
@@ -618,11 +645,12 @@ def run_e2e_vectorized(sizes: list[tuple[int, float]] | None = None,
 # ``masked`` (vectorized hot state, per-task loop -- the pre-kernel
 # production path, isolating this PR's gain from the earlier cap-array
 # PR's), and ``per_task`` (vectorized=False -- the dict oracle the kernel
-# is property-tested against).  ``phase_s["step23_s"]`` is directly
-# comparable across them; every schedule() round's action stream is
-# summarized and asserted bit-identical, flat *and* under a multi-site
-# topology (the locality-cost kernel branch, where the dict path pays a
-# per-candidate ``locality_missing_cost`` call).  ``BENCH_JAX=1`` adds the
+# is property-tested against).  The ``sched.step2`` + ``sched.step3``
+# trace spans are directly comparable across them; every schedule()
+# round's action stream is summarized and asserted bit-identical, flat
+# *and* under a multi-site topology (the locality-cost kernel branch,
+# where the dict path pays a per-candidate ``locality_missing_cost``
+# call).  ``BENCH_JAX=1`` adds the
 # jit-compiled winner reduction as a fourth impl (identity asserted, no
 # speedup claim -- jit dispatch only pays off on accelerators).  Full tier
 # asserts the blocked kernel's step-2/3 phase is >= ``_BATCHED_MIN_SPEEDUP``x
@@ -692,6 +720,7 @@ def _bd_wave(sched, dps, rng, n_nodes: int, next_id: int, fid: int):
     return sched.schedule(), next_id, fid
 
 
+@_traced()
 def run_batched_drain(sizes: list[tuple[int, int]] | None = None,
                       ) -> tuple[list[dict], dict]:
     smoke = bench_smoke()
@@ -712,9 +741,11 @@ def run_batched_drain(sizes: list[tuple[int, int]] | None = None,
                 sched, dps, rng, fid = _bd_build(n_nodes, n_ready, vec,
                                                  batched, params)
                 next_id = n_ready
+                s0 = _span_seconds("sched.step2", "sched.step3")
                 t0 = time.perf_counter()
                 summaries = [_summarize(sched.schedule())]
-                cold_ms = sched.phase_s["step23_s"] * 1000
+                cold_ms = (_span_seconds("sched.step2", "sched.step3")
+                           - s0) * 1000
                 actions = 0
                 for _ in range(BD_WAVES):
                     acts, next_id, fid = _bd_wave(sched, dps, rng,
@@ -723,7 +754,8 @@ def run_batched_drain(sizes: list[tuple[int, int]] | None = None,
                     actions += len(acts)
                 wall_ms = ((time.perf_counter() - t0) * 1000
                            / (BD_WAVES + 1))
-                s23_ms = sched.phase_s["step23_s"] * 1000
+                s23_ms = (_span_seconds("sched.step2", "sched.step3")
+                          - s0) * 1000
                 streams[impl] = summaries
                 step23[(n_nodes, topo_name, impl)] = s23_ms
                 rows.append({"impl": impl, "scenario": "batched_drain",
@@ -1007,10 +1039,6 @@ def run_multi_tenant(sizes: list[int] | None = None,
                 "queue_depth_max": tres.queue_depth_max,
                 "queue_depth_mean": tres.queue_depth_mean,
                 "horizon": tres.horizon,
-                # per-arrival scheduler-churn profile (dirty sets + solver /
-                # flow recompute counters); raw samples dropped: rows lean
-                "churn": {k: v for k, v in tres.churn.items()
-                          if k != "samples"},
                 "per_tenant": {t: {k: d[k] for k in
                                    ("admitted", "rejected", "completed",
                                     "p99", "starved", "service_cpu_s")}
@@ -1079,6 +1107,7 @@ def _reset_cluster(sched: WowScheduler) -> None:
         sched._dirty_nodes.add(n)
 
 
+@_traced()
 def run_live_rm(n_nodes: int = 12, bursts: int = 5,
                 storms: int = 6, hot_pool: int = 8, seed: int = 0) -> dict:
     """Measure the ``strict_parity=False`` B&B warm start on *real* bursty
@@ -1142,11 +1171,11 @@ def run_live_rm(n_nodes: int = 12, bursts: int = 5,
             for s_i in range(storms):
                 ev = b * storms + s_i
                 _drift_node(sched, ev % hot_pool, 16.0 - 1e-9 * (ev + 1))
-                s0 = sched.solver_stats["solve_s"]
+                s0 = _span_seconds("sched.step1.solve")
                 t0 = time.perf_counter()
                 actions = sched.schedule()
                 sched_s += time.perf_counter() - t0
-                solver_s += sched.solver_stats["solve_s"] - s0
+                solver_s += _span_seconds("sched.step1.solve") - s0
                 starts = [a for a in actions if isinstance(a, StartTask)]
                 objs.append(sum(specs[a.task_id].priority for a in starts))
                 backlog_max = max(backlog_max,

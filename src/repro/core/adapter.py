@@ -146,12 +146,6 @@ class RuntimeAdapter:
         still live (queued or running) or never seen are no-ops."""
         pass
 
-    def churn_probe(self) -> dict:
-        """Cheap snapshot of scheduler-internal churn counters, sampled by
-        the engine after each traffic arrival (dirty-set / solver-activity
-        profiling).  DFS-bound baselines have no incremental core: empty."""
-        return {}
-
     # ------------------------------------------------------------ helpers
     def _known(self, task_id: int) -> bool:
         """Shared unknown-id guard: does ``task_id`` name an outstanding or
@@ -338,21 +332,6 @@ class WowAdapter(RuntimeAdapter):
 
     def _known(self, task_id: int) -> bool:
         return task_id in self.sched.running
-
-    def churn_probe(self) -> dict:
-        """Dirty-set sizes + cumulative solver event counter.  The
-        reference core keeps no dirty sets or solver stats
-        (getattr-guarded).  Counters only -- no wall-clock timings, so the
-        probe is replay-deterministic (bit-identical TrafficResults)."""
-        probe = {
-            "dirty_tasks": (
-                len(getattr(self.sched, "_dirty_tasks", ()))
-                + len(self.dps._dirty_tasks)),
-        }
-        stats = getattr(self.sched, "solver_stats", None)
-        if stats:
-            probe["solver_events"] = stats.get("events", 0)
-        return probe
 
 
 def make_adapter(name: str, nodes: dict[int, NodeState], *, c_node: int = 1,

@@ -58,7 +58,9 @@ equivalence tests check the indices against.
 from __future__ import annotations
 
 import random
+from operator import attrgetter
 
+from . import trace
 from .types import CopPlan, FileSpec, NodeId, Transfer
 
 # Equal weights for the two price components (§III-C).
@@ -88,6 +90,9 @@ class DataPlacementService:
         self._node_order = node_order
         # total bytes moved through COPs, for the Fig.4 overhead metric
         self.cop_bytes_total = 0
+        # replica additions and removals through the two choke points
+        self.replica_writes = 0
+        trace.counter("dps.replica_writes", self, attrgetter("replica_writes"))
         # ----- reverse indices (see module docstring)
         self._node_files: dict[NodeId, set[int]] = {}
         self._waiting: dict[int, set[int]] = {}
@@ -211,6 +216,7 @@ class DataPlacementService:
         locs = self._locations.setdefault(file_id, set())
         if node in locs:
             return
+        self.replica_writes += 1
         locs.add(node)
         self._node_files.setdefault(node, set()).add(file_id)
         if self._src_active and node in self._free_src:
@@ -237,6 +243,7 @@ class DataPlacementService:
         locs = self._locations.get(file_id)
         if locs is None or node not in locs:
             return
+        self.replica_writes += 1
         locs.discard(node)
         held = self._node_files.get(node)
         if held is not None:

@@ -60,10 +60,10 @@ tests compare against.
 from __future__ import annotations
 
 import dataclasses
-import time
 from collections import OrderedDict
 from typing import Iterable, Mapping
 
+from . import trace
 from .types import NodeState, TaskSpec
 
 # Budget of B&B nodes before falling back to greedy.  Exact instances in the
@@ -188,50 +188,58 @@ def solve_exact(problem: AssignmentProblem,
         for i in range(len(tasks) - 1, -1, -1):
             if tasks[i].id in best_assign:
                 best_val = best_val + tasks[i].priority
+    # Depth-first search over (task index, value) nodes, visited in the
+    # order of a recursion that tries each feasible prepared node (most
+    # free first: it helps the bound) and then skipping the task.  The
+    # loop keeps its own stack and calls no Python function per node:
+    # CPython 3.11+ allocates a frame-stack chunk when a call crosses a
+    # chunk boundary and frees it when the call returns, so a recursion
+    # oscillating across a boundary paid that on every call, and whether
+    # it did depended only on how deep the caller's stack happened to be.
+    prepared = p.prepared
+    n_tasks = len(tasks)
     cur_assign: dict[int, int] = {}
+    stack: list[list] = []          # [task index, value, candidates, next]
     visited = 0
-    aborted = False
-
-    def rec(i: int, val: float) -> None:
-        nonlocal best_val, best_assign, visited, aborted
-        if aborted:
-            return
-        visited += 1
-        if visited > node_budget:
-            aborted = True
-            return
-        if val + suffix[i] <= best_val:
-            return  # cannot beat incumbent
-        if i == len(tasks):
-            if val > best_val:
-                best_val = val
-                best_assign = dict(cur_assign)
-            return
+    i, val, enter = 0, 0.0, True    # enter: visit node (i, val) first
+    while True:
+        if enter:
+            visited += 1
+            if visited > node_budget:
+                return None
+            if val + suffix[i] > best_val:      # else: cannot beat incumbent
+                if i == n_tasks:
+                    if val > best_val:
+                        best_val = val
+                        best_assign = dict(cur_assign)
+                else:
+                    t = tasks[i]
+                    keys = sorted([(-free_cores[n], -free_mem[n], n)
+                                   for n in prepared[t.id]
+                                   if free_mem[n] >= t.mem
+                                   and free_cores[n] >= t.cores])
+                    stack.append([i, val, [k[2] for k in keys], 0])
+        if not stack:
+            return best_assign
+        frame = stack[-1]
+        i, val, cands, nxt = frame
+        enter = False
         t = tasks[i]
-        # branch: assign to each feasible prepared node (greedy order helps
-        # the bound: most-free node first)
-        cands = sorted(
-            (n for n in p.prepared[t.id]
-             if free_mem[n] >= t.mem and free_cores[n] >= t.cores),
-            key=lambda n: (-(free_cores[n]), -(free_mem[n]), n),
-        )
-        for n in cands:
-            free_mem[n] -= t.mem
-            free_cores[n] -= t.cores
-            cur_assign[t.id] = n
-            rec(i + 1, val + t.priority)
+        if nxt:                     # the subtree under cands[nxt - 1] is done
+            n = cands[nxt - 1]
             del cur_assign[t.id]
             free_mem[n] += t.mem
             free_cores[n] += t.cores
-            if aborted:
-                return
-        # branch: skip the task
-        rec(i + 1, val)
-
-    rec(0, 0.0)
-    if aborted:
-        return None
-    return best_assign
+        if nxt < len(cands):        # branch: assign to the next candidate
+            n = cands[nxt]
+            frame[3] = nxt + 1
+            free_mem[n] -= t.mem
+            free_cores[n] -= t.cores
+            cur_assign[t.id] = n
+            i, val, enter = i + 1, val + t.priority, True
+        else:                       # branch: skip the task
+            stack.pop()
+            i, enter = i + 1, True
 
 
 def solve_greedy(problem: AssignmentProblem) -> dict[int, int]:
@@ -552,12 +560,15 @@ class IncrementalAssignmentSolver:
         self._task_comp: dict[int, int] = {}
         self._node_comp: dict[int, int] = {}
         self._next_cid = 0
-        self.stats: dict[str, float] = {
+        self.stats: dict[str, int] = {
             "events": 0, "comps_rebuilt": 0, "comps_reused": 0,
             "cache_hits": 0, "cache_misses": 0, "exact_solves": 0,
             "greedy_solves": 0, "budget_aborts": 0, "warm_seeds": 0,
-            "solve_s": 0.0,
         }
+        for name, key in (("step1.comps_resolved", "comps_rebuilt"),
+                          ("step1.cache_hits", "cache_hits"),
+                          ("step1.cache_misses", "cache_misses")):
+            trace.counter(name, self, lambda s, key=key: s.stats[key])
 
     # ------------------------------------------------------------ event API
     def solve_event(self, tasks: Mapping[int, TaskSpec],
@@ -573,12 +584,12 @@ class IncrementalAssignmentSolver:
         order inside each component, which is what makes decomposed results
         identical to a monolithic solve over the same instance.
         """
-        t0 = time.perf_counter()
-        try:
-            return self._solve_event(tasks, candidates, seq,
-                                     dirty_tasks, dirty_nodes)
-        finally:
-            self.stats["solve_s"] += time.perf_counter() - t0
+        if trace.on:
+            with trace.span("sched.step1.solve"):
+                return self._solve_event(tasks, candidates, seq,
+                                         dirty_tasks, dirty_nodes)
+        return self._solve_event(tasks, candidates, seq,
+                                 dirty_tasks, dirty_nodes)
 
     def _solve_event(self, tasks, candidates, seq, dirty_tasks, dirty_nodes):
         self.stats["events"] += 1

@@ -88,8 +88,9 @@ tie-equivalent optimum -- see DESIGN.md "Step-1 solver".
 """
 from __future__ import annotations
 
-import time
+from operator import attrgetter
 
+from . import trace
 from .dps import DataPlacementService
 from .ilp import (AssignmentProblem, FingerprintCache,
                   IncrementalAssignmentSolver, component_fingerprint,
@@ -167,10 +168,9 @@ class WowScheduler:
         self.cops_created: int = 0
         self.tasks_started: int = 0
         self.declines: int = 0
-        # per-phase wall time (benchmarks): step 1 overall, its input-less
-        # share, and steps 2-3 together
-        self.phase_s: dict[str, float] = {
-            "step1_s": 0.0, "inputless_s": 0.0, "step23_s": 0.0}
+        self.drain_probed: int = 0      # ready tasks steps 2-3 visited
+        trace.counter("drain.cops_started", self, attrgetter("cops_created"))
+        trace.counter("drain.tasks_probed", self, attrgetter("drain_probed"))
 
         # ----- incremental state (see module docstring)
         self._seq = 0
@@ -362,19 +362,23 @@ class WowScheduler:
     # ---------------------------------------------------------------- steps
     def schedule(self) -> list[Action]:
         actions: list[Action] = []
-        t0 = time.perf_counter()
-        started = self._step1_start_prepared(actions)
-        t1 = time.perf_counter()
-        self._step2_prepare_for_free_compute(actions, started)
-        self._step3_speculative_prepare(actions)
-        t2 = time.perf_counter()
-        self.phase_s["step1_s"] += t1 - t0
-        self.phase_s["step23_s"] += t2 - t1
+        if not trace.on:
+            started = self._step1_start_prepared(actions)
+            self._step2_prepare_for_free_compute(actions, started)
+            self._step3_speculative_prepare(actions)
+            return actions
+        with trace.span("sched.step1"):
+            started = self._step1_start_prepared(actions)
+        with trace.span("sched.step2"):
+            self._step2_prepare_for_free_compute(actions, started)
+        with trace.span("sched.step3"):
+            self._step3_speculative_prepare(actions)
+        trace.end_round()
         return actions
 
     @property
     def solver_stats(self) -> dict:
-        """Counters/timings of the incremental step-1 solver (benchmarks)."""
+        """Counters of the incremental step-1 solver (benchmarks)."""
         return self._solver.stats
 
     @property
@@ -681,7 +685,11 @@ class WowScheduler:
 
     # Step 1: assign ready tasks to prepared nodes via the incremental ILP.
     def _step1_start_prepared(self, actions: list[Action]) -> set[int]:
-        dirty_tasks, dirty_nodes = self._refresh_candidates()
+        if trace.on:
+            with trace.span("sched.step1.refresh"):
+                dirty_tasks, dirty_nodes = self._refresh_candidates()
+        else:
+            dirty_tasks, dirty_nodes = self._refresh_candidates()
         stale = len(self._less_index) > 0 and self._less_stale
         less_cands: dict[int, list[int]] = {}
         if stale and self._startable:
@@ -689,13 +697,15 @@ class WowScheduler:
             # compete for the same capacity -- expand the full candidate
             # dict (O(fitting backlog), rare) and solve jointly (the
             # pre-fast-path behaviour) so decisions stay bit-exact.
-            t0 = time.perf_counter()
-            less_cands = self._inputless_candidates()
+            if trace.on:
+                with trace.span("sched.step1.inputless"):
+                    less_cands = self._inputless_candidates()
+            else:
+                less_cands = self._inputless_candidates()
             self._less_stale = False
-            self.phase_s["inputless_s"] += time.perf_counter() - t0
         if less_cands:
             # joint time is inherently unsplittable and counts as solver
-            # time, not inputless_s
+            # time (sched.step1.solve), not sched.step1.inputless
             self.inputless_stats["joint_events"] += 1
             assign = self._solver.solve_event(
                 self.ready, {**self._startable, **less_cands},
@@ -708,10 +718,12 @@ class WowScheduler:
                 self.ready, self._startable, self._submit_seq,
                 dirty_tasks, dirty_nodes)
             if stale and not self._startable:
-                t0 = time.perf_counter()
-                extra = self._solve_inputless()
+                if trace.on:
+                    with trace.span("sched.step1.inputless"):
+                        extra = self._solve_inputless()
+                else:
+                    extra = self._solve_inputless()
                 self._less_stale = False
-                self.phase_s["inputless_s"] += time.perf_counter() - t0
                 if extra:
                     assign = dict(assign)
                     assign.update(extra)
@@ -805,9 +817,11 @@ class WowScheduler:
         kern = self._kernel
         if kern is not None:
             kern.begin()
+        probed = 0
         for tid in self._ready_index.step2_order():
             if not self._free_slot_nodes:
                 break               # no COP can start or source anywhere
+            probed += 1
             t = self.ready[tid]
             if not self._task_cop_budget(tid):
                 continue
@@ -838,6 +852,7 @@ class WowScheduler:
                 # through to the per-task oracle (re-probing the winner is
                 # harmless, infeasible probes are side-effect-free)
             self._step2_probe_task(tid, t, feas, pool, actions)
+        self.drain_probed += probed
 
     def _step2_probe_task(self, tid: int, t: TaskSpec, feas, pool,
                           actions: list[Action]) -> None:
@@ -896,9 +911,11 @@ class WowScheduler:
         kern = self._kernel
         if kern is not None:
             kern.begin()
+        probed = 0
         for tid in self._ready_index.step3_order():
             if not self._free_slot_nodes:
                 break
+            probed += 1
             if not self._task_cop_budget(tid):
                 continue
             t = self.ready[tid]
@@ -944,3 +961,4 @@ class WowScheduler:
                     best = plan
             if best is not None:
                 self._start_cop(best, actions)
+        self.drain_probed += probed

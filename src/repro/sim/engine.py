@@ -16,9 +16,11 @@ from __future__ import annotations
 import dataclasses
 import heapq
 import math
+from operator import attrgetter
 
 from ..core import (DFS_LOC, FileSpec, NodeOrder, NodeState, StartCop,
-                    StartTask, TaskSpec, abstract_ranks, assign_priorities)
+                    StartTask, TaskSpec, abstract_ranks, assign_priorities,
+                    trace)
 from ..core.types import CopPlan
 from .dfs import CephModel, DfsModel, NfsModel
 from .metrics import SimResult, TrafficResult, compute_traffic_result, gini
@@ -182,6 +184,11 @@ class Simulation:
         self.completed_cops: dict[int, tuple[CopPlan, float]] = {}
         self.used_cops: set[int] = set()
         self.tasks_no_cop = 0
+        # task starts, and completed COPs scanned at them (_start_task)
+        self.task_starts = 0
+        self.cops_scanned = 0
+        trace.counter("sim.task_starts", self, attrgetter("task_starts"))
+        trace.counter("sim.cops_scanned", self, attrgetter("cops_scanned"))
         self._scheduled_failures: list[tuple[float, int]] = []
         self._scheduled_joins: list[tuple[float, int]] = []
         self.steps_executed = 0              # engine loop steps (events/sec)
@@ -201,8 +208,6 @@ class Simulation:
         self._tenant_retry = ({t.name: t.retry for t in self.traffic.tenants
                                if t.retry is not None}
                               if self.traffic else {})
-        # per-arrival scheduler-churn samples (dirty sets, solver, flows)
-        self._churn_samples: list[dict] = []
         # id-namespace allocation cursors: instance k's local ids are
         # rebased onto [base, base+span) so concurrent instances never
         # collide with each other or with a t=0 workflow
@@ -284,6 +289,7 @@ class Simulation:
                 self._start_cop(act.plan)
 
     def _start_task(self, tid: int, node: int) -> None:
+        self.task_starts += 1
         self.pending.discard(tid)
         task = self.wf.tasks[tid]
         run = _TaskRun(task, node, "read", set(), self.time)
@@ -299,6 +305,7 @@ class Simulation:
             assert dps.is_prepared(task.inputs, node), (
                 f"scheduler started task {tid} on unprepared node {node}")
             needed = False
+            self.cops_scanned += len(self.completed_cops)
             for cid, (plan, _) in self.completed_cops.items():
                 if plan.target != node:
                     continue
@@ -647,14 +654,6 @@ class Simulation:
         for t in inst.tasks.values():
             if self.remaining_inputs[t.id] == 0:
                 self._submit(t)
-        # cross-workflow churn profile: sample the scheduler's dirty sets
-        # and cumulative solver/flow counters right after the arrival lands
-        # (before the next iterate() drains them)
-        sample: dict = {"t": self.time, "instance": spec.index}
-        sample.update(self.strategy.churn_probe())
-        if hasattr(self.fm, "health"):
-            sample["flow_recomputes"] = int(self.fm.health()["recomputes"])
-        self._churn_samples.append(sample)
 
     def _traffic_task_done(self, tid: int, start: float, end: float,
                            cores: float) -> None:
@@ -747,27 +746,6 @@ class Simulation:
                         "blocked": blocked, "reason": reason})
         return out
 
-    def _churn_summary(self) -> dict:
-        """Aggregate the per-arrival churn samples: dirty-set statistics
-        plus cumulative-counter-per-arrival rates, and the raw samples (the
-        arrival stream is bounded, so the list stays small)."""
-        samples = self._churn_samples
-        if not samples:
-            return {}
-        out: dict = {"arrivals_sampled": len(samples)}
-        dirty = [s["dirty_tasks"] for s in samples if "dirty_tasks" in s]
-        if dirty:
-            out["dirty_tasks_mean"] = sum(dirty) / len(dirty)
-            out["dirty_tasks_max"] = max(dirty)
-        for key, rate_key in (("solver_events", "solver_events_per_arrival"),
-                              ("flow_recomputes",
-                               "flow_recomputes_per_arrival")):
-            vals = [s[key] for s in samples if key in s]
-            if vals:
-                out[rate_key] = vals[-1] / len(vals)
-        out["samples"] = samples
-        return out
-
     def traffic_result(self) -> TrafficResult:
         if self.traffic is None:
             raise RuntimeError("simulation was not run with a TrafficConfig")
@@ -776,7 +754,7 @@ class Simulation:
                                  key=lambda r: r.id),
             self._rejections, self._depth_samples, end_time=self.time,
             incomplete=self._traffic_incomplete(),
-            retries=self._retries, churn=self._churn_summary())
+            retries=self._retries)
 
     # ------------------------------------------------------------------ run
     def run(self, max_steps: int = 50_000_000) -> SimResult:

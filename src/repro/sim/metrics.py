@@ -138,8 +138,6 @@ class TrafficResult:
     # after a rejection, and admitted instances that needed >1 attempt
     retries: int = 0
     retry_admitted: int = 0
-    # per-arrival scheduler churn profile (engine churn_probe samples)
-    churn: dict = dataclasses.field(default_factory=dict)
 
     def row(self) -> dict:
         d = dataclasses.asdict(self)
@@ -151,7 +149,6 @@ def compute_traffic_result(cfg, records, rejections, depth_samples,
                            end_time: float,
                            incomplete: list[dict] | None = None,
                            retries: list | None = None,
-                           churn: dict | None = None,
                            ) -> TrafficResult:
     """Aggregate engine bookkeeping into a ``TrafficResult``.
 
@@ -160,8 +157,7 @@ def compute_traffic_result(cfg, records, rejections, depth_samples,
     attempts that bounce again are counted once per bounce).
     ``depth_samples``: (time, pending_tasks, live_instances) sampled at
     every arrival and instance completion.
-    ``retries``: (time, tenant) per scheduled retry re-submission.
-    ``churn``: per-arrival scheduler churn summary (engine-provided)."""
+    ``retries``: (time, tenant) per scheduled retry re-submission."""
     retries = list(retries or [])
     tenants = {t.name: t for t in cfg.tenants}
     incomplete = list(incomplete or [])
@@ -253,5 +249,4 @@ def compute_traffic_result(cfg, records, rejections, depth_samples,
         retries=len(retries),
         retry_admitted=sum(1 for r in records
                            if getattr(r, "attempts", 1) > 1),
-        churn=dict(churn or {}),
     )

@@ -1,5 +1,5 @@
 """Hierarchical topology layer: path construction, fill parity, flat
-bit-identity, locality-aware placement, retry + churn-profile satellites.
+bit-identity, locality-aware placement, retry satellite.
 
 Covers the topology PR's guarantees:
 
@@ -18,8 +18,8 @@ Covers the topology PR's guarantees:
   the nearest replica; repair destinations prefer fresh racks; the DPS
   plans COPs from minimum-distance sources and prices them with weighted
   bytes; the tracked locality cost matches the from-scratch reference.
-* **Satellites** -- ``RetryPolicy`` (seeded capped backoff, retry counters
-  in ``TrafficResult``) and the per-arrival churn profile.
+* **Satellite** -- ``RetryPolicy`` (seeded capped backoff, retry counters
+  in ``TrafficResult``).
 """
 import hashlib
 import json
@@ -569,26 +569,3 @@ def test_retry_run_replays_bit_identically():
     (r1, t1), (r2, t2) = runs
     assert repr(r1.makespan) == repr(r2.makespan)
     assert t1 == t2
-
-
-# ------------------------------------------------- churn-profile satellite
-def test_traffic_result_carries_churn_profile():
-    cfg = _retry_traffic(None)
-    _, wow = run_traffic(cfg, "wow", n_nodes=4)
-    churn = wow.churn
-    assert churn["arrivals_sampled"] == wow.admitted
-    assert len(churn["samples"]) == churn["arrivals_sampled"]
-    for s in churn["samples"]:
-        assert {"t", "instance", "dirty_tasks", "solver_events",
-                "flow_recomputes"} <= set(s)
-    assert churn["dirty_tasks_max"] >= churn["dirty_tasks_mean"] >= 0
-    assert churn["solver_events_per_arrival"] >= 0
-    # the counter is cumulative-at-sample-time: non-negative always, may be
-    # zero when every flow event lands after the last arrival
-    assert churn["flow_recomputes_per_arrival"] >= 0
-    # DFS-bound baselines have no incremental core: flow counters only
-    _, orig = run_traffic(cfg, "orig", n_nodes=4)
-    assert orig.churn["arrivals_sampled"] == orig.admitted
-    assert "dirty_tasks_mean" not in orig.churn
-    assert all("dirty_tasks" not in s for s in orig.churn["samples"])
-    assert orig.churn["flow_recomputes_per_arrival"] >= 0
