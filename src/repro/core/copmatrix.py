@@ -28,7 +28,7 @@ retained per-task dict oracle:
   not inflight x not prepared) as array ops, computes the full cost row
   (missing bytes, or the locality-weighted cost under a topology) and
   selects the winner by the same staged masked reductions
-  ``scheduler._greedy_uniform_vec`` uses -- ``key min, then node-id
+  ``ilp.greedy_uniform`` uses -- ``key min, then node-id
   min`` -- so float ties split exactly as the dict path's
   ``(cost, node)`` tuple sort does.  Only the *winning* node is then
   probed through the scalar ``plan_cop``, which is the only probe the
@@ -477,7 +477,7 @@ class BlockedDrainKernel:
         ids = cap._node_of[:n]
         if self.jax_winner is not None:
             return self.jax_winner(key, ids)
-        # staged reduction, ordered like _greedy_uniform_vec: min key
+        # staged reduction, ordered like ilp.greedy_uniform: min key
         # first, then min node id among the ties -- exactly the dict
         # tuple-compare (cost, node)
         m0 = key.min()

@@ -31,6 +31,9 @@ largest component holds 8 tasks is 512 tiny problems, not one huge one.
 * ``solve_greedy`` -- priority-descending best-fit with one
   swap-improvement pass; used beyond the exact budget (oversized
   components) and as the fallback when the B&B node budget is exhausted.
+* ``_solve_uniform`` -- components of identical tasks (one shape, one
+  priority, one candidate list): the B&B's answer, budget abort included,
+  counted over capacity histograms instead of searched.
 
 A component is solved exactly when it has <= ``_EXACT_CAND_LIMIT`` candidate
 slots or <= ``_EXACT_TASK_LIMIT`` tasks -- per *component*, so decomposition
@@ -65,6 +68,11 @@ from typing import Iterable, Mapping
 
 from . import trace
 from .types import NodeState, TaskSpec
+
+try:  # optional; without numpy the uniform tier stays off (the loop decides)
+    import numpy as np
+except ModuleNotFoundError:  # pragma: no cover
+    np = None
 
 # Budget of B&B nodes before falling back to greedy.  Exact instances in the
 # paper are tiny; this bound keeps worst-case latency low at huge scale.
@@ -336,6 +344,209 @@ def solve_monolithic(problem: AssignmentProblem) -> dict[int, int]:
     return solve_greedy(problem)
 
 
+# -------------------------------------------------------------- uniform tier
+def greedy_uniform(mem, cores, tids: Iterable[int], ids, fm,
+                   fc) -> dict[int, int]:
+    """``solve_greedy`` of identical tasks on arrays: best-fit placement of
+    ``tids`` (already in ``(-priority, id)`` order, read lazily: a long
+    backlog is read only up to its first failure) on the nodes ``ids``
+    with free ``fm`` / ``fc``, stopping at the first task that fits nowhere
+    (capacity never grows mid-solve, so every later task fails too, and
+    the repair pass has no strictly-lower-priority placed task to move).
+    The best-fit key ``(fc - cores, fm - mem, id)`` is minimized by three
+    staged masked reductions over the values the dict loop reads, the
+    subtractions made *before* comparing, so float ties fall exactly where
+    the tuple comparison puts them.  ``fm`` / ``fc`` are not modified."""
+    fm = fm.copy()
+    fc = fc.copy()
+    big = np.iinfo(np.int64).max
+    out: dict[int, int] = {}
+    for tid in tids:
+        ok = (fm >= mem) & (fc >= cores)
+        fck = np.where(ok, fc - cores, np.inf)
+        m0 = fck.min()
+        if m0 == np.inf:
+            break                       # first failure stops the shape
+        t1 = fck == m0
+        fmk = np.where(t1, fm - mem, big)
+        t2 = fmk == fmk.min()
+        idk = np.where(t2, ids, big)
+        j = int(idk.argmin())
+        out[tid] = int(ids[j])
+        fm[j] -= mem
+        fc[j] -= cores
+    return out
+
+
+def _uniform_list(tasks: list[TaskSpec],
+                  cand: Mapping[int, list[int]]) -> list[int] | None:
+    """The candidate list every task shares when the tasks have one shape
+    ``(mem, cores)``, one positive priority and one duplicate-free
+    candidate list (identity is tested first: the input-less path hands
+    every task of a shape the same list object); else None.  O(tasks)."""
+    if np is None or not tasks:
+        return None
+    t0 = tasks[0]
+    lst = cand.get(t0.id)
+    if not lst or not (t0.priority > 0 and t0.mem >= 0 and t0.cores >= 0):
+        return None
+    for t in tasks:
+        c = cand.get(t.id)
+        if (t.mem != t0.mem or t.cores != t0.cores
+                or t.priority != t0.priority
+                or (c is not lst and c != lst)):
+            return None
+    return lst if len(set(lst)) == len(lst) else None
+
+
+def _free_arrays(nodes: Mapping[int, NodeState], ids: list[int], cap,
+                 mem) -> tuple:
+    """Free memory and cores of ``ids`` as arrays, read from the same
+    values `_free_maps` reads.  Cores are float64 and memory int64 unless
+    the values or ``mem`` are floats, so each subtraction below is the one
+    Python performs."""
+    fm = fc = None
+    if cap is not None and len(ids) >= _MASK_MIN_CANDS:
+        try:
+            slots = cap.slots_of(ids)
+        except KeyError:          # a node left the mirror: dict fallback
+            pass
+        else:
+            fm, fc = cap.free_mem[slots], cap.free_cores[slots]
+    if fm is None:
+        fm = np.array([nodes[n].free_mem for n in ids])
+        fc = np.array([nodes[n].free_cores for n in ids], dtype=np.float64)
+    if fm.dtype.kind != "f" and not isinstance(mem, int):
+        fm = fm.astype(np.float64)
+    return fm, fc
+
+
+def _solve_uniform(tasks: list[TaskSpec], lst: list[int],
+                   nodes: Mapping[int, NodeState], cap,
+                   node_budget: int) -> tuple[dict[int, int], str, int] | None:
+    """What ``solve_exact(prob, node_budget)`` followed by the greedy
+    fallback returns for a component of identical tasks sharing the
+    candidate list ``lst`` (:func:`_uniform_list`), counted instead of
+    searched (DESIGN.md "Uniform tier").  Returns (assignment, tier,
+    visits): tier "exact" or "aborted", visits the search nodes the loop
+    visits, counted only until they pass the budget.  None when no
+    candidate fits (the loop answers that trivially).
+
+    * The tasks are interchangeable, so a node matters only through how
+      many more placements it admits: ``rem``, counted with the loop's own
+      subtractions (once a node fails it fails for good: free capacity
+      only shrinks).
+    * The search's first depth-first path places task after task on the
+      most-free fitting node until none fits: ``min(n, sum(rem))`` tasks,
+      the optimum, so ``best_val`` is that path's value from then on.
+    * Every other search node is visited after that path, and whether it
+      expands (``val + suffix[i] > best_val``, in the loop's float sums)
+      depends only on its depth and the tasks assigned.  So a subtree's
+      visit count is a function of (depth, assigned, multiset of
+      remaining capacities), memoized over capacity histograms and
+      saturated just past the budget."""
+    t0 = tasks[0]
+    mem, cores = t0.mem, t0.cores
+    fm, fc = _free_arrays(nodes, lst, cap, mem)
+    ok = (fm >= mem) & (fc >= cores)         # `_feasible`'s filter
+    if not ok.any():
+        return None
+    if not ok.all():
+        keep = np.flatnonzero(ok)
+        lst = [lst[k] for k in keep.tolist()]
+        fm, fc = fm[keep], fc[keep]
+    ids = np.asarray(lst, dtype=np.int64)
+    n = len(tasks)
+    # the loop's bound terms, built with its own float additions: suffix
+    # sums from the back, the running value forward along any path
+    suffix = [0.0] * (n + 1)
+    for i in range(n - 1, -1, -1):
+        suffix[i] = suffix[i + 1] + tasks[i].priority
+    fwd = [0.0] * (n + 1)
+    for j in range(n):
+        fwd[j + 1] = fwd[j] + t0.priority
+
+    # placements each node admits, capped at n
+    rem = np.zeros(len(lst), dtype=np.int64)
+    m_left, c_left = fm, fc
+    for _ in range(n):
+        fit = (m_left >= mem) & (c_left >= cores)
+        if not fit.any():
+            break
+        rem += fit
+        m_left, c_left = m_left - mem, c_left - cores
+
+    # the first path: the loop's candidate order is (-cores, -mem, id)
+    fmw, fcw = fm.copy(), fc.copy()
+    assign: dict[int, int] = {}
+    frames = []             # (depth, capacity histogram, chosen level)
+    for i, t in enumerate(tasks):
+        idx = np.flatnonzero(rem > 0)
+        if not idx.size:
+            break
+        sel = fcw[idx]
+        idx = idx[sel == sel.max()]
+        if idx.size > 1:
+            sel = fmw[idx]
+            idx = idx[sel == sel.max()]
+        k = int(idx[ids[idx].argmin()]) if idx.size > 1 else int(idx[0])
+        levels = n - i
+        hist = np.bincount(np.minimum(rem, levels), minlength=levels + 1)
+        frames.append((i, tuple(hist[1:].tolist()), min(int(rem[k]), levels)))
+        assign[t.id] = int(ids[k])
+        rem[k] -= 1
+        fmw[k] -= mem
+        fcw[k] -= cores
+
+    best = fwd[len(assign)]
+    over = node_budget + 1
+    memo: dict[tuple, int] = {}
+
+    def cut(h: tuple, levels: int) -> tuple:
+        # a node admitting more placements than tasks remain is no
+        # different from one admitting exactly that many
+        return h[:levels - 1] + (sum(h[levels - 1:]),) if levels > 0 else ()
+
+    def placed(h: tuple, c: int) -> tuple:
+        h = list(h)
+        h[c - 1] -= 1
+        if c > 1:
+            h[c - 2] += 1
+        return tuple(h)
+
+    def visits(i: int, j: int, h: tuple) -> int:
+        """Nodes the loop visits in the subtree entered at depth ``i`` with
+        ``j`` tasks assigned and capacity histogram ``h``, capped at
+        ``over``."""
+        if i == n or not fwd[j] + suffix[i] > best:
+            return 1
+        key = (i, j, h)
+        v = memo.get(key)
+        if v is None:
+            rest = n - i - 1
+            v = 1 + visits(i + 1, j, cut(h, rest))
+            for c, m in enumerate(h, 1):
+                if m and v < over:
+                    v += m * visits(i + 1, j + 1, cut(placed(h, c), rest))
+            memo[key] = v = min(v, over)
+        return v
+
+    total = n + 1                       # the first path, root to leaf
+    for i, h, lvl in frames:
+        if total > node_budget:
+            break
+        rest = n - i - 1
+        total += visits(i + 1, i, cut(h, rest))         # skip task i
+        for c, m in enumerate(h, 1):    # task i on another fitting node
+            m -= c == lvl
+            if m > 0 and total <= node_budget:
+                total += m * visits(i + 1, i + 1, cut(placed(h, c), rest))
+    if total > node_budget:
+        return greedy_uniform(mem, cores, sorted(t.id for t in tasks),
+                              ids, fm, fc), "aborted", total
+    return assign, "exact", total
+
+
 # ------------------------------------------------------------- decomposition
 def group_by_shared_nodes(keys: list, cand_of) -> list[list]:
     """Union-find over ``keys`` via shared candidate nodes (``cand_of(key)``
@@ -361,8 +572,14 @@ def group_by_shared_nodes(keys: list, cand_of) -> list[list]:
         parent[rb] = ra
 
     node_owner: dict[int, int] = {}
+    first: dict[int, tuple] = {}    # id(list) -> (list, first key with it)
     for k in keys:
-        for n in cand_of(k):
+        c = cand_of(k)
+        f = first.setdefault(id(c), (c, k))[1]
+        if f != k:          # the same list object: the same nodes, which
+            union(k, f)     # the first key with it already joined
+            continue
+        for n in c:
             o = node_owner.setdefault(n, k)
             if o != k:
                 union(k, o)
@@ -371,6 +588,12 @@ def group_by_shared_nodes(keys: list, cand_of) -> list[list]:
     for k in keys:
         groups.setdefault(find(k), []).append(k)
     return [groups[r] for r in sorted(groups, key=pos.__getitem__)]
+
+
+def _distinct(lists: Iterable[list]) -> list[list]:
+    """The distinct list objects among ``lists``, by identity: the tasks of
+    one input-less shape share one candidate list object, read once."""
+    return list({id(c): c for c in lists}.values())
 
 
 def _components(p: AssignmentProblem) -> list[tuple[list[TaskSpec],
@@ -386,7 +609,7 @@ def _components(p: AssignmentProblem) -> list[tuple[list[TaskSpec],
                                        p.prepared.__getitem__):
         tasks = [by_id[tid] for tid in group]
         cand = {tid: p.prepared[tid] for tid in group}
-        node_ids = sorted({n for c in cand.values() for n in c})
+        node_ids = sorted({n for c in _distinct(cand.values()) for n in c})
         out.append((tasks, cand, node_ids))
     return out
 
@@ -405,13 +628,24 @@ def _solve_component(tasks: list[TaskSpec], cand: dict[int, list[int]],
                      seed: dict[int, int] | None = None,
                      node_budget: int = _EXACT_NODE_BUDGET,
                      cap: object | None = None,
+                     stats: dict[str, int] | None = None,
                      ) -> tuple[dict[int, int], str]:
     """One component: exact when small (per-component gate), else greedy.
     Returns (assignment, tier) with tier in {"exact", "greedy", "aborted"}.
-    ``cand`` lists must already be filtered to currently-fitting nodes."""
+    ``cand`` lists must already be filtered to currently-fitting nodes.
+    Unseeded components of identical tasks take the uniform tier, which
+    returns what the search and its fallback would; it bumps
+    ``stats["uniform_solves"]`` when ``stats`` is given."""
     prob = AssignmentProblem(tasks, cand, nodes, cap)
     n_cand = sum(len(v) for v in cand.values())
     if exact_gate(len(tasks), n_cand):
+        lst = _uniform_list(tasks, cand) if seed is None else None
+        if lst is not None:
+            out = _solve_uniform(tasks, lst, nodes, cap, node_budget)
+            if out is not None:
+                if stats is not None:
+                    stats["uniform_solves"] += 1
+                return out[0], out[1]
         exact = solve_exact(prob, node_budget, incumbent=seed)
         if exact is not None:
             return exact, "exact"
@@ -454,9 +688,11 @@ def component_fingerprint(tids, tasks: Mapping[int, TaskSpec],
     capacity array the node free tuples come from one gather (plain Python
     ints/floats via ``.tolist()``, so fingerprints compare equal across the
     gathered and walked forms)."""
-    nlist = sorted({n for c in cand.values() for n in c})
+    lists = _distinct(cand.values())
+    nlist = sorted({n for c in lists for n in c})
     npos = {n: i for i, n in enumerate(nlist)}
     id_rank = {t: i for i, t in enumerate(sorted(tids))}
+    pos = {id(c): tuple(npos[n] for n in c) for c in lists}
     node_fp = None
     if cap is not None and len(nlist) >= _MASK_MIN_CANDS:
         try:
@@ -472,7 +708,7 @@ def component_fingerprint(tids, tasks: Mapping[int, TaskSpec],
     fp = (
         tuple((id_rank[t], tasks[t].mem, tasks[t].cores,
                tasks[t].priority,
-               tuple(npos[n] for n in cand[t])) for t in tids),
+               pos[id(cand[t])]) for t in tids),
         node_fp,
     )
     return fp, nlist, npos
@@ -564,10 +800,13 @@ class IncrementalAssignmentSolver:
             "events": 0, "comps_rebuilt": 0, "comps_reused": 0,
             "cache_hits": 0, "cache_misses": 0, "exact_solves": 0,
             "greedy_solves": 0, "budget_aborts": 0, "warm_seeds": 0,
+            "uniform_solves": 0,
         }
         for name, key in (("step1.comps_resolved", "comps_rebuilt"),
                           ("step1.cache_hits", "cache_hits"),
-                          ("step1.cache_misses", "cache_misses")):
+                          ("step1.cache_misses", "cache_misses"),
+                          ("step1.uniform_solves", "uniform_solves"),
+                          ("step1.budget_aborts", "budget_aborts")):
             trace.counter(name, self, lambda s, key=key: s.stats[key])
 
     # ------------------------------------------------------------ event API
@@ -640,7 +879,8 @@ class IncrementalAssignmentSolver:
             assign = self._solve_comp(tids, tasks, candidates, prev)
             cid = self._next_cid
             self._next_cid += 1
-            nodeset = frozenset(n for t in tids for n in candidates[t])
+            nodeset = frozenset(
+                n for c in _distinct(candidates[t] for t in tids) for n in c)
             self._comp_tasks[cid] = tids
             self._comp_nodes[cid] = nodeset
             self._comp_assign[cid] = assign
@@ -669,7 +909,7 @@ class IncrementalAssignmentSolver:
         t_specs = [tasks[t] for t in tids]
         node_states = {n: self.nodes[n] for n in nlist}
         assign, tier = _solve_component(t_specs, cand, node_states, seed=seed,
-                                        cap=self.cap)
+                                        cap=self.cap, stats=self.stats)
         if tier == "exact":
             self.stats["exact_solves"] += 1
         else:

@@ -94,7 +94,8 @@ from . import trace
 from .dps import DataPlacementService
 from .ilp import (AssignmentProblem, FingerprintCache,
                   IncrementalAssignmentSolver, component_fingerprint,
-                  exact_gate, group_by_shared_nodes, solve_greedy)
+                  exact_gate, greedy_uniform, group_by_shared_nodes,
+                  solve_greedy)
 from .ilp import solve as solve_stateless
 from .nodearray import HAVE_NUMPY, ArrayCapacityClasses, NodeCapacityArray
 from .readyset import CapacityClasses, NodeOrder, ReadySet, ShapeIndex
@@ -460,7 +461,10 @@ class WowScheduler:
           task can have no strictly-lower-priority placed task when
           placement order is priority-descending and all shapes are equal).
           The shape index stores buckets in that exact order, so this costs
-          O(assigned x fitting nodes) -- no backlog scan, no sort.
+          O(assigned x fitting nodes) -- no backlog scan, no sort.  With
+          the capacity array it is ``ilp.greedy_uniform``, the step-1
+          solver's uniform fallback; else its dict twin
+          :meth:`_greedy_uniform`.
         * **generic tier** -- small or multi-shape components go through
           `ilp.solve` unchanged, behind a canonical fingerprint cache
           (`ilp.FingerprintCache`, the step-1 solver's machinery) so a
@@ -482,9 +486,13 @@ class WowScheduler:
                 fit = fits[shape]
                 if not exact_gate(len(group), len(group) * len(fit)):
                     self.inputless_stats["fast_solves"] += 1
-                    if self._cap_array is not None:
-                        assign.update(
-                            self._greedy_uniform_vec(shape, group, fit))
+                    cap = self._cap_array
+                    if cap is not None:
+                        slots = cap.slots_of(fit)
+                        assign.update(greedy_uniform(
+                            shape[0], shape[1], (tid for _, tid in group),
+                            np.asarray(fit, dtype=np.int64),
+                            cap.free_mem[slots], cap.free_cores[slots]))
                     else:
                         assign.update(self._greedy_uniform(shape, group, fit))
                     continue
@@ -631,38 +639,6 @@ class WowScheduler:
             out[tid] = best
             free_mem[best] -= mem
             free_cores[best] -= cores
-        return out
-
-    def _greedy_uniform_vec(self, shape: tuple[int, float],
-                            group: list[tuple[float, int]],
-                            fit: list[int]) -> dict[int, int]:
-        """Array twin of :meth:`_greedy_uniform`: the best-fit key
-        ``(fc - cores, fm - mem, id)`` is minimized by three staged masked
-        reductions over the same values the dict loop reads (the
-        subtractions are performed *before* comparing, so float ties fall
-        exactly where the dict path's tuple comparison puts them)."""
-        mem, cores = shape
-        cap = self._cap_array
-        slots = cap.slots_of(fit)
-        fm = cap.free_mem[slots].copy()
-        fc = cap.free_cores[slots].copy()
-        ids = np.asarray(fit, dtype=np.int64)
-        big = np.iinfo(np.int64).max
-        out: dict[int, int] = {}
-        for _, tid in group:
-            ok = (fm >= mem) & (fc >= cores)
-            fck = np.where(ok, fc - cores, np.inf)
-            m0 = fck.min()
-            if m0 == np.inf:
-                break                       # first failure stops the shape
-            t1 = fck == m0
-            fmk = np.where(t1, fm - mem, big)
-            t2 = fmk == fmk.min()
-            idk = np.where(t2, ids, big)
-            j = int(idk.argmin())
-            out[tid] = int(ids[j])
-            fm[j] -= mem
-            fc[j] -= cores
         return out
 
     def _solve_inputless_component(self, tids: list[int],

@@ -514,6 +514,51 @@ def test_sustained_scenario_cache_stays_cold():
     assert sched.solver_stats["cache_hits"] == 0    # ...and never recurred
 
 
+@pytest.mark.parametrize("seed", range(4))
+def test_mixed_event_uniform_component_matches_reference(seed):
+    """A mixed event: up to 24 input-less tasks of one shape fitting on
+    more than 64 nodes, solved jointly with a startable data-bound task
+    whose only prepared node the shape does not fit.  The input-less
+    component takes the uniform tier, and the actions equal the
+    reference scheduler's (whose monolithic search stays inside its gate
+    and budget: integral priorities keep the bound's sums exact)."""
+    from repro.core import (DataPlacementService, FileSpec,
+                            ReferenceWowScheduler, StartCop, StartTask,
+                            WowScheduler)
+    rng = random.Random(seed)
+    n_nodes = 80
+    cores = [float(rng.randint(8, 16)) for _ in range(n_nodes)]
+    host = rng.randrange(n_nodes)
+    cores[host] = 4.0               # fits the data-bound task only
+
+    def build(cls):
+        nodes = {i: NodeState(i, mem=64 * GiB, cores=cores[i])
+                 for i in range(n_nodes)}
+        dps = DataPlacementService(seed=seed)
+        dps.register_file(FileSpec(id=0, size=GiB, producer=-1), host)
+        return cls(nodes, dps)
+
+    def summary(actions):
+        return [("task", a.task_id, a.node) if isinstance(a, StartTask)
+                else ("cop", a.plan.task_id, a.plan.target)
+                for a in actions if isinstance(a, (StartTask, StartCop))]
+
+    new, ref = build(WowScheduler), build(ReferenceWowScheduler)
+    n_less = rng.randint(12, 23)
+    specs = [dict(id=0, abstract="b", mem=GiB, cores=2.0, inputs=(0,),
+                  priority=5.0)]
+    specs += [dict(id=t, abstract="a", mem=2 * GiB, cores=8.0, inputs=(),
+                   priority=1.0) for t in range(1, n_less + 1)]
+    for spec in specs:
+        new.submit(TaskSpec(**spec))
+        ref.submit(TaskSpec(**spec))
+    got = summary(new.schedule())
+    assert got == summary(ref.schedule())
+    assert ("task", 0, host) in got
+    assert new.inputless_stats["joint_events"] == 1
+    assert new.solver_stats["uniform_solves"] >= 2
+
+
 def test_clean_components_are_not_resolved():
     """Components untouched by the dirty sets are skipped wholesale."""
     nodes = {i: NodeState(i, mem=8 * GiB, cores=8.0) for i in range(4)}

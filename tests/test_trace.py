@@ -125,6 +125,7 @@ def test_on_one_row_per_round_and_counters_add_up(trace_off):
         "step1.comps_resolved": sched.solver_stats["comps_rebuilt"],
         "step1.cache_hits": sched.solver_stats["cache_hits"],
         "step1.cache_misses": sched.solver_stats["cache_misses"],
+        "step1.uniform_solves": sched.solver_stats["uniform_solves"],
         "drain.cops_started": sched.cops_created,
         "drain.tasks_probed": sched.drain_probed,
         "dps.replica_writes": sched.dps.replica_writes,
@@ -136,6 +137,8 @@ def test_on_one_row_per_round_and_counters_add_up(trace_off):
     assert want["sim.task_starts"] == sum(
         1 for e in sim.action_log if e[1] == "task")
     assert all(want[name] > 0 for name in want), want
+    assert (sum(r.get("step1.budget_aborts", 0.0) for r in rows)
+            == sched.solver_stats["budget_aborts"])
     assert trace.totals()["sim.task_starts"] == want["sim.task_starts"]
 
 
