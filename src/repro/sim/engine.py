@@ -182,13 +182,21 @@ class Simulation:
         self.storage_per_node: dict[int, float] = {}
         self.cpu_per_node: dict[int, float] = {}
         self.completed_cops: dict[int, tuple[CopPlan, float]] = {}
+        # index of completed_cops, kept by _finish_cop and, like it, never
+        # pruned: target node -> file id -> COP ids that copied the file
+        # there, and the (task id, target node) pairs COPs were made for
+        self._cops_by_target: dict[int, dict[int, list[int]]] = {}
+        self._cop_task_targets: set[tuple[int, int]] = set()
         self.used_cops: set[int] = set()
         self.tasks_no_cop = 0
-        # task starts, and completed COPs scanned at them (_start_task)
+        # task starts, index entries visited at them (_start_task), and
+        # COPs entered into the index (_finish_cop)
         self.task_starts = 0
         self.cops_scanned = 0
+        self.cops_indexed = 0
         trace.counter("sim.task_starts", self, attrgetter("task_starts"))
         trace.counter("sim.cops_scanned", self, attrgetter("cops_scanned"))
+        trace.counter("sim.cops_indexed", self, attrgetter("cops_indexed"))
         self._scheduled_failures: list[tuple[float, int]] = []
         self._scheduled_joins: list[tuple[float, int]] = []
         self.steps_executed = 0              # engine loop steps (events/sec)
@@ -304,17 +312,14 @@ class Simulation:
             dps = self.strategy.dps
             assert dps.is_prepared(task.inputs, node), (
                 f"scheduler started task {tid} on unprepared node {node}")
-            needed = False
-            self.cops_scanned += len(self.completed_cops)
-            for cid, (plan, _) in self.completed_cops.items():
-                if plan.target != node:
-                    continue
-                files = {t.file_id for t in plan.transfers}
-                if files & set(task.inputs):
-                    self.used_cops.add(cid)
-                if plan.task_id == tid:
-                    needed = True
-            if not needed:
+            by_file = self._cops_by_target.get(node)
+            if by_file:
+                for f in set(task.inputs):
+                    cids = by_file.get(f)
+                    if cids:
+                        self.cops_scanned += len(cids)
+                        self.used_cops.update(cids)
+            if (tid, node) not in self._cop_task_targets:
                 self.tasks_no_cop += 1
         # read phase flows
         if self.strategy.local_io:
@@ -438,7 +443,13 @@ class Simulation:
     def _finish_cop(self, cop_id: int, ok: bool) -> None:
         cop = self.cop_runs.pop(cop_id)
         if ok:
-            self.completed_cops[cop_id] = (cop.plan, self.time)
+            plan = cop.plan
+            self.completed_cops[cop_id] = (plan, self.time)
+            by_file = self._cops_by_target.setdefault(plan.target, {})
+            for f in {t.file_id for t in plan.transfers}:
+                by_file.setdefault(f, []).append(cop_id)
+            self._cop_task_targets.add((plan.task_id, plan.target))
+            self.cops_indexed += 1
         self.strategy.cop_finished(cop.plan, ok)
 
     # ----------------------------------------------------- failure/elastic

@@ -131,12 +131,17 @@ def test_on_one_row_per_round_and_counters_add_up(trace_off):
         "dps.replica_writes": sched.dps.replica_writes,
         "sim.task_starts": sim.task_starts,
         "sim.cops_scanned": sim.cops_scanned,
+        "sim.cops_indexed": sim.cops_indexed,
     }
     got = {name: sum(r.get(name, 0.0) for r in rows) for name in want}
     assert got == want
     assert want["sim.task_starts"] == sum(
         1 for e in sim.action_log if e[1] == "task")
     assert all(want[name] > 0 for name in want), want
+    # the simulator's COP index: one entry per completed COP, and every
+    # COP used at a task start was visited there at least once
+    assert want["sim.cops_indexed"] == len(sim.completed_cops)
+    assert want["sim.cops_scanned"] >= len(sim.used_cops)
     assert (sum(r.get("step1.budget_aborts", 0.0) for r in rows)
             == sched.solver_stats["budget_aborts"])
     assert trace.totals()["sim.task_starts"] == want["sim.task_starts"]
