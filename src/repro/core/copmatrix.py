@@ -484,6 +484,15 @@ class BlockedDrainKernel:
         tie = key == m0
         return int(np.where(tie, ids, big).min())
 
+    def free_slot_fit_node(self, mem: int, cores: float) -> int | None:
+        """A free-COP-slot node whose *free* resources fit the shape, or
+        None when there is none -- which proves every step-2 candidate
+        mask of the shape empty for the rest of the pass (the step-2
+        emptiness pre-test)."""
+        mask = self._fit2_mask(mem, cores) & self._free_vec()
+        s = int(mask.argmax())
+        return int(self.cap._node_of[s]) if mask[s] else None
+
     def step3_candidates(self, tid: int, t) -> list[int] | None:
         """Step-3 candidate node ids in canonical (slot) order, or None
         when the task has no matrix row.  Mask construction only: the

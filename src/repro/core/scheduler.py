@@ -170,8 +170,11 @@ class WowScheduler:
         self.tasks_started: int = 0
         self.declines: int = 0
         self.drain_probed: int = 0      # ready tasks steps 2-3 visited
+        self.drain_skipped: int = 0     # visits the emptiness pre-test ended
         trace.counter("drain.cops_started", self, attrgetter("cops_created"))
         trace.counter("drain.tasks_probed", self, attrgetter("drain_probed"))
+        trace.counter("drain.probes_skipped", self,
+                      attrgetter("drain_skipped"))
 
         # ----- incremental state (see module docstring)
         self._seq = 0
@@ -783,6 +786,13 @@ class WowScheduler:
     # structure immediately but not the materialized snapshot -- matching
     # the reference, which sorts once and re-checks budget/feasibility at
     # visit time, as the loops here still do.
+    #
+    # Emptiness pre-test (DESIGN.md "Batched COP drain"): before building a
+    # visited task's candidate set, each loop first asks whether facts that
+    # hold for the whole pass already prove it empty, and skips the task if
+    # so.  Every skipped step is read-only and an empty candidate set never
+    # reaches plan_cop, so no COP id or tie-break draw is consumed either
+    # way: decisions are unchanged.
     def _step2_prepare_for_free_compute(self, actions: list[Action],
                                         started: set[int]) -> None:
         del started  # step 1 already popped started tasks from self.ready
@@ -793,13 +803,28 @@ class WowScheduler:
         kern = self._kernel
         if kern is not None:
             kern.begin()
-        probed = 0
+        probed = skipped = 0
+        # Shapes (mem, cores) that no free-slot node's free resources fit.
+        # Within one pass free resources are frozen (step 1 made its
+        # reservations) and the free-slot set only shrinks, so a dead shape
+        # stays dead: its later tasks have empty candidate sets.  A shape is
+        # proved dead only after one of its probes came back empty, never
+        # per visit -- where every probe finds a candidate (large clusters)
+        # the proof would be pure overhead.  A failed proof leaves a
+        # witness, a free-slot node the shape fits, and while the witness
+        # keeps its free slot the shape is alive without a new proof.
+        dead: set[tuple[int, float]] = set()
+        witness: dict[tuple[int, float], int] = {}
         for tid in self._ready_index.step2_order():
             if not self._free_slot_nodes:
                 break               # no COP can start or source anywhere
             probed += 1
             t = self.ready[tid]
             if not self._task_cop_budget(tid):
+                continue
+            shape = (t.mem, t.cores)
+            if shape in dead:
+                skipped += 1
                 continue
             feas, pool = self._cop_target_pool(t)
             if pool is None:
@@ -815,7 +840,9 @@ class WowScheduler:
                 # its first, minimum-key candidate: exactly the winner.
                 winner = kern.step2_winner(tid, t, dps)
                 if winner is None:
-                    continue        # empty candidate set: oracle starts none
+                    # empty candidate set: oracle starts none
+                    self._step2_empty(t, shape, dead, witness)
+                    continue
                 if winner >= 0:
                     plan = dps.plan_cop(tid, t.inputs, winner,
                                         self._free_slot_nodes,
@@ -827,15 +854,37 @@ class WowScheduler:
                 # invariant above -- an infeasible winning probe: fall
                 # through to the per-task oracle (re-probing the winner is
                 # harmless, infeasible probes are side-effect-free)
-            self._step2_probe_task(tid, t, feas, pool, actions)
+            if not self._step2_probe_task(tid, t, feas, pool, actions):
+                self._step2_empty(t, shape, dead, witness)
         self.drain_probed += probed
+        self.drain_skipped += skipped
+
+    def _step2_empty(self, t: TaskSpec, shape: tuple[int, float],
+                     dead: set, witness: dict) -> None:
+        """A step-2 probe of ``t`` found no candidate: add its shape to
+        ``dead`` unless some free-slot node's free resources fit it."""
+        w = witness.get(shape)
+        if w is not None and w in self._free_slot_nodes:
+            return
+        kern = self._kernel
+        if kern is not None:
+            w = kern.free_slot_fit_node(t.mem, t.cores)
+        else:
+            nodes = self.nodes
+            w = next((n for n in self._free_slot_nodes if nodes[n].fits(t)),
+                     None)
+        if w is None:
+            dead.add(shape)
+        else:
+            witness[shape] = w
 
     def _step2_probe_task(self, tid: int, t: TaskSpec, feas, pool,
-                          actions: list[Action]) -> None:
+                          actions: list[Action]) -> bool:
         """Per-task step-2 machinery -- the retained dict oracle the blocked
         kernel is property-tested bit-identical against, and the live path
         for constrained pools (``pool is not _free_slot_nodes``), for
-        ``batched=False``, and for the kernel's defensive fallthrough."""
+        ``batched=False``, and for the kernel's defensive fallthrough.
+        Returns False when the candidate set was empty."""
         dps = self.dps
         # nodes with free compute capacity, spare COP slot, not already
         # prepared / being prepared
@@ -851,7 +900,7 @@ class WowScheduler:
         cands = [n for n in base
                  if (tid, n) not in inflight and n not in prepped]
         if not cands:
-            return
+            return False
         # earliest start ~ fewest missing bytes (paper §IV-C).  Most
         # candidates hold none of the task's inputs and share the key
         # (task_bytes, n), so when *no* node holds input bytes the sort
@@ -875,6 +924,7 @@ class WowScheduler:
             if plan is not None:
                 self._start_cop(plan, actions)
                 break
+        return True
 
     # Step 3: use leftover network capacity to speculatively prepare
     # high-priority tasks on compute-busy nodes.
@@ -887,12 +937,21 @@ class WowScheduler:
         kern = self._kernel
         if kern is not None:
             kern.begin()
-        probed = 0
+        probed = skipped = 0
+        free = self._free_slot_nodes        # shrinks in place as COPs start
         for tid in self._ready_index.step3_order():
-            if not self._free_slot_nodes:
+            if not free:
                 break
             probed += 1
             if not self._task_cop_budget(tid):
+                continue
+            # every candidate is a free-slot node the task is not prepared
+            # on, so none exist once its prepared set covers the free-slot
+            # set.  The length guard keeps the test O(1) where free-slot
+            # nodes outnumber the prepared ones (large clusters).
+            prep = dps.prepared_node_set(tid)
+            if len(prep) >= len(free) and free <= prep:
+                skipped += 1
                 continue
             t = self.ready[tid]
             feas, pool = self._cop_target_pool(t)
@@ -912,19 +971,17 @@ class WowScheduler:
             if cands is not None:
                 pass
             elif self._cap_array is not None and pool is self._free_slot_nodes:
-                prepped = dps.prepared_node_set(tid)
                 inflight = self.inflight_targets
                 cands = [
                     n for n in self._cap_array.free_slot_total_fit_ids(
                         t.mem, t.cores)
-                    if (tid, n) not in inflight and n not in prepped]
+                    if (tid, n) not in inflight and n not in prep]
             else:
-                prepped = dps.prepared_node_set(tid)
                 inflight = self.inflight_targets
                 cands = order.sort(
                     n for n in pool
                     if (tid, n) not in inflight
-                    and n not in prepped
+                    and n not in prep
                     and t.mem <= self.nodes[n].mem    # could ever run here
                     and t.cores <= self.nodes[n].cores)
             if not cands:
@@ -938,3 +995,4 @@ class WowScheduler:
             if best is not None:
                 self._start_cop(best, actions)
         self.drain_probed += probed
+        self.drain_skipped += skipped

@@ -254,6 +254,43 @@ def test_blocked_matches_reference_core():
     assert logs[False] == logs[True]
 
 
+@pytest.mark.parametrize("batched", [None, False], ids=["blocked", "dict"])
+@pytest.mark.parametrize("workflow,scale", [("sarek", 0.3),
+                                            ("rangeland", 0.1)])
+def test_pretest_matches_reference_core(workflow, scale, batched):
+    """The drain's emptiness pre-test against the frozen reference, on the
+    paper's 8-node testbed with one COP per node, where scarce slots and
+    cores leave most visited tasks without a candidate: the skips must be
+    real in both steps and change no decision."""
+    from repro.sim import SimConfig, Simulation
+    from repro.workloads import make_workflow
+
+    runs = {}
+    skipped = {2: 0, 3: 0}
+    for ref in (False, True):
+        cfg = SimConfig(n_nodes=8, c_node=1, reference_core=ref,
+                        vectorized=None if batched is None else False,
+                        batched=batched)
+        sim = Simulation(make_workflow(workflow, scale=scale), cfg, "wow")
+        if not ref:
+            sched = sim.strategy.sched
+
+            def counted(step, run_step):
+                def run(*args):
+                    before = sched.drain_skipped
+                    run_step(*args)
+                    skipped[step] += sched.drain_skipped - before
+                return run
+            sched._step2_prepare_for_free_compute = counted(
+                2, sched._step2_prepare_for_free_compute)
+            sched._step3_speculative_prepare = counted(
+                3, sched._step3_speculative_prepare)
+        r = sim.run()
+        runs[ref] = (sim.action_log, r.makespan)
+    assert runs[False] == runs[True]
+    assert skipped[2] > 0 and skipped[3] > 0, skipped
+
+
 @settings(max_examples=8, deadline=None)
 @given(st.integers(0, 10 ** 6))
 def test_blocked_parity_property(seed):

@@ -269,3 +269,127 @@ def test_step2_partial_present_bytes_order(vectorized):
     # node 2 already holds A, so the COP moves exactly file B from node 1
     assert [(t.file_id, t.src, t.dst) for t in plan.transfers] == \
         [(2, 1, 2)]
+
+
+# ------------------------------------------- step-2/3 emptiness pre-test
+def _pretest_sched(cls, free_cores, files, tasks, active_cops=(), **kw):
+    """Nodes of 8 GiB and 8 cores with the given free cores (and a busy
+    COP slot on the nodes in ``active_cops``), 1 MB files ``{id:
+    holders}``, and data-bound tasks ``(id, inputs, priority)`` of one
+    shape, 1 GiB and 1 core."""
+    nodes = {i: NodeState(i, mem=8 * GiB, cores=8.0, free_cores=fc,
+                          active_cops=int(i in active_cops))
+             for i, fc in enumerate(free_cores)}
+    dps = DataPlacementService(seed=0)
+    for fid, holders in files.items():
+        dps.register_file(FileSpec(fid, 1024 ** 2, 0), holders[0])
+        for n in holders[1:]:
+            dps.add_replica(fid, n)
+    sched = cls(nodes, dps, **kw)
+    for tid, inputs, prio in tasks:
+        sched.submit(TaskSpec(id=tid, abstract="a", mem=GiB, cores=1.0,
+                              inputs=inputs, priority=prio))
+    return sched
+
+
+def _cops(actions):
+    return [(a.plan.id, a.plan.task_id, a.plan.target,
+             [(t.file_id, t.src, t.dst) for t in a.plan.transfers])
+            for a in actions if isinstance(a, StartCop)]
+
+
+def _schedule_pretest(vectorized, *case, **kw):
+    """One ``schedule()`` of the case on the scheduler under test, with its
+    pre-test skips per step, beside the frozen reference's COPs."""
+    from repro.core import ReferenceWowScheduler
+    sched = _pretest_sched(WowScheduler, *case, vectorized=vectorized, **kw)
+    skipped = {2: 0, 3: 0}
+
+    def counted(step, run_step):
+        def run(*args):
+            before = sched.drain_skipped
+            run_step(*args)
+            skipped[step] += sched.drain_skipped - before
+        return run
+    sched._step2_prepare_for_free_compute = counted(
+        2, sched._step2_prepare_for_free_compute)
+    sched._step3_speculative_prepare = counted(
+        3, sched._step3_speculative_prepare)
+    got = _cops(sched.schedule())
+    want = _cops(_pretest_sched(ReferenceWowScheduler, *case).schedule())
+    return sched, got, want, skipped
+
+
+class _CountingSet(set):
+    """A prepared set that counts the subset tests made against it."""
+    tests = 0
+
+    def __ge__(self, other):
+        _CountingSet.tests += 1
+        return set.__ge__(self, other)
+
+
+def _count_subset_tests(sched, monkeypatch):
+    read = sched.dps.prepared_node_set
+    monkeypatch.setattr(sched.dps, "prepared_node_set",
+                        lambda tid: _CountingSet(read(tid)))
+    monkeypatch.setattr(_CountingSet, "tests", 0)
+
+
+@pytest.mark.parametrize("vectorized", [False, None])
+def test_step2_shape_dies_mid_pass(vectorized):
+    """Node 0 alone has free cores.  Task 1's COP takes its slot, which
+    kills the shape: task 2's probe comes back empty and proves it, and
+    tasks 3 and 4, of the same shape, are skipped -- with the reference's
+    COPs, ids and transfers unchanged."""
+    sched, got, want, skipped = _schedule_pretest(
+        vectorized, [8.0, 0.0, 0.0, 0.0], {1: [1], 2: [2], 3: [3], 4: [2]},
+        [(1, (1,), 4.0), (2, (2,), 3.0), (3, (3,), 2.0), (4, (4,), 1.0)])
+    assert skipped == {2: 2, 3: 0}
+    assert got == want
+    # step 2 prepared task 1 on node 0; step 3 then found node 3 for task 2
+    assert [(task, target) for _, task, target, _ in got] == [(1, 0), (2, 3)]
+
+
+@pytest.mark.parametrize("vectorized", [False, None])
+def test_step3_task_prepared_on_every_free_slot_node_is_skipped(vectorized):
+    """No node has free cores, and task 1's input is on both nodes: step 3
+    sees its prepared set cover the free-slot set and skips it."""
+    sched, got, want, skipped = _schedule_pretest(
+        vectorized, [0.0, 0.0], {1: [0, 1]}, [(1, (1,), 1.0)])
+    assert skipped == {2: 0, 3: 1}
+    assert got == want == []
+    assert sched.drain_probed == 2            # one visit in each step
+
+
+@pytest.mark.parametrize("vectorized", [False, None])
+def test_step3_task_prepared_on_all_but_one_free_slot_node_is_probed(
+        vectorized, monkeypatch):
+    """Task 1 is prepared on three nodes and the free-slot set has three,
+    but node 3's slot is busy and node 2 lacks the input: the subset test
+    runs, fails, and the probe starts a COP to node 2."""
+    case = ([0.0] * 4, {1: [0, 1, 3]}, [(1, (1,), 1.0)])
+    sched = _pretest_sched(WowScheduler, *case, active_cops=(3,),
+                           vectorized=vectorized)
+    _count_subset_tests(sched, monkeypatch)
+    got = _cops(sched.schedule())
+    assert _CountingSet.tests == 1
+    assert sched.drain_skipped == 0
+    from repro.core import ReferenceWowScheduler
+    ref = _pretest_sched(ReferenceWowScheduler, *case, active_cops=(3,))
+    assert got == _cops(ref.schedule())
+    assert [(task, target) for _, task, target, _ in got] == [(1, 2)]
+
+
+@pytest.mark.parametrize("vectorized", [False, None])
+def test_step3_length_guard_decides_without_subset_test(vectorized,
+                                                        monkeypatch):
+    """Four free-slot nodes against one prepared node: the length guard
+    answers and no subset test is made; the task is probed as before."""
+    sched = _pretest_sched(WowScheduler, [0.0] * 4, {1: [0]},
+                           [(1, (1,), 1.0)], vectorized=vectorized)
+    _count_subset_tests(sched, monkeypatch)
+    got = _cops(sched.schedule())
+    assert _CountingSet.tests == 0
+    assert sched.drain_skipped == 0
+    assert [(task, target) for _, task, target, _ in got] == [(1, 1)]

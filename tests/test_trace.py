@@ -28,7 +28,7 @@ SUBSPANS = ("sched.step1.refresh", "sched.step1.solve",
 def _traffic():
     return TrafficConfig(
         tenants=(TenantSpec("alice", weight=2.0,
-                            workflows=("rnaseq", "sarek"), scale=0.05,
+                            workflows=("rnaseq", "sarek"), scale=0.1,
                             slo=300.0),
                  TenantSpec("bob", weight=1.0, workflows=("group",),
                             scale=0.05, slo=400.0)),
@@ -128,6 +128,7 @@ def test_on_one_row_per_round_and_counters_add_up(trace_off):
         "step1.uniform_solves": sched.solver_stats["uniform_solves"],
         "drain.cops_started": sched.cops_created,
         "drain.tasks_probed": sched.drain_probed,
+        "drain.probes_skipped": sched.drain_skipped,
         "dps.replica_writes": sched.dps.replica_writes,
         "sim.task_starts": sim.task_starts,
         "sim.cops_scanned": sim.cops_scanned,
