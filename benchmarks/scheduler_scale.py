@@ -59,27 +59,14 @@ Two further scenarios cover this PR's other step-1 paths:
   stays fast (full-scale rows are a local/nightly tier).
 * ``run_sampled_recompute`` -- per-event recompute latency at
   4096/16384/65536 nodes via *sampled-recompute timing*: instead of whole
-  runs (unaffordable past 4096 for the dict path) it times a fixed sample
-  of schedule() recomputes against a jittered busy-cluster snapshot, for
-  the vectorized ``NodeCapacityArray`` path, the PR-5 dict path and the
-  frozen reference, asserting the action streams stay bit-identical.
-  Headline keys ``sampled_recompute`` / ``scale_speedup``.
-* ``run_e2e_vectorized`` -- full wow runs with ``vectorized=False`` vs
-  ``True`` (bit-identical action log + makespan asserted), recording the
-  end-to-end before/after of the vectorized hot state.  Headline key
-  ``e2e_vectorized``.
-* ``run_batched_drain`` -- the blocked step-2/3 placement kernel
-  (``core/copmatrix.py``) vs the pre-kernel masked path vs the per-task
-  dict oracle, on a fan-in drain workload (2-input tasks over 3-way
-  replicated files, cold burst + completion waves), flat and multi-site,
-  with every round's action stream asserted bit-identical and a
-  ``_BATCHED_MIN_SPEEDUP``x step-2/3 phase floor at the flat headline
-  point.  Headline key ``batched_drain``.
+  runs it times a fixed sample of schedule() recomputes against a
+  jittered busy-cluster snapshot, for ``WowScheduler`` and the frozen
+  reference, asserting the action streams stay bit-identical.  Headline
+  key ``sampled_recompute``.
 
 Results land in BENCH_scheduler_scale.json; headline numbers are the
 sustained speedup and the phase times on the (1024 nodes, 4096 ready
-tasks) row, plus ``scale_speedup`` (dict/vectorized per-recompute ratio
-at 4096 nodes).
+tasks) row.
 """
 from __future__ import annotations
 
@@ -90,9 +77,9 @@ import sys
 import time
 
 import repro.core.reference as _reference
-from repro.core import (HAVE_NUMPY, DataPlacementService, FileSpec,
-                        NodeState, ReferenceWowScheduler, StartTask,
-                        TaskSpec, WowScheduler, trace)
+from repro.core import (DataPlacementService, FileSpec, NodeState,
+                        ReferenceWowScheduler, StartTask, TaskSpec,
+                        WowScheduler, trace)
 
 from .common import emit, write_json
 
@@ -430,37 +417,32 @@ def run_dfs_churn(fail_t: float = 30.0, fail_node: int = 1) -> dict:
 
 
 # --------------------------------------- sampled-recompute at extreme scale
-# Timing whole runs past 4096 nodes is unaffordable (the dict path alone
-# would take hours at 65536), so this scenario times a *fixed sample of
-# recompute events* against a synthetic mid-run cluster snapshot instead:
+# Timing whole runs past 4096 nodes is unaffordable, so this scenario times
+# a *fixed sample of recompute events* against a synthetic mid-run cluster
+# snapshot instead:
 #
-# * every node is partially busy with jittered free capacities, so the dict
-#   ``CapacityClasses`` degenerates to ~one class per node and each fitting
-#   query walks (and sorts) O(n) entries -- the regime the vectorized
-#   ``NodeCapacityArray`` replaces with one masked argwhere pass;
+# * every node is partially busy with jittered free capacities, so each
+#   fitting query faces ~one capacity class per node -- one masked pass
+#   over ``NodeCapacityArray``;
 # * each sampled event submits ``RECOMP_K`` input-less tasks (the fan-out
 #   shape that dominates large waves), times one ``schedule()`` recompute,
 #   then finishes the placed tasks so the snapshot returns to steady state.
 #
-# Rows cover the vectorized path, the PR-5 dict path (``vectorized=False``)
-# and the frozen reference (few samples; capped at
-# ``_RECOMP_REFERENCE_MAX_NODES`` -- its per-event rebuild is O(n) per ready
-# task).  The three paths consume one shared RNG schedule, and the bench
-# asserts the per-event action streams are bit-identical (dict == vectorized
-# in full; reference as a prefix).  Headline keys
-# ``sampled_recompute_ms_*`` and ``scale_speedup`` (dict/vectorized at
-# ``_RECOMP_HEADLINE_NODES``).
+# Rows cover ``WowScheduler`` and the frozen reference (few samples; capped
+# at ``_RECOMP_REFERENCE_MAX_NODES`` -- its per-event rebuild is O(n) per
+# ready task).  Both consume one shared RNG schedule, and the bench asserts
+# the reference's per-event action stream is a prefix of the scheduler's.
+# Headline key ``sampled_recompute``.
 RECOMP_SIZES = [4096, 16384, 65536]
 RECOMP_SMOKE_SIZES = [512]
 RECOMP_K = 32                       # tasks per sampled recompute event
-RECOMP_SAMPLES = {"vectorized": 20, "dict": 20, "reference": 3}
+RECOMP_SAMPLES = {"indexed": 20, "reference": 3}
 _RECOMP_REFERENCE_MAX_NODES = 16384
-_RECOMP_HEADLINE_NODES = 4096
 
 
-def build_busy(n_nodes: int, cls, seed: int = 0, vectorized=None):
+def build_busy(n_nodes: int, cls, seed: int = 0):
     """A mid-run cluster snapshot: every node partially busy with jittered
-    free capacities (distinct (free_mem, free_cores) pairs => ~one dict
+    free capacities (distinct (free_mem, free_cores) pairs => ~one
     capacity class per node), every node still fitting the probe shape (so
     candidate lists stay O(n), like a real half-loaded wave)."""
     rng = random.Random(seed)
@@ -471,8 +453,6 @@ def build_busy(n_nodes: int, cls, seed: int = 0, vectorized=None):
         s.free_cores = 6.0 + 0.5 * rng.randrange(0, 13)
         nodes[i] = s
     dps = DataPlacementService(seed=seed)
-    if cls is WowScheduler:
-        return cls(nodes, dps, vectorized=vectorized), rng
     return cls(nodes, dps), rng
 
 
@@ -481,11 +461,8 @@ def _sampled_recompute_one(n_nodes: int, impl: str, samples: int,
     """Time ``samples`` recompute events (plus one unmeasured warm-up) and
     return per-event ms and the summarized action stream for the parity
     assertion."""
-    if impl == "reference":
-        sched, rng = build_busy(n_nodes, ReferenceWowScheduler, seed)
-    else:
-        sched, rng = build_busy(n_nodes, WowScheduler, seed,
-                                vectorized=(impl == "vectorized"))
+    cls = ReferenceWowScheduler if impl == "reference" else WowScheduler
+    sched, rng = build_busy(n_nodes, cls, seed)
     next_id = 0
     log: list[list] = []
     total = 0.0
@@ -512,15 +489,12 @@ def run_sampled_recompute(sizes: list[int] | None = None,
     if sizes is None:
         sizes = RECOMP_SMOKE_SIZES if bench_smoke() else RECOMP_SIZES
     rows: list[dict] = []
-    speedups: dict[int, float] = {}
     per_size_ms: dict[int, dict[str, float]] = {}
     emit("scheduler_scale,sampled_recompute,impl,nodes,k,samples,"
          "ms_per_recompute")
     for n_nodes in sizes:
         res: dict[str, dict] = {}
-        for impl in ("vectorized", "dict", "reference"):
-            if impl == "vectorized" and not HAVE_NUMPY:
-                continue
+        for impl in ("indexed", "reference"):
             if impl == "reference" and n_nodes > _RECOMP_REFERENCE_MAX_NODES:
                 continue
             samples = RECOMP_SAMPLES[impl]
@@ -531,283 +505,22 @@ def run_sampled_recompute(sizes: list[int] | None = None,
             emit(f"scheduler_scale,sampled_recompute,{impl},{n_nodes},"
                  f"{RECOMP_K},{samples},"
                  f"{res[impl]['ms_per_recompute']:.3f}")
-        # bit-parity across paths on the shared event schedule
-        if "vectorized" in res:
-            assert res["vectorized"]["log"] == res["dict"]["log"], (
-                f"sampled_recompute@{n_nodes}: vectorized path diverged "
-                f"from the dict path")
-            if "reference" in res:
-                k = len(res["reference"]["log"])
-                assert res["reference"]["log"] == res["dict"]["log"][:k], (
-                    f"sampled_recompute@{n_nodes}: dict path diverged from "
-                    f"the reference")
-            speedups[n_nodes] = (res["dict"]["ms_per_recompute"]
-                                 / max(res["vectorized"]["ms_per_recompute"],
-                                       1e-9))
+        # bit-parity on the shared event schedule
+        if "reference" in res:
+            k = len(res["reference"]["log"])
+            assert res["reference"]["log"] == res["indexed"]["log"][:k], (
+                f"sampled_recompute@{n_nodes}: scheduler diverged from "
+                f"the reference")
         per_size_ms[n_nodes] = {i: r["ms_per_recompute"]
                                 for i, r in res.items()}
-    head_nodes = (_RECOMP_HEADLINE_NODES
-                  if _RECOMP_HEADLINE_NODES in speedups
-                  else (max(speedups) if speedups else None))
-    scale_speedup = speedups.get(head_nodes) if head_nodes else None
-    if scale_speedup is not None:
-        emit(f"scheduler_scale,scale_speedup_{head_nodes}n,"
-             f"{scale_speedup:.1f}x")
     headline = {
         "k": RECOMP_K,
         "sizes": sizes,
         "ms_per_recompute": {str(n): ms
                              for n, ms in sorted(per_size_ms.items())},
-        "speedups": {str(n): sp for n, sp in sorted(speedups.items())},
-        "scale_speedup_nodes": head_nodes,
-        "scale_speedup": scale_speedup,
     }
     return rows, headline
 
-
-# ------------------------------------- end-to-end vectorization before/after
-# Tentpole part 4: the e2e profile at 4096 nodes showed ``schedule()`` is
-# ~84% of a full wow run (cold ``_greedy_uniform`` + the step-2 scan/sort),
-# so the measured fix for the top non-fill cost *is* the vectorized hot
-# state plus the shared step-2 micro-fixes.  This scenario records the
-# before/after: one full wow run per size with ``vectorized=False`` (the
-# PR-5 path, all shared fixes included) vs ``vectorized=True``, asserting
-# the action log and makespan are bit-identical.  Headline key
-# ``e2e_vectorized`` with ``e2e_speedup`` at the largest size.
-E2E_SIZES = [(1024, 10.24), (4096, 20.48)]
-E2E_SMOKE_SIZES = [(128, 1.28)]
-
-
-def run_e2e_vectorized(sizes: list[tuple[int, float]] | None = None,
-                       ) -> tuple[list[dict], dict]:
-    from repro.sim import SimConfig, Simulation
-    from repro.workloads import make_workflow
-
-    if sizes is None:
-        sizes = E2E_SMOKE_SIZES if bench_smoke() else E2E_SIZES
-    rows: list[dict] = []
-    speedups: dict[int, float] = {}
-    emit("scheduler_scale,e2e_vectorized,nodes,vectorized,wall_s,makespan")
-    for n_nodes, scale in sizes:
-        walls: dict[bool, float] = {}
-        logs: dict[bool, list] = {}
-        makespans: dict[bool, float] = {}
-        for vec in ([False, True] if HAVE_NUMPY else [False]):
-            wf = make_workflow(SIM_WORKFLOW, scale=scale)
-            cfg = SimConfig(n_nodes=n_nodes, dfs="ceph", vectorized=vec)
-            sim = Simulation(wf, cfg, "wow")
-            t0 = time.perf_counter()
-            r = sim.run()
-            walls[vec] = time.perf_counter() - t0
-            logs[vec] = sim.action_log
-            makespans[vec] = r.makespan
-            rows.append({"impl": "vectorized" if vec else "dict",
-                         "scenario": "e2e_vectorized", "nodes": n_nodes,
-                         "tasks": r.tasks_total, "wall_s": walls[vec],
-                         "makespan": r.makespan})
-            emit(f"scheduler_scale,e2e_vectorized,{n_nodes},{vec},"
-                 f"{walls[vec]:.2f},{r.makespan:.2f}")
-        if True in walls:
-            assert logs[True] == logs[False], (
-                f"e2e_vectorized@{n_nodes}: action log diverged")
-            assert makespans[True] == makespans[False], (
-                f"e2e_vectorized@{n_nodes}: makespan diverged")
-            speedups[n_nodes] = walls[False] / max(walls[True], 1e-9)
-    head_nodes = max(speedups) if speedups else None
-    e2e_speedup = speedups.get(head_nodes) if head_nodes else None
-    if e2e_speedup is not None:
-        emit(f"scheduler_scale,e2e_speedup_{head_nodes}n,{e2e_speedup:.1f}x")
-    headline = {
-        "workflow": SIM_WORKFLOW,
-        "sizes": [n for n, _ in sizes],
-        "speedups": {str(n): sp for n, sp in sorted(speedups.items())},
-        "e2e_speedup_nodes": head_nodes,
-        "e2e_speedup": e2e_speedup,
-    }
-    return rows, headline
-
-
-# --------------------------------------------------- batched COP drain
-# The blocked step-2/3 placement kernel (core/copmatrix.py) vs the retained
-# per-task machinery, in the regime the kernel targets: a *fan-in drain*.
-# Every task needs two inputs that live on disjoint random hosts (so no
-# task is born prepared and step 1 cannot short-circuit the drain), each
-# input replicated 3 ways (so ``cop_feasible_targets`` stays unconstrained
-# -- a constrained pool legally bypasses the kernel).  A cold burst fills
-# the whole COP-slot budget through step-2 argmins over every node, then
-# each wave round finishes the entire running/in-flight set (a workflow
-# wave ending) and re-drains.  The single-event sustained stream of the
-# headline rows is the *wrong* regime for this kernel: one finished COP
-# frees one slot, so the per-task path touches ~1 candidate and there is
-# nothing to batch.
-#
-# Three impls, all the same ``WowScheduler``: ``blocked`` (batched=True),
-# ``masked`` (vectorized hot state, per-task loop -- the pre-kernel
-# production path, isolating this PR's gain from the earlier cap-array
-# PR's), and ``per_task`` (vectorized=False -- the dict oracle the kernel
-# is property-tested against).  The ``sched.step2`` + ``sched.step3``
-# trace spans are directly comparable across them; every schedule()
-# round's action stream is summarized and asserted bit-identical, flat
-# *and* under a multi-site topology (the locality-cost kernel branch,
-# where the dict path pays a per-candidate ``locality_missing_cost``
-# call).  ``BENCH_JAX=1`` adds the
-# jit-compiled winner reduction as a fourth impl (identity asserted, no
-# speedup claim -- jit dispatch only pays off on accelerators).  Full tier
-# asserts the blocked kernel's step-2/3 phase is >= ``_BATCHED_MIN_SPEEDUP``x
-# the per-task oracle at the flat headline point; the step-3 probe loop
-# stays scalar in all impls (every feasible probe consumes a COP id, see
-# scheduler.py), so the speedup is pure candidate-construction batching.
-BD_SIZES = [(512, 2048), (1024, 4096)]
-BD_SMOKE_SIZES = [(32, 128)]
-BD_WAVES = 3
-BD_TOPO: dict[str, dict | None] = {
-    "flat": None,
-    "site": {"rack_size": 32, "racks_per_site": 4, "oversubscription": 8.0},
-}
-_BD_IMPLS: dict[str, tuple[bool | None, bool | str]] = {
-    "blocked": (None, True),        # (vectorized, batched)
-    "masked": (None, False),
-    "per_task": (False, False),
-}
-_BATCHED_MIN_SPEEDUP = 2.0
-
-
-def _bd_submit(sched, dps, rng, n_nodes: int, tid: int, fid: int) -> int:
-    """Submit one fan-in task: two fresh inputs on disjoint random hosts,
-    each replicated 3 ways.  Returns the next free file id."""
-    for _ in range(2):
-        hosts = rng.sample(range(n_nodes), 3)
-        dps.register_file(FileSpec(id=fid, size=rng.randint(1, 4) * GiB,
-                                   producer=-1), hosts[0])
-        for h in hosts[1:]:
-            dps.add_replica(fid, h)
-        fid += 1
-    sched.submit(TaskSpec(id=tid, abstract="a", mem=TASK_MEM,
-                          cores=TASK_CORES, inputs=(fid - 2, fid - 1),
-                          priority=rng.uniform(1, 10)))
-    return fid
-
-
-def _bd_build(n_nodes: int, n_ready: int, vectorized, batched, topo_params,
-              seed: int = 0):
-    rng = random.Random(seed)
-    nodes = {i: NodeState(i, 128 * GiB, 16.0) for i in range(n_nodes)}
-    dps = DataPlacementService(seed=seed)
-    if topo_params is not None:
-        from repro.sim import Topology, TopologySpec
-        dps.set_topology(Topology(TopologySpec(**topo_params), n_nodes,
-                                  100.0))
-    sched = WowScheduler(nodes, dps, vectorized=vectorized, batched=batched)
-    fid = 10 ** 6                   # file ids disjoint from task ids
-    for t in range(n_ready):
-        fid = _bd_submit(sched, dps, rng, n_nodes, t, fid)
-    return sched, dps, rng, fid
-
-
-def _bd_wave(sched, dps, rng, n_nodes: int, next_id: int, fid: int):
-    """One drain wave: finish every running task and every in-flight COP
-    (a workflow wave ending), submit one fresh fan-in task per finished
-    task so the backlog stays fan-heavy, then schedule().  Returns
-    ``(actions, next_id, fid)``."""
-    finished = list(sched.running.items())
-    for tid, node in finished:
-        sched.on_task_finished(tid, node)
-    for cid in list(sched.active_cops):
-        sched.on_cop_finished(sched.active_cops[cid], ok=True)
-    for _ in range(len(finished)):
-        fid = _bd_submit(sched, dps, rng, n_nodes, next_id, fid)
-        next_id += 1
-    return sched.schedule(), next_id, fid
-
-
-@_traced()
-def run_batched_drain(sizes: list[tuple[int, int]] | None = None,
-                      ) -> tuple[list[dict], dict]:
-    smoke = bench_smoke()
-    if sizes is None:
-        sizes = BD_SMOKE_SIZES if smoke else BD_SIZES
-    impls = dict(_BD_IMPLS)
-    if os.environ.get("BENCH_JAX"):
-        impls["jax"] = (None, "jax")
-    rows: list[dict] = []
-    step23: dict[tuple[int, str, str], float] = {}
-    speedups: dict[tuple[int, str], float] = {}
-    emit("scheduler_scale,batched_drain,impl,nodes,tasks,topo,"
-         "cold_step23_ms,step23_ms_total,round_ms,actions_per_round")
-    for n_nodes, n_ready in sizes:
-        for topo_name, params in BD_TOPO.items():
-            streams: dict[str, list] = {}
-            for impl, (vec, batched) in impls.items():
-                sched, dps, rng, fid = _bd_build(n_nodes, n_ready, vec,
-                                                 batched, params)
-                next_id = n_ready
-                s0 = _span_seconds("sched.step2", "sched.step3")
-                t0 = time.perf_counter()
-                summaries = [_summarize(sched.schedule())]
-                cold_ms = (_span_seconds("sched.step2", "sched.step3")
-                           - s0) * 1000
-                actions = 0
-                for _ in range(BD_WAVES):
-                    acts, next_id, fid = _bd_wave(sched, dps, rng,
-                                                  n_nodes, next_id, fid)
-                    summaries.append(_summarize(acts))
-                    actions += len(acts)
-                wall_ms = ((time.perf_counter() - t0) * 1000
-                           / (BD_WAVES + 1))
-                s23_ms = (_span_seconds("sched.step2", "sched.step3")
-                          - s0) * 1000
-                streams[impl] = summaries
-                step23[(n_nodes, topo_name, impl)] = s23_ms
-                rows.append({"impl": impl, "scenario": "batched_drain",
-                             "nodes": n_nodes, "tasks": n_ready,
-                             "topo": topo_name, "cold_step23_ms": cold_ms,
-                             "step23_ms": s23_ms, "round_ms": wall_ms,
-                             "waves": BD_WAVES,
-                             "actions_per_round": actions / BD_WAVES})
-                emit(f"scheduler_scale,batched_drain,{impl},{n_nodes},"
-                     f"{n_ready},{topo_name},{cold_ms:.1f},{s23_ms:.1f},"
-                     f"{wall_ms:.1f},{actions / BD_WAVES:.1f}")
-            base = streams["per_task"]
-            for impl, stream in streams.items():
-                assert stream == base, (
-                    f"batched_drain@{n_nodes}/{topo_name}: {impl} kernel "
-                    f"diverged from the per-task oracle")
-            speedups[(n_nodes, topo_name)] = (
-                step23[(n_nodes, topo_name, "per_task")]
-                / max(step23[(n_nodes, topo_name, "blocked")], 1e-9))
-            emit(f"scheduler_scale,batched_drain_speedup_{n_nodes}n_"
-                 f"{topo_name},{speedups[(n_nodes, topo_name)]:.1f}x")
-    head_n = max(n for n, _ in sizes)
-    head_speedup = speedups[(head_n, "flat")]
-    # The floor is a claim about clean timings: cProfile's per-call hook
-    # taxes the two impls unequally (the dict path is call-heavy, the
-    # blocked path spends its time inside few numpy calls), so a
-    # `benchmarks.run --profile` pass measures the profiler, not the
-    # kernel -- warn instead of failing there.
-    profiled = sys.getprofile() is not None
-    if not smoke and not profiled:
-        assert head_speedup >= _BATCHED_MIN_SPEEDUP, (
-            f"batched_drain@{head_n}: blocked step-2/3 only "
-            f"{head_speedup:.2f}x the per-task path (floor "
-            f"{_BATCHED_MIN_SPEEDUP}x)")
-    elif profiled and head_speedup < _BATCHED_MIN_SPEEDUP:
-        emit(f"scheduler_scale,batched_drain_floor_skipped_under_profiler,"
-             f"{head_speedup:.2f}x")
-    headline = {
-        "sizes": [n for n, _ in sizes],
-        "impls": list(impls),
-        "topologies": list(BD_TOPO),
-        "waves": BD_WAVES,
-        "identical_actions": True,
-        "step23_ms": {f"{n}:{t}:{i}": ms
-                      for (n, t, i), ms in sorted(step23.items())},
-        "step23_speedup": {f"{n}:{t}": sp
-                           for (n, t), sp in sorted(speedups.items())},
-        "headline_nodes": head_n,
-        "headline_speedup": head_speedup,
-        "site_speedup": speedups[(head_n, "site")],
-    }
-    return rows, headline
 
 # ------------------------------------------------- hierarchical topology
 # Same full-workflow runs as sim_throughput, but under the hierarchical
@@ -933,8 +646,8 @@ def run_topology(sizes: list[tuple[int, float]] | None = None,
     fill_speedup = fill_eps["heap"] / max(fill_eps["scan"], 1e-9)
     emit(f"scheduler_scale,topology_fill_speedup_{n_fill}n,"
          f"{fill_speedup:.1f}x")
-    # Same clean-timings-only rule as the batched_drain floor: under
-    # cProfile the ratio measures per-call hook overhead, not the fill.
+    # A claim about clean timings: under cProfile the ratio measures
+    # per-call hook overhead, not the fill.
     if not smoke and sys.getprofile() is None:
         assert fill_speedup >= _TOPO_FILL_MIN_SPEEDUP, (
             f"topology@{n_fill}: path-constrained heap fill only "
@@ -1330,18 +1043,9 @@ def main() -> list[dict]:
     sim_rows, sim_head = run_sim_throughput()
     rows.extend(sim_rows)
 
-    # sampled-recompute timing at extreme scale (vectorized vs dict vs ref)
+    # sampled-recompute timing at extreme scale (scheduler vs reference)
     rec_rows, rec_head = run_sampled_recompute()
     rows.extend(rec_rows)
-
-    # full-run before/after of the vectorized hot state (bit-parity asserted)
-    e2e_rows, e2e_head = run_e2e_vectorized()
-    rows.extend(e2e_rows)
-
-    # blocked step-2/3 placement kernel vs the per-task dict oracle
-    # (per-round action bit-identity asserted, flat + multi-site)
-    bd_rows, bd_head = run_batched_drain()
-    rows.extend(bd_rows)
 
     # open-loop multi-tenant traffic: identical arrival streams, three
     # strategies, SLO/fairness service metrics
@@ -1390,9 +1094,6 @@ def main() -> list[dict]:
                      "inputless_stats": less["indexed"]["inputless_stats"],
                      "sim_throughput": sim_head,
                      "sampled_recompute": rec_head,
-                     "scale_speedup": rec_head["scale_speedup"],
-                     "e2e_vectorized": e2e_head,
-                     "batched_drain": bd_head,
                      "multi_tenant": mt_head,
                      "topology": topo_head,
                      "live_rm": live,

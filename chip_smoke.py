@@ -1,34 +1,23 @@
-"""Smoke run of the main path on one TPU chip.
+"""Smoke run of the model code on one TPU chip.
 
     python chip_smoke.py
 
-Two phases run in this one process; the script starts no other (a chip
-belongs to one process at a time).
-
-1. Scheduler: the WOW decision path with its device twin of the step-2
-   winner reduction (``batched="jax"``) on a 1024-node flat cluster --
-   the fan-in drain traffic of ``benchmarks/scheduler_scale.py``
-   (``run_batched_drain``) driven through ``make_adapter``, and a whole
-   ``rnaseq`` workflow in the simulator.  Each action stream (and the
-   workflow's makespan) must be bit-identical to the host path
-   (``batched=True``), the drain must dispatch to the TPU, and the
-   process-wide ``jax_enable_x64`` must be unchanged afterwards.
-2. Model: mamba2-780m at its published widths with the Pallas SSD kernel
-   (``kernel_mode="pallas"``).  One SSD layer in f32 and the bf16 prefill
-   logits are compared with ``kernel_mode="ref"`` on the same chip, both
-   sides at full f32 matmul precision; the
-   continuous-batching ``ServingEngine`` answers 8 requests and the
-   ``Trainer`` takes 5 steps.  Weights are random, made from a seed.
+It runs in this one process and starts no other (a chip belongs to one
+process at a time): mamba2-780m at its published widths with the Pallas
+SSD kernel (``kernel_mode="pallas"``).  One SSD layer in f32 and the bf16
+prefill logits are compared with ``kernel_mode="ref"`` on the same chip,
+both sides at full f32 matmul precision; the continuous-batching
+``ServingEngine`` answers 8 requests and the ``Trainer`` takes 5 steps.
+Weights are random, made from a seed.
 
 Any failed check exits non-zero.  Where JAX finds no TPU the script exits
-non-zero before any phase and prints no result.  The last line of stdout
+non-zero before it starts and prints no result.  The last line of stdout
 is ``{"ok": true, "device": {"platform", "kind", "count"}}``.
 """
 from __future__ import annotations
 
 import json
 import os
-import random
 import sys
 import time
 
@@ -62,99 +51,6 @@ def rel_err(a, ref) -> float:
     a = np.asarray(a, np.float64)
     ref = np.asarray(ref, np.float64)
     return float(np.linalg.norm(a - ref) / np.linalg.norm(ref))
-
-
-# ------------------------------------------------------------- scheduler
-def drain_run(batched, n_nodes: int, n_ready: int, waves: int,
-              seed: int = 0) -> tuple[list[str], dict]:
-    """Fan-in drain through the adapter: 2-input tasks over 3-way
-    replicated files on random hosts, a cold burst, then ``waves`` rounds
-    that finish every running task and COP and resubmit as many tasks.
-    Returns the ``repr`` of each round's actions and the twin's stats."""
-    from repro.core import FileSpec, NodeState, TaskSpec, make_adapter
-
-    rng = random.Random(seed)
-    nodes = {i: NodeState(i, 128 * GiB, 16.0) for i in range(n_nodes)}
-    wow = make_adapter("wow", nodes, seed=seed, batched=batched)
-    next_file = 10 ** 6                  # file ids disjoint from task ids
-
-    def submit(tid: int) -> None:
-        nonlocal next_file
-        for _ in range(2):
-            hosts = rng.sample(range(n_nodes), 3)
-            wow.dps.register_file(
-                FileSpec(id=next_file, size=rng.randint(1, 4) * GiB,
-                         producer=-1), hosts[0])
-            for h in hosts[1:]:
-                wow.dps.add_replica(next_file, h)
-            next_file += 1
-        wow.submit(TaskSpec(id=tid, abstract="a", mem=48 * GiB, cores=6.0,
-                            inputs=(next_file - 2, next_file - 1),
-                            priority=rng.uniform(1, 10)))
-
-    for tid in range(n_ready):
-        submit(tid)
-    rounds = [repr(wow.schedule())]
-    next_id = n_ready
-    for _ in range(waves):
-        finished = list(wow.sched.running.items())
-        for tid, node in finished:
-            wow.task_finished(tid, node)
-        for plan in list(wow.sched.active_cops.values()):
-            wow.cop_finished(plan, ok=True)
-        for _ in finished:
-            submit(next_id)
-            next_id += 1
-        rounds.append(repr(wow.schedule()))
-    return rounds, wow.sched.device_stats
-
-
-def workflow_run(batched, n_nodes: int, scale: float) -> tuple[tuple, dict]:
-    """One whole ``rnaseq`` workflow in the simulator (the engine that
-    ``run_workflow`` wraps, kept here for its action log).  Returns
-    ``(action log, makespan repr, task count)`` and the twin's stats."""
-    from repro.sim import SimConfig, Simulation
-    from repro.workloads import make_workflow
-
-    wf = make_workflow("rnaseq", scale=scale, seed=0)
-    n_tasks = len(wf.tasks)
-    sim = Simulation(wf, SimConfig(n_nodes=n_nodes, batched=batched), "wow")
-    res = sim.run()
-    return ((sim.action_log, repr(res.makespan), n_tasks),
-            sim.strategy.sched.device_stats)
-
-
-def scheduler_phase(n_nodes: int = 1024, n_ready: int = 4096,
-                    waves: int = 3, rnaseq_scale: float = 64.0) -> dict:
-    """Device twin vs host path on the drain and on ``rnaseq``; returns
-    the twin's dispatch count and result platforms for each run."""
-    import jax
-
-    x64_before = jax.config.jax_enable_x64
-    host, _ = drain_run(True, n_nodes, n_ready, waves)
-    dev, drain_stats = drain_run("jax", n_nodes, n_ready, waves)
-    check(dev == host, "drain: the jax twin's action stream differs from "
-                       "the host path")
-    n_actions = sum(r.count("Start") for r in host)
-    log(f"scheduler drain: {n_nodes} nodes, {n_ready} ready tasks, "
-        f"{waves} waves, {n_actions} actions bit-identical to the host "
-        f"path; device dispatches {drain_stats['dispatches']}, result "
-        f"platforms {drain_stats['platforms']}")
-
-    host_wf, _ = workflow_run(True, n_nodes, rnaseq_scale)
-    dev_wf, wf_stats = workflow_run("jax", n_nodes, rnaseq_scale)
-    check(dev_wf == host_wf, "rnaseq: the jax twin's action log or "
-                             "makespan differs from the host path")
-    log(f"scheduler rnaseq: scale {rnaseq_scale}, {host_wf[2]} tasks, "
-        f"{len(host_wf[0])} actions, makespan {host_wf[1]} s (simulated), "
-        f"bit-identical to the host path; device dispatches "
-        f"{wf_stats['dispatches']}, result platforms "
-        f"{wf_stats['platforms']}")
-
-    check(jax.config.jax_enable_x64 == x64_before,
-          "the scheduler phase changed jax_enable_x64")
-    log(f"jax_enable_x64 before and after: {x64_before}")
-    return {"drain": drain_stats, "rnaseq": wf_stats}
 
 
 # ----------------------------------------------------------------- model
@@ -287,15 +183,6 @@ def main() -> int:
 
     log(f"device: {dev.platform} {dev.device_kind} x{len(devices)}; "
         f"compile cache: {enable_compile_cache()}")
-    sched = scheduler_phase()
-    check(sched["drain"]["dispatches"] > 0,
-          "the drain never dispatched to the device")
-    for run, stats in sched.items():
-        check(stats["platforms"] in ([], ["tpu"]),
-              f"{run}: device results on {stats['platforms']}")
-    check(sched["drain"]["platforms"] == ["tpu"],
-          "drain results did not come from the TPU")
-
     model_phase(CONFIG.replace(kernel_mode="pallas"))
     peak = dev.memory_stats()["peak_bytes_in_use"]
     log(f"device memory peak: {peak} bytes ({peak / GiB:.2f} GiB)")

@@ -10,19 +10,18 @@ from .ilp import (AssignmentProblem, FingerprintCache,
                   IncrementalAssignmentSolver, component_fingerprint,
                   decompose, solve, solve_exact, solve_greedy,
                   solve_monolithic)
-from .nodearray import (HAVE_NUMPY, ArrayCapacityClasses, NodeCapacityArray)
+from .nodearray import NodeCapacityArray
 from .priority import abstract_ranks, assign_priorities, priority_value
-from .readyset import CapacityClasses, NodeOrder, ReadySet, ShapeIndex
+from .readyset import NodeOrder, ReadySet, ShapeIndex
 from .reference import ReferenceWowScheduler
 from .scheduler import WowScheduler
 from .types import (Action, CopPlan, DFS_LOC, FileSpec, NodeState, StartCop,
                     StartTask, TaskSpec, Transfer)
 
 __all__ = [
-    "ADAPTER_API", "Action", "ArrayCapacityClasses", "AssignmentProblem",
-    "CapacityClasses",
+    "ADAPTER_API", "Action", "AssignmentProblem",
     "CopPlan", "CwsAdapter", "DFS_LOC", "DataPlacementService", "FileSpec",
-    "FingerprintCache", "HAVE_NUMPY", "IncrementalAssignmentSolver",
+    "FingerprintCache", "IncrementalAssignmentSolver",
     "NodeCapacityArray", "NodeOrder", "NodeState", "OrigAdapter", "ReadySet",
     "ReferenceWowScheduler", "RuntimeAdapter", "ShapeIndex", "StartCop",
     "StartTask", "TaskSpec", "Transfer", "WowAdapter", "WowScheduler",

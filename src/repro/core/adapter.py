@@ -267,10 +267,8 @@ class WowAdapter(RuntimeAdapter):
                  c_task: int = 2, seed: int = 0,
                  reference_core: bool = False,
                  node_order: NodeOrder | None = None,
-                 vectorized: bool | None = None,
                  strict_parity: bool = True,
-                 topology=None,
-                 batched: bool | str | None = None) -> None:
+                 topology=None) -> None:
         super().__init__(nodes)
         if node_order is None:
             node_order = NodeOrder(nodes)
@@ -280,16 +278,14 @@ class WowAdapter(RuntimeAdapter):
             # topology detaches inside set_topology (bit-identical runs)
             self.dps.set_topology(topology)
         if reference_core:
-            # the frozen reference has no vectorized path (and no decline
-            # support) by design
+            # the frozen reference has no decline support by design
             self.sched = ReferenceWowScheduler(
                 nodes, self.dps, c_node=c_node, c_task=c_task,
                 node_order=node_order)
         else:
             self.sched = WowScheduler(
                 nodes, self.dps, c_node=c_node, c_task=c_task,
-                node_order=node_order, vectorized=vectorized,
-                strict_parity=strict_parity, batched=batched)
+                node_order=node_order, strict_parity=strict_parity)
         self._specs: dict[int, TaskSpec] = {}
 
     @property
@@ -338,10 +334,8 @@ def make_adapter(name: str, nodes: dict[int, NodeState], *, c_node: int = 1,
                  c_task: int = 2, seed: int = 0,
                  reference_core: bool = False,
                  node_order: NodeOrder | None = None,
-                 vectorized: bool | None = None,
                  strict_parity: bool = True,
-                 topology=None,
-                 batched: bool | str | None = None) -> RuntimeAdapter:
+                 topology=None) -> RuntimeAdapter:
     if name == "orig":
         return OrigAdapter(nodes)
     if name == "cws":
@@ -349,7 +343,6 @@ def make_adapter(name: str, nodes: dict[int, NodeState], *, c_node: int = 1,
     if name == "wow":
         return WowAdapter(nodes, c_node=c_node, c_task=c_task, seed=seed,
                           reference_core=reference_core,
-                          node_order=node_order, vectorized=vectorized,
-                          strict_parity=strict_parity, topology=topology,
-                          batched=batched)
+                          node_order=node_order,
+                          strict_parity=strict_parity, topology=topology)
     raise ValueError(f"unknown strategy {name!r}")

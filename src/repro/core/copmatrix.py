@@ -5,8 +5,8 @@ executed task-at-a-time: per ready task the scheduler built a Python
 candidate list over the free-slot pool, sorted it with a per-node lambda
 key (``locality_missing_cost`` / ``present_bytes_map``) and probed
 ``plan_cop`` node by node.  This module batches that inner machinery
-(DESIGN.md "Batched COP drain") while staying **bit-identical** to the
-retained per-task dict oracle:
+(DESIGN.md "Batched COP drain") while staying **bit-identical** to that
+per-task sort, which ``core.reference.ReferenceWowScheduler`` keeps:
 
 * :class:`CopMatrix` -- dense ``(tracked task row) x (node column)``
   mirrors of the DPS per-(task, node) present-input counters and
@@ -18,7 +18,7 @@ retained per-task dict oracle:
   entry is popped -- the same-pattern twin of ``core/nodearray.py``.
   Column 0 is a permanent all-zero *null column*: nodes that hold no
   tracked bytes have no column, their gathers read 0 through it, which is
-  exactly the ``dict.get(node, 0)`` the oracle computes.
+  exactly the ``dict.get(node, 0)`` the per-task sort computes.
 * :class:`SlotColMap` -- the cached ``capacity slot -> matrix column``
   translation (int64 array), rebuilt when either side's version counter
   moves.  Stale entries for dead slots are harmless: every kernel mask
@@ -29,55 +29,34 @@ retained per-task dict oracle:
   (missing bytes, or the locality-weighted cost under a topology) and
   selects the winner by the same staged masked reductions
   ``ilp.greedy_uniform`` uses -- ``key min, then node-id
-  min`` -- so float ties split exactly as the dict path's
+  min`` -- so float ties split exactly as the per-task
   ``(cost, node)`` tuple sort does.  Only the *winning* node is then
   probed through the scalar ``plan_cop``, which is the only probe the
-  dict path performs too (an unconstrained step-2 probe always succeeds:
-  see ``_step2_probe_task``), so COP-id and tie-break-RNG consumption are
+  per-task loop performs too (an unconstrained step-2 probe always
+  succeeds: see the scheduler's step 2), so COP-id and tie-break-RNG
+  consumption are
   unchanged.  Per step-3 task only the candidate-mask construction is
   batched: every feasible probe consumes a COP id (and possibly an RNG
   draw), so the probe loop itself must stay scalar and in canonical slot
   order.
 
-Float bit-exactness of the locality cost row: the dict oracle iterates
-``dps._task_mult[task].items()`` and accumulates ``cost += size * m * w``
+Float bit-exactness of the locality cost row: ``dps.locality_missing_cost``
+iterates ``dps._task_mult[task].items()`` and accumulates ``cost += size * m * w``
 per missing file.  The kernel iterates the same dict in the same order and
 adds one length-N contribution vector per file, so every element sees the
 identical sequence of IEEE-754 additions; present holders contribute an
 exact ``0.0`` (safe: the accumulator is never ``-0.0``, all contributions
 are ``>= 0``), and the per-candidate weight is selected *without float
 arithmetic* -- the minimum over the locality classes any holder offers
-(rack / site / WAN membership counted in integers), which equals the dict
-path's ``min(topo.weight(h, node) for h in holders)`` for arbitrary
+(rack / site / WAN membership counted in integers), which equals the
+per-node ``min(topo.weight(h, node) for h in holders)`` for arbitrary
 user-set class weights.
-
-An optional ``jax.jit`` twin of the winner reduction (``use_jax``,
-:class:`JaxWinner`) finally connects the scheduler half of the repo to
-its jax half: inputs are padded to the next power of two to bound
-recompilations, and the device reduces order-preserving int64 keys under
-a call-scoped x64 setting (f32 would break tie parity, and the TPU's
-emulated f64 merges keys one ulp apart).  A
-``lax.scan`` over whole task blocks is documented as impossible without
-breaking parity -- COP starts interleave with candidate masks and every
-probe consumes stateful RNG/COP ids -- so the jax path batches the same
-per-task reduction, not the task loop (DESIGN.md "Batched COP drain").
-
-numpy is optional, matching ``core/nodearray.py``: without it the module
-imports fine, ``HAVE_NUMPY`` is False, and the scheduler keeps the
-per-task dict oracle.
 """
 from __future__ import annotations
 
-import functools
+import numpy as np
 
 from .types import NodeId
-
-try:  # optional dependency -- the dict oracle needs nothing beyond stdlib
-    import numpy as np
-    HAVE_NUMPY = True
-except ModuleNotFoundError:  # pragma: no cover - exercised on bare images
-    np = None
-    HAVE_NUMPY = False
 
 _MIN_COLS = 16
 _MIN_ROWS = 16
@@ -97,11 +76,6 @@ class CopMatrix:
     """
 
     def __init__(self) -> None:
-        if not HAVE_NUMPY:
-            raise RuntimeError(
-                "CopMatrix requires numpy; construct the scheduler with "
-                "batched=False (per-task dict oracle) on numpy-less "
-                "environments")
         self._row_of: dict[int, int] = {}
         self._col_of: dict[NodeId, int] = {}
         self._free_rows: list[int] = []
@@ -314,10 +288,7 @@ class BlockedDrainKernel:
     """
 
     def __init__(self, cap, mx: CopMatrix, c_node: int,
-                 inflight_by_task: dict[int, set[int]],
-                 use_jax: bool = False) -> None:
-        if not HAVE_NUMPY:
-            raise RuntimeError("BlockedDrainKernel requires numpy")
+                 inflight_by_task: dict[int, set[int]]) -> None:
         self.cap = cap
         self.mx = mx
         self.c_node = c_node
@@ -331,7 +302,6 @@ class BlockedDrainKernel:
         self._tier_key: tuple | None = None
         self._racks: "np.ndarray" | None = None
         self._sites: "np.ndarray" | None = None
-        self.jax_winner = JaxWinner() if use_jax else None
 
     # ---------------------------------------------------------- per event
     def begin(self) -> None:
@@ -367,7 +337,7 @@ class BlockedDrainKernel:
     def _candidate_mask(self, tid: int, t, fit: "np.ndarray",
                         ) -> "np.ndarray | None":
         """fit x free COP slot x not prepared x not inflight, or None when
-        the task has no matrix row (untracked: dict fallback)."""
+        the task has no matrix row (untracked: the caller's fallback)."""
         row = self.mx.row_of(tid)
         if row is None:
             return None
@@ -427,8 +397,8 @@ class BlockedDrainKernel:
             w = np.minimum(w, np.where(site_cnt < len(locs), w_wan, np.inf))
             contrib = sm * w
             for h in locs:
-                # present on the candidate itself: the dict loop skips the
-                # file (contributes nothing); holders outside the slot map
+                # present on the candidate itself: the per-node cost skips
+                # the file (contributes nothing); holders outside the slot map
                 # (e.g. the NFS server) still count toward the classes
                 s = slot_of.get(h)
                 if s is not None:
@@ -451,10 +421,10 @@ class BlockedDrainKernel:
 
     # ------------------------------------------------------------ queries
     def step2_winner(self, tid: int, t, dps) -> int | None:
-        """Node id the dict path's step-2 sort would probe first; None when
-        the candidate set is empty (the oracle would start nothing either);
-        -1 when the task has no matrix row -- the caller must fall back to
-        the per-task oracle, which recomputes candidates from the dicts."""
+        """Node id the per-task step-2 sort would probe first; None when
+        the candidate set is empty (nothing can start); -1 when the task has
+        no matrix row -- the caller must fall back to the per-task probe,
+        which recomputes candidates from the DPS dicts."""
         mask = self._candidate_mask(tid, t, self._fit2_mask(t.mem, t.cores))
         if mask is None:
             return -1
@@ -469,16 +439,14 @@ class BlockedDrainKernel:
             # missing bytes == total - present; the null column makes the
             # gather read 0 for colless nodes, like dict.get(node, 0).
             # Candidates holding nothing share the key, so the tie-break
-            # degenerates to id order -- the dict path's plain sort.
+            # degenerates to id order -- the per-task plain sort.
             row = self.mx.row_of(tid)
             tb = dps.task_input_bytes(tid)
             key = np.where(mask, tb - self.mx.pbytes[row].take(self._colv[:n]),
                            big)
         ids = cap._node_of[:n]
-        if self.jax_winner is not None:
-            return self.jax_winner(key, ids)
         # staged reduction, ordered like ilp.greedy_uniform: min key
-        # first, then min node id among the ties -- exactly the dict
+        # first, then min node id among the ties -- exactly the
         # tuple-compare (cost, node)
         m0 = key.min()
         tie = key == m0
@@ -504,70 +472,3 @@ class BlockedDrainKernel:
             return None
         cap = self.cap
         return cap._node_of[np.flatnonzero(mask)].tolist()
-
-
-# --------------------------------------------------------------- jax twin
-@functools.cache
-def _jax_select():
-    """Lazily built jitted staged reduction: min key, then min id among
-    the ties.  Traced and called under a scoped ``jax.enable_x64``."""
-    import jax
-    import jax.numpy as jnp
-
-    @jax.jit
-    def select(key, ids):
-        m0 = key.min()
-        return jnp.where(key == m0, ids, jnp.iinfo(ids.dtype).max).min()
-
-    return select
-
-
-def ordered_int64(key: "np.ndarray") -> "np.ndarray":
-    """Map float64 keys to int64 keys with the same order and the same ties.
-
-    The TPU has no native float64: its emulated f64 ``min``/``==`` merge
-    keys one ulp apart, which breaks tie parity with the host oracle.  The
-    IEEE bit pattern of a non-NaN double, with the magnitude bits flipped
-    for negatives, is a signed integer of the same order; ``+ 0.0`` first
-    folds ``-0.0`` into ``+0.0`` so the two zeros stay one tie."""
-    bits = (key + 0.0).view(np.int64)
-    return np.where(bits < 0, bits ^ np.int64(0x7FFFFFFFFFFFFFFF), bits)
-
-
-class JaxWinner:
-    """Device twin of the staged winner reduction in
-    :meth:`BlockedDrainKernel.step2_winner`.
-
-    The device reduces int64 only: float keys go through
-    :func:`ordered_int64` on the host, so the result is exact on every
-    backend.  64-bit types are enabled for the call alone
-    (``jax.enable_x64`` as a context), leaving the process-wide flag as
-    it was.  Inputs are padded to the next power of two (pad key and pad
-    id = int64 max), bounding recompilation at one trace per log2 size.
-
-    ``dispatches`` counts device calls and ``platforms`` holds the
-    platform of every result, so a run can show that the reduction really
-    ran on the accelerator.
-    """
-
-    def __init__(self) -> None:
-        import jax
-        self._enable_x64 = jax.enable_x64
-        self._select = _jax_select()
-        self.dispatches = 0
-        self.platforms: set[str] = set()
-
-    def __call__(self, key: "np.ndarray", ids: "np.ndarray") -> int:
-        if key.dtype.kind == "f":
-            key = ordered_int64(key)
-        n = len(key)
-        padded = 1 << max(0, (n - 1).bit_length())
-        if padded != n:
-            big = np.iinfo(np.int64).max
-            key = np.concatenate([key, np.full(padded - n, big, np.int64)])
-            ids = np.concatenate([ids, np.full(padded - n, big, np.int64)])
-        with self._enable_x64(True):
-            out = self._select(key, ids)
-        self.dispatches += 1
-        self.platforms.update(d.platform for d in out.devices())
-        return int(out)

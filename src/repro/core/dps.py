@@ -114,7 +114,7 @@ class DataPlacementService:
         self._blocked_dirty: set[int] = set()
         # ----- batched-drain matrix (core/copmatrix.py): array mirrors of
         # _present_cnt/_present_bytes, inert until enable_matrix() -- the
-        # owning scheduler calls it when its blocked step-2/3 kernel is on
+        # owning WowScheduler calls it to build its blocked step-2/3 kernel
         self._mx = None
 
     # -------------------------------------------------- batched-drain matrix

@@ -66,13 +66,10 @@ import dataclasses
 from collections import OrderedDict
 from typing import Iterable, Mapping
 
+import numpy as np
+
 from . import trace
 from .types import NodeState, TaskSpec
-
-try:  # optional; without numpy the uniform tier stays off (the loop decides)
-    import numpy as np
-except ModuleNotFoundError:  # pragma: no cover
-    np = None
 
 # Budget of B&B nodes before falling back to greedy.  Exact instances in the
 # paper are tiny; this bound keeps worst-case latency low at huge scale.
@@ -384,7 +381,7 @@ def _uniform_list(tasks: list[TaskSpec],
     ``(mem, cores)``, one positive priority and one duplicate-free
     candidate list (identity is tested first: the input-less path hands
     every task of a shape the same list object); else None.  O(tasks)."""
-    if np is None or not tasks:
+    if not tasks:
         return None
     t0 = tasks[0]
     lst = cand.get(t0.id)

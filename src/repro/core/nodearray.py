@@ -2,11 +2,11 @@
 
 At 4096+ nodes the scheduler's dominant per-event cost is walking the
 per-node hot state (``NodeState.free_mem``/``free_cores``, COP slots) one
-dict entry at a time: capacity-class walks in ``readyset.CapacityClasses``,
-the step-2/3 free-slot pool scans and the input-less best-fit loops all
-touch O(nodes) Python objects per event.  :class:`NodeCapacityArray` mirrors
-that state into flat numpy arrays indexed by a dense *slot map* so those
-walks become masked array queries (DESIGN.md "Vectorized hot state").
+dict entry at a time: capacity-class walks, the step-2/3 free-slot pool
+scans and the input-less best-fit loops all touch O(nodes) Python objects
+per event.  :class:`NodeCapacityArray` mirrors that state into flat numpy
+arrays indexed by a dense *slot map* so those walks become masked array
+queries (DESIGN.md "Vectorized hot state").
 
 Slot-map invariants (the bit-parity load-bearing part):
 
@@ -28,27 +28,18 @@ Slot-map invariants (the bit-parity load-bearing part):
   including *mid-event* between a step-1 reservation and the step-2/3
   scans, which lazy dirty-refresh alone would miss.
 
-Queries read the same values the dict paths read and tie-break the same
-way, so every consumer is bit-identical to its dict twin (the retained
-``vectorized=False`` oracle; property- and equivalence-tested in
-``tests/test_nodearray.py``).
-
-numpy is optional (matching the ``tests/_hyp.py`` optional-dependency
-pattern): without it ``HAVE_NUMPY`` is False and the scheduler keeps the
-dict path, so the suite stays green on bare containers.
+Queries read the same values a walk over the ``NodeState`` dict reads and
+tie-break the same way (property-tested against brute force in
+``tests/test_nodearray.py``; whole runs against
+``core.reference.ReferenceWowScheduler``).
 """
 from __future__ import annotations
 
 from typing import Iterable
 
-from .types import NodeId, NodeState
+import numpy as np
 
-try:  # optional dependency -- the dict path needs nothing beyond stdlib
-    import numpy as np
-    HAVE_NUMPY = True
-except ModuleNotFoundError:  # pragma: no cover - exercised on bare images
-    np = None
-    HAVE_NUMPY = False
+from .types import NodeId, NodeState
 
 _MIN_COMPACT = 64
 
@@ -58,10 +49,6 @@ class NodeCapacityArray:
 
     def __init__(self, nodes: dict[int, NodeState], order: Iterable[NodeId],
                  c_node: int = 1) -> None:
-        if not HAVE_NUMPY:
-            raise RuntimeError(
-                "NodeCapacityArray requires numpy; construct the scheduler "
-                "with vectorized=False on numpy-less environments")
         self.c_node = c_node
         self.slot_of: dict[NodeId, int] = {}
         n = len(nodes)
@@ -169,11 +156,6 @@ class NodeCapacityArray:
         self.free_mem[s] = free_mem
         self.free_cores[s] = free_cores
 
-    def add_cops(self, node: NodeId, delta: int) -> None:
-        s = self.slot_of.get(node)
-        if s is not None:
-            self.active_cops[s] += delta
-
     # --------------------------------------------------------------- queries
     def _live(self) -> "np.ndarray":
         return self.alive[:self._n]
@@ -188,28 +170,12 @@ class NodeCapacityArray:
         order (slot order *is* canonical order -- no sort)."""
         return self._node_of[np.flatnonzero(self.fit_mask(mem, cores))].tolist()
 
-    def fitting_with_slots(self, mem: int,
-                           cores: float) -> tuple[list[NodeId], "np.ndarray"]:
-        slots = np.flatnonzero(self.fit_mask(mem, cores))
-        return self._node_of[slots].tolist(), slots
-
-    def any_fit(self, mem: int, cores: float) -> bool:
-        return bool(self.fit_mask(mem, cores).any())
-
     def free_slot_fit_ids(self, mem: int, cores: float) -> list[NodeId]:
         """Free-COP-slot nodes whose *free* resources fit -- the step-2
         candidate pool scan, in canonical order."""
         n = self._n
         mask = (self._live() & (self.active_cops[:n] < self.c_node)
                 & (self.free_mem[:n] >= mem) & (self.free_cores[:n] >= cores))
-        return self._node_of[np.flatnonzero(mask)].tolist()
-
-    def free_slot_total_fit_ids(self, mem: int, cores: float) -> list[NodeId]:
-        """Free-COP-slot nodes whose *total* capacity could ever run the
-        task -- the step-3 candidate pool scan, in canonical order."""
-        n = self._n
-        mask = (self._live() & (self.active_cops[:n] < self.c_node)
-                & (self.mem[:n] >= mem) & (self.cores[:n] >= cores))
         return self._node_of[np.flatnonzero(mask)].tolist()
 
     def filter_fitting(self, cands: list[NodeId], mem: int,
@@ -246,38 +212,3 @@ class NodeCapacityArray:
     def live_ids(self) -> list[NodeId]:
         """Live node ids in slot (= canonical) order."""
         return self._node_of[np.flatnonzero(self._live())].tolist()
-
-
-class ArrayCapacityClasses:
-    """`readyset.CapacityClasses` facade over a :class:`NodeCapacityArray`:
-    same refresh/drop/fitting/any_fit surface, answered by masked array
-    queries instead of capacity-class dict walks.  The scheduler swaps this
-    in when ``vectorized=True``; results are bit-identical (same values,
-    same canonical order)."""
-
-    def __init__(self, cap: NodeCapacityArray,
-                 nodes: dict[int, NodeState]) -> None:
-        self._cap = cap
-        self._nodes = nodes
-
-    def refresh(self, node: NodeId) -> None:
-        state = self._nodes.get(node)
-        if state is None:
-            self._cap.drop(node)
-        else:
-            self._cap.refresh_from(node, state)
-
-    def refresh_many(self, nodes: Iterable[NodeId]) -> None:
-        self._cap.refresh_many(nodes, self._nodes)
-
-    def drop(self, node: NodeId) -> None:
-        self._cap.drop(node)
-
-    def fitting(self, mem: int, cores: float) -> list[NodeId]:
-        return self._cap.fitting(mem, cores)
-
-    def fitting_with_slots(self, mem: int, cores: float):
-        return self._cap.fitting_with_slots(mem, cores)
-
-    def any_fit(self, mem: int, cores: float) -> bool:
-        return self._cap.any_fit(mem, cores)

@@ -1,5 +1,5 @@
-"""Indexed ready-set subsystem: canonical node order, capacity classes and
-the priority-indexed step-2/3 ready structure.
+"""Indexed ready-set subsystem: canonical node order, the input-less shape
+index and the priority-indexed step-2/3 ready structure.
 
 Three small, allocation-light containers that turn the scheduler's per-event
 O(backlog) rescans into O(dirty)-shaped index maintenance (DESIGN.md
@@ -14,17 +14,13 @@ O(backlog) rescans into O(dirty)-shaped index maintenance (DESIGN.md
   ascend" convention: a node may re-join under its old (lower) id and both
   implementations still agree, because both enumerate it *last*.
 
-* :class:`CapacityClasses` -- nodes grouped by identical
-  ``(free_mem, free_cores)``.  Input-less ready tasks are prepared
-  everywhere, so their step-1 candidates are purely a capacity question;
-  grouping makes "all nodes fitting shape (m, c)" an O(classes) query
-  instead of an O(nodes)-per-task scan, which is what lets the scheduler
-  drop input-less tasks from the DPS/component machinery entirely.
-
 * :class:`ShapeIndex` -- input-less ready tasks bucketed by resource shape
   ``(mem, cores)``, each bucket pre-sorted in the greedy visit order
   ``(-priority, id)`` and maintained in O(log R) under submit/start.
-  Together with :class:`CapacityClasses` it makes the scheduler's
+  Input-less ready tasks are prepared everywhere, so their step-1
+  candidates are purely a capacity question ("all nodes fitting shape
+  (m, c)", one masked query of ``core.nodearray.NodeCapacityArray``);
+  together with that query it makes the scheduler's
   capacity-only step-1 path O(shapes + assigned) per stale event instead of
   an O(backlog) regroup-and-rebuild (DESIGN.md "Incremental input-less
   placement").
@@ -49,7 +45,7 @@ from __future__ import annotations
 
 from bisect import bisect_left, insort
 
-from .types import NodeId, NodeState
+from .types import NodeId
 
 
 class NodeOrder:
@@ -99,72 +95,6 @@ class NodeOrder:
 
     def ids(self) -> list[NodeId]:
         return list(self._ids)
-
-
-class CapacityClasses:
-    """Nodes grouped by identical ``(free_mem, free_cores)``.
-
-    The scheduler refreshes exactly the dirty nodes (whose free resources
-    changed) per event; queries then cost O(distinct capacity classes),
-    which in steady state is bounded by the distinct task shapes in
-    flight, not the cluster size.
-    """
-
-    def __init__(self, nodes: dict[int, NodeState],
-                 order: NodeOrder) -> None:
-        self._nodes = nodes
-        self._order = order
-        self._members: dict[tuple, set[NodeId]] = {}
-        self._class_of: dict[NodeId, tuple] = {}
-        for n in nodes:
-            self.refresh(n)
-
-    def refresh(self, node: NodeId) -> None:
-        """(Re-)classify ``node`` from its live free resources."""
-        state = self._nodes.get(node)
-        if state is None:
-            self.drop(node)
-            return
-        key = (state.free_mem, state.free_cores)
-        old = self._class_of.get(node)
-        if old == key:
-            return
-        if old is not None:
-            self._evict(node, old)
-        self._class_of[node] = key
-        self._members.setdefault(key, set()).add(node)
-
-    def refresh_many(self, nodes) -> None:
-        """Batch form of :meth:`refresh` (one call per dirty-node drain;
-        the array-backed twin answers it in a single pass)."""
-        for n in nodes:
-            self.refresh(n)
-
-    def drop(self, node: NodeId) -> None:
-        old = self._class_of.pop(node, None)
-        if old is not None:
-            self._evict(node, old)
-
-    def _evict(self, node: NodeId, key: tuple) -> None:
-        members = self._members.get(key)
-        if members is not None:
-            members.discard(node)
-            if not members:
-                del self._members[key]
-
-    def fitting(self, mem: int, cores: float) -> list[NodeId]:
-        """All nodes whose free resources fit ``(mem, cores)``, in
-        canonical order -- the candidate list an input-less task's step-1
-        assignment sees."""
-        out: list[NodeId] = []
-        for (fm, fc), members in self._members.items():
-            if fm >= mem and fc >= cores:
-                out.extend(members)
-        return self._order.sort(out)
-
-    def any_fit(self, mem: int, cores: float) -> bool:
-        return any(fm >= mem and fc >= cores
-                   for fm, fc in self._members)
 
 
 class ShapeIndex:

@@ -40,10 +40,10 @@ remaining per-event O(backlog) scans:
     DPS or the incremental solver's component structure (which they used to
     weld into one always-dirty component); their step-1 subproblem is built
     per *shape* from `readyset.ShapeIndex` (pre-sorted greedy order,
-    maintained under submit/start) and `readyset.CapacityClasses` (all
+    maintained under submit/start) and `nodearray.NodeCapacityArray` (all
     fitting nodes per shape), then solved per shape-component by the
-    cheapest decision-identical tier: an analytic uniform-shape greedy for
-    large single-shape components, else `ilp.solve` behind the canonical
+    cheapest decision-identical tier: `ilp.greedy_uniform` for large
+    single-shape components, else `ilp.solve` behind the canonical
     fingerprint cache -- O(shapes + assigned) per stale fan-out event
     instead of O(backlog), with decisions unchanged (DESIGN.md
     "Incremental input-less placement").  On the rare event where
@@ -62,21 +62,21 @@ remaining per-event O(backlog) scans:
     ``sorted(self.nodes)`` and defines candidate/iteration order the same
     way the reference's ``list(self.nodes)`` scans do, lifting the old
     "node ids ascend" convention (nodes may re-join under old ids).
-  * **Batched COP drain** (``batched=True``, default whenever
-    ``vectorized``; DESIGN.md "Batched COP drain").  The DPS maintains a
-    dense (task x node-slot) present-count / present-bytes matrix
+  * **Batched COP drain** (DESIGN.md "Batched COP drain").  Node hot state
+    lives in `core.nodearray.NodeCapacityArray`; the DPS maintains a dense
+    (task x node-slot) present-count / present-bytes matrix
     (`core.copmatrix.CopMatrix`) at its replica-mutation choke points, and
-    a `core.copmatrix.BlockedDrainKernel` replaces the per-task inner
-    machinery of steps 2-3: candidate masks, missing-bytes / locality-cost
-    rows and the step-2 argmin become array expressions in canonical slot
-    order, with staged reductions that split float ties exactly as the
-    dict tuple-compare.  Only the *winning* step-2 probe reaches scalar
-    ``plan_cop`` (provably always feasible for the unconstrained pool), so
-    COP-id and tie-break RNG consumption is unchanged; step-3 keeps its
-    scalar probe-all loop (each feasible probe consumes a COP id) and only
-    the candidate construction is blocked.  The per-task dict machinery is
-    retained verbatim as the oracle (``batched=False``), property-tested
-    bit-identical; constrained pools always take the oracle path.
+    a `core.copmatrix.BlockedDrainKernel` builds the step-2/3 candidate
+    masks, missing-bytes / locality-cost rows and the step-2 argmin as
+    array expressions in canonical slot order, with staged reductions that
+    split float ties exactly as a ``(cost, node)`` tuple sort.  Only the
+    *winning* step-2 probe reaches scalar ``plan_cop`` (provably always
+    feasible for the unconstrained pool), so COP-id and tie-break RNG
+    consumption is unchanged; step-3 keeps its scalar probe-all loop (each
+    feasible probe consumes a COP id) and only the candidate construction
+    is blocked.  Constrained pools, and tasks the kernel has no matrix row
+    for, take the per-task pool filter (`_step2_probe_task` and step 3's
+    generic branch).
 
 Decisions are bit-identical to ``core.reference.ReferenceWowScheduler``
 (equivalence-tested), with one deliberate, documented exception: where the
@@ -90,6 +90,8 @@ from __future__ import annotations
 
 from operator import attrgetter
 
+import numpy as np
+
 from . import trace
 from .dps import DataPlacementService
 from .ilp import (AssignmentProblem, FingerprintCache,
@@ -97,14 +99,10 @@ from .ilp import (AssignmentProblem, FingerprintCache,
                   exact_gate, greedy_uniform, group_by_shared_nodes,
                   solve_greedy)
 from .ilp import solve as solve_stateless
-from .nodearray import HAVE_NUMPY, ArrayCapacityClasses, NodeCapacityArray
-from .readyset import CapacityClasses, NodeOrder, ReadySet, ShapeIndex
+from .copmatrix import BlockedDrainKernel
+from .nodearray import NodeCapacityArray
+from .readyset import NodeOrder, ReadySet, ShapeIndex
 from .types import (Action, CopPlan, NodeState, StartCop, StartTask, TaskSpec)
-
-try:  # optional; the dict path stays pure-stdlib (see core/nodearray.py)
-    import numpy as np
-except ModuleNotFoundError:  # pragma: no cover
-    np = None
 
 
 class WowScheduler:
@@ -115,9 +113,7 @@ class WowScheduler:
         c_node: int = 1,
         c_task: int = 2,
         node_order: NodeOrder | None = None,
-        vectorized: bool | None = None,
         strict_parity: bool = True,
-        batched: bool | str | None = None,
     ) -> None:
         self.nodes = nodes
         self.dps = dps
@@ -127,29 +123,6 @@ class WowScheduler:
         # from surviving previous assignments -- pays off exactly when a
         # runtime declines placements (core/adapter.py decline-requeue path)
         self.strict_parity = bool(strict_parity)
-        # vectorized hot node state (DESIGN.md "Vectorized hot state"):
-        # None = auto (on exactly when numpy is importable).  The dict path
-        # is the retained, equivalence-tested oracle; decisions are
-        # bit-identical either way.
-        if vectorized is None:
-            vectorized = HAVE_NUMPY
-        elif vectorized and not HAVE_NUMPY:
-            raise RuntimeError("vectorized=True requires numpy; "
-                               "pass vectorized=False (dict path) instead")
-        self.vectorized = bool(vectorized)
-        # batched step-2/3 drain (DESIGN.md "Batched COP drain"): None =
-        # auto (on exactly when the node state is vectorized), "jax" = the
-        # jitted winner-reduction twin (copmatrix.JaxWinner).  The per-task
-        # dict machinery is the retained oracle; decisions are bit-identical
-        # either way (property-tested in tests/test_copmatrix.py).
-        if batched is None:
-            batched = self.vectorized
-        if batched and not self.vectorized:
-            raise RuntimeError("batched drain requires vectorized node "
-                               "state; pass batched=False (per-task "
-                               "oracle) instead")
-        self.batched = bool(batched)
-        self._batched_jax = batched == "jax"
         # canonical node enumeration order; the environment passes its own
         # (sim/engine.py owns one), standalone use derives it from the dict
         self.node_order = node_order if node_order is not None \
@@ -194,26 +167,16 @@ class WowScheduler:
         self._startable: dict[int, list[int]] = {} # cached prep ∩ fits, != []
         self._free_slot_nodes: set[int] = {
             n for n, s in nodes.items() if s.active_cops < c_node}
-        if self.vectorized:
-            self._cap_array: NodeCapacityArray | None = NodeCapacityArray(
-                nodes, self.node_order, c_node)
-            self._capacity = ArrayCapacityClasses(self._cap_array, nodes)
-        else:
-            self._cap_array = None
-            self._capacity = CapacityClasses(nodes, self.node_order)
+        self._cap_array = NodeCapacityArray(nodes, self.node_order, c_node)
         self._ready_index = ReadySet()
         self.dps.sync_free_sources(self._free_slot_nodes)
         # step-1 solver state lives for the scheduler's lifetime; dirty
         # components are re-solved per event, the rest are reused
         self._solver = IncrementalAssignmentSolver(
             nodes, strict_parity=self.strict_parity, cap=self._cap_array)
-        if self.batched:
-            from .copmatrix import BlockedDrainKernel
-            self._kernel = BlockedDrainKernel(
-                self._cap_array, self.dps.enable_matrix(), c_node,
-                self._inflight_by_task, use_jax=self._batched_jax)
-        else:
-            self._kernel = None
+        self._kernel = BlockedDrainKernel(
+            self._cap_array, self.dps.enable_matrix(), c_node,
+            self._inflight_by_task)
 
     # ------------------------------------------------------------- events
     def submit(self, task: TaskSpec) -> None:
@@ -240,8 +203,7 @@ class WowScheduler:
         t_node.free_cores += self._cores_of(task_id)
         self._finished_specs.pop(task_id, None)
         self._dirty_nodes.add(node)
-        if self._cap_array is not None:
-            self._cap_array.refresh_from(node, t_node)
+        self._cap_array.refresh_from(node, t_node)
 
     def on_cop_finished(self, plan: CopPlan, ok: bool = True) -> None:
         if plan.id not in self.active_cops:
@@ -253,8 +215,7 @@ class WowScheduler:
         for n in plan.nodes:
             state = self.nodes[n]
             state.active_cops = max(0, state.active_cops - 1)
-            if self._cap_array is not None:
-                self._cap_array.refresh_from(n, state)
+            self._cap_array.refresh_from(n, state)
             if state.active_cops < self.c_node:
                 self._slot_freed(n)
         self.inflight_targets.discard((plan.task_id, plan.target))
@@ -282,8 +243,7 @@ class WowScheduler:
         state = self.nodes[node]
         state.free_mem += t.mem
         state.free_cores += t.cores
-        if self._cap_array is not None:
-            self._cap_array.refresh_from(node, state)
+        self._cap_array.refresh_from(node, state)
         self._dirty_nodes.add(node)
         self.declines += 1
         self.submit(t)
@@ -325,9 +285,8 @@ class WowScheduler:
 
     def note_node_added(self, node: int) -> None:
         self.node_order.add(node)       # no-op when the environment owns it
-        if self._cap_array is not None:
-            # fresh slot at the end: same re-append semantics as NodeOrder
-            self._cap_array.add(node, self.nodes[node])
+        # fresh slot at the end: same re-append semantics as NodeOrder
+        self._cap_array.add(node, self.nodes[node])
         self._dirty_nodes.add(node)
         self._less_stale = True
         if self.nodes[node].active_cops < self.c_node:
@@ -337,7 +296,7 @@ class WowScheduler:
         # tasks prepared on the node were dirtied by dps.drop_node already
         self.node_order.discard(node)
         self._slot_busy(node)
-        self._capacity.drop(node)
+        self._cap_array.drop(node)
         self._dirty_nodes.discard(node)
         self._less_stale = True
 
@@ -385,16 +344,6 @@ class WowScheduler:
         """Counters of the incremental step-1 solver (benchmarks)."""
         return self._solver.stats
 
-    @property
-    def device_stats(self) -> dict:
-        """``{"dispatches", "platforms"}`` of the jax winner twin
-        (``batched="jax"``); empty when the twin is off."""
-        twin = self._kernel.jax_winner if self._kernel is not None else None
-        if twin is None:
-            return {}
-        return {"dispatches": twin.dispatches,
-                "platforms": sorted(twin.platforms)}
-
     def _refresh_candidates(self) -> tuple[set[int], set[int]]:
         """Recompute cached start candidates for exactly the dirty tasks.
 
@@ -407,9 +356,9 @@ class WowScheduler:
             if n in self.nodes:
                 dirty.update(self.dps.iter_tasks_prepared_on(n))
         if dirty_nodes:
-            # one batch pass over the dirty nodes (for the array state this
-            # is an idempotent re-sync on top of the choke-point writes)
-            self._capacity.refresh_many(dirty_nodes)
+            # one batch pass over the dirty nodes: an idempotent re-sync
+            # on top of the choke-point writes
+            self._cap_array.refresh_many(dirty_nodes, self.nodes)
             self._less_stale = True
         self._dirty_nodes = set()
         self._dirty_tasks = set()
@@ -436,7 +385,7 @@ class WowScheduler:
         only on the (rare) mixed event that must be solved jointly."""
         cands: dict[int, list[int]] = {}
         for shape in self._less_index.shapes():
-            fit = self._capacity.fitting(*shape)
+            fit = self._cap_array.fitting(*shape)
             if fit:
                 for tid in self._less_index.tasks_of(shape):
                     cands[tid] = fit
@@ -464,10 +413,8 @@ class WowScheduler:
           task can have no strictly-lower-priority placed task when
           placement order is priority-descending and all shapes are equal).
           The shape index stores buckets in that exact order, so this costs
-          O(assigned x fitting nodes) -- no backlog scan, no sort.  With
-          the capacity array it is ``ilp.greedy_uniform``, the step-1
-          solver's uniform fallback; else its dict twin
-          :meth:`_greedy_uniform`.
+          O(assigned x fitting nodes) -- no backlog scan, no sort.  It is
+          ``ilp.greedy_uniform``, the step-1 solver's uniform fallback.
         * **generic tier** -- small or multi-shape components go through
           `ilp.solve` unchanged, behind a canonical fingerprint cache
           (`ilp.FingerprintCache`, the step-1 solver's machinery) so a
@@ -476,7 +423,7 @@ class WowScheduler:
         self.inputless_stats["events"] += 1
         fits: dict[tuple[int, float], list[int]] = {}
         for shape in self._less_index.shapes():
-            fit = self._capacity.fitting(*shape)
+            fit = self._cap_array.fitting(*shape)
             if fit:
                 fits[shape] = fit
         if not fits:
@@ -490,14 +437,11 @@ class WowScheduler:
                 if not exact_gate(len(group), len(group) * len(fit)):
                     self.inputless_stats["fast_solves"] += 1
                     cap = self._cap_array
-                    if cap is not None:
-                        slots = cap.slots_of(fit)
-                        assign.update(greedy_uniform(
-                            shape[0], shape[1], (tid for _, tid in group),
-                            np.asarray(fit, dtype=np.int64),
-                            cap.free_mem[slots], cap.free_cores[slots]))
-                    else:
-                        assign.update(self._greedy_uniform(shape, group, fit))
+                    slots = cap.slots_of(fit)
+                    assign.update(greedy_uniform(
+                        shape[0], shape[1], (tid for _, tid in group),
+                        np.asarray(fit, dtype=np.int64),
+                        cap.free_mem[slots], cap.free_cores[slots]))
                     continue
             n_tasks = sum(len(self._less_index.group(s)) for s in comp)
             n_cand = sum(len(self._less_index.group(s)) * len(fits[s])
@@ -531,32 +475,20 @@ class WowScheduler:
         cores bound adds a +1 float-safety margin per node (repeated float
         subtraction may admit one placement more than ``//`` predicts;
         overcounting only keeps extra tasks, undercounting would break
-        parity).  Dict and array paths compute identical values."""
+        parity)."""
         mem, cores = shape
         if mem <= 0 and cores <= 0:
             return len(fit) * (1 << 40)     # unbounded: keep everything
         cap = self._cap_array
-        if cap is not None:
-            slots = cap.slots_of(fit)
-            if mem > 0:
-                bound = cap.free_mem[slots] // mem
-                if cores > 0:
-                    cb = (cap.free_cores[slots] // cores).astype(np.int64) + 1
-                    bound = np.minimum(bound, cb)
-            else:
-                bound = (cap.free_cores[slots] // cores).astype(np.int64) + 1
-            return int(bound.sum())
-        total = 0
-        for n in fit:
-            s = self.nodes[n]
-            if mem > 0:
-                b = s.free_mem // mem
-                if cores > 0:
-                    b = min(b, int(s.free_cores // cores) + 1)
-            else:
-                b = int(s.free_cores // cores) + 1
-            total += b
-        return total
+        slots = cap.slots_of(fit)
+        if mem > 0:
+            bound = cap.free_mem[slots] // mem
+            if cores > 0:
+                cb = (cap.free_cores[slots] // cores).astype(np.int64) + 1
+                bound = np.minimum(bound, cb)
+        else:
+            bound = (cap.free_cores[slots] // cores).astype(np.int64) + 1
+        return int(bound.sum())
 
     def _truncate_component(self, comp: list[tuple[int, float]],
                             fits: dict[tuple[int, float], list[int]],
@@ -616,33 +548,6 @@ class WowScheduler:
             {n: self.nodes[n] for n in nlist}, self._cap_array))
         self._less_cache.put(fp, tids, npos, sub)
         return sub
-
-    def _greedy_uniform(self, shape: tuple[int, float],
-                        group: list[tuple[float, int]],
-                        fit: list[int]) -> dict[int, int]:
-        """Best-fit placement of identical tasks in ``(-priority, id)``
-        order, stopping at the first task that fits nowhere -- bit-equal to
-        ``solve_greedy`` on the single-shape component (see
-        :meth:`_solve_inputless`)."""
-        mem, cores = shape
-        free_mem = {n: self.nodes[n].free_mem for n in fit}
-        free_cores = {n: self.nodes[n].free_cores for n in fit}
-        out: dict[int, int] = {}
-        for _, tid in group:
-            best = None
-            best_key = None
-            for n in fit:
-                fm, fc = free_mem[n], free_cores[n]
-                if fm >= mem and fc >= cores:
-                    key = (fc - cores, fm - mem, n)
-                    if best is None or key < best_key:
-                        best, best_key = n, key
-            if best is None:
-                break
-            out[tid] = best
-            free_mem[best] -= mem
-            free_cores[best] -= cores
-        return out
 
     def _solve_inputless_component(self, tids: list[int],
                                    cand: dict[int, list[int]]) -> dict[int, int]:
@@ -712,10 +617,9 @@ class WowScheduler:
             node = self.nodes[n]
             node.free_mem -= t.mem
             node.free_cores -= t.cores
-            if self._cap_array is not None:
-                # write through *now*: the step-2/3 pool masks of this same
-                # event read post-reservation capacity, like the dict path
-                self._cap_array.set_free(n, node.free_mem, node.free_cores)
+            # write through *now*: the step-2/3 pool masks of this same
+            # event read post-reservation capacity
+            self._cap_array.set_free(n, node.free_mem, node.free_cores)
             self.running[tid] = n
             self._finished_specs[tid] = t
             started.add(tid)
@@ -766,8 +670,7 @@ class WowScheduler:
         for n in plan.nodes:
             state = self.nodes[n]
             state.active_cops += 1
-            if self._cap_array is not None:
-                self._cap_array.refresh_from(n, state)
+            self._cap_array.refresh_from(n, state)
             if state.active_cops >= self.c_node:
                 self._slot_busy(n)
         self.inflight_targets.add((plan.task_id, plan.target))
@@ -801,8 +704,7 @@ class WowScheduler:
         self._sync_ready_index()
         dps = self.dps
         kern = self._kernel
-        if kern is not None:
-            kern.begin()
+        kern.begin()
         probed = skipped = 0
         # Shapes (mem, cores) that no free-slot node's free resources fit.
         # Within one pass free resources are frozen (step 1 made its
@@ -829,18 +731,18 @@ class WowScheduler:
             feas, pool = self._cop_target_pool(t)
             if pool is None:
                 continue
-            if kern is not None and pool is self._free_slot_nodes:
+            if pool is self._free_slot_nodes:
                 # blocked kernel (DESIGN.md "Batched COP drain"): the whole
                 # candidate mask + cost row + staged argmin as array ops.
                 # An unconstrained pool means feas is None, and then the
                 # probe on *any* candidate target always succeeds (every
                 # input has an admissible free-slot source, and a source
                 # that is the target cannot be needed -- the file would not
-                # be missing there), so the dict path's probe loop stops at
-                # its first, minimum-key candidate: exactly the winner.
+                # be missing there), so the per-task probe loop would stop
+                # at its first, minimum-key candidate: exactly the winner.
                 winner = kern.step2_winner(tid, t, dps)
                 if winner is None:
-                    # empty candidate set: oracle starts none
+                    # empty candidate set: nothing to start
                     self._step2_empty(t, shape, dead, witness)
                     continue
                 if winner >= 0:
@@ -850,9 +752,9 @@ class WowScheduler:
                     if plan is not None:
                         self._start_cop(plan, actions)
                         continue
-                # winner == -1 (untracked row) or -- unreachable by the
+                # winner == -1 (no matrix row) or -- unreachable by the
                 # invariant above -- an infeasible winning probe: fall
-                # through to the per-task oracle (re-probing the winner is
+                # through to the per-task probe (re-probing the winner is
                 # harmless, infeasible probes are side-effect-free)
             if not self._step2_probe_task(tid, t, feas, pool, actions):
                 self._step2_empty(t, shape, dead, witness)
@@ -866,13 +768,7 @@ class WowScheduler:
         w = witness.get(shape)
         if w is not None and w in self._free_slot_nodes:
             return
-        kern = self._kernel
-        if kern is not None:
-            w = kern.free_slot_fit_node(t.mem, t.cores)
-        else:
-            nodes = self.nodes
-            w = next((n for n in self._free_slot_nodes if nodes[n].fits(t)),
-                     None)
+        w = self._kernel.free_slot_fit_node(t.mem, t.cores)
         if w is None:
             dead.add(shape)
         else:
@@ -880,17 +776,15 @@ class WowScheduler:
 
     def _step2_probe_task(self, tid: int, t: TaskSpec, feas, pool,
                           actions: list[Action]) -> bool:
-        """Per-task step-2 machinery -- the retained dict oracle the blocked
-        kernel is property-tested bit-identical against, and the live path
-        for constrained pools (``pool is not _free_slot_nodes``), for
-        ``batched=False``, and for the kernel's defensive fallthrough.
-        Returns False when the candidate set was empty."""
+        """Per-task step-2 probe: the path for constrained pools (``pool is
+        not _free_slot_nodes``) and for the kernel's fallthrough (no matrix
+        row).  Returns False when the candidate set was empty."""
         dps = self.dps
         # nodes with free compute capacity, spare COP slot, not already
         # prepared / being prepared
         prepped = dps.prepared_node_set(tid)
         inflight = self.inflight_targets
-        if self._cap_array is not None and pool is self._free_slot_nodes:
+        if pool is self._free_slot_nodes:
             # whole free-slot pool: one masked array scan replaces the
             # per-node fits() walk (identical set; the sort below fixes
             # the order either way)
@@ -935,8 +829,7 @@ class WowScheduler:
         dps = self.dps
         order = self.node_order
         kern = self._kernel
-        if kern is not None:
-            kern.begin()
+        kern.begin()
         probed = skipped = 0
         free = self._free_slot_nodes        # shrinks in place as COPs start
         for tid in self._ready_index.step3_order():
@@ -959,24 +852,17 @@ class WowScheduler:
                 continue
             # canonical order: the reference probes nodes in enumeration
             # order and plan_cop consumes tie-break randomness per feasible
-            # probe, so the probe order is decision-relevant.  The masked
-            # scan yields slot order, which *is* canonical order.  Unlike
+            # probe, so the probe order is decision-relevant.  The kernel's
+            # mask yields slot order, which *is* canonical order.  Unlike
             # step 2 the probe loop itself cannot be batched: every
             # *feasible* probe consumes a COP id (and possibly a tie-break
             # RNG draw) whether or not it wins, so the blocked kernel only
-            # replaces candidate-mask construction.
+            # replaces candidate-mask construction.  A constrained pool, or
+            # a task without a matrix row (None), filters the pool itself.
             cands = None
-            if kern is not None and pool is self._free_slot_nodes:
+            if pool is free:
                 cands = kern.step3_candidates(tid, t)
-            if cands is not None:
-                pass
-            elif self._cap_array is not None and pool is self._free_slot_nodes:
-                inflight = self.inflight_targets
-                cands = [
-                    n for n in self._cap_array.free_slot_total_fit_ids(
-                        t.mem, t.cores)
-                    if (tid, n) not in inflight and n not in prep]
-            else:
+            if cands is None:
                 inflight = self.inflight_targets
                 cands = order.sort(
                     n for n in pool

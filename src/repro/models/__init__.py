@@ -1,9 +1,4 @@
+from .api import Model
 from .config import ArchConfig
-
-try:  # jax side of the repo; absent on numpy-less containers (the
-    # scheduler/sim half only needs ArchConfig -- see tests/_no_numpy_shim)
-    from .api import Model
-except ImportError:  # pragma: no cover - exercised by the no-numpy CI leg
-    Model = None  # type: ignore[assignment]
 
 __all__ = ["ArchConfig", "Model"]

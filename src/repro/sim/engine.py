@@ -21,11 +21,11 @@ from operator import attrgetter
 from ..core import (DFS_LOC, FileSpec, NodeOrder, NodeState, StartCop,
                     StartTask, TaskSpec, abstract_ranks, assign_priorities,
                     trace)
+from ..core.adapter import RuntimeAdapter, WowAdapter, make_adapter
 from ..core.types import CopPlan
 from .dfs import CephModel, DfsModel, NfsModel
 from .metrics import SimResult, TrafficResult, compute_traffic_result, gini
 from .network import FlowManager, ReferenceFlowManager, build_links
-from .strategies import BaseStrategy, WowStrategy, make_strategy
 from .topology import Topology, TopologySpec
 from .traffic import ArrivalSpec, InstanceRecord, TrafficConfig, \
     arrival_schedule
@@ -58,15 +58,6 @@ class SimConfig:
     # "scan" (retained pre-heap progressive fill -- the pre-PR engine, kept
     # as the equivalence reference and the sim_throughput baseline)
     flow_fill: str = "heap"
-    # vectorized hot node state in the wow scheduler: None = auto (on when
-    # numpy is importable), False = retained dict oracle.  Decisions are
-    # bit-identical either way (DESIGN.md "Vectorized hot state").
-    vectorized: bool | None = None
-    # batched COP drain in the wow scheduler: None = auto (on exactly when
-    # vectorized), False = per-task dict oracle, "jax" = jitted winner
-    # reduction.  Decisions are bit-identical in all modes (DESIGN.md
-    # "Batched COP drain").
-    batched: bool | str | None = None
     # hierarchical topology (sim/topology.py): nodes -> racks -> sites with
     # oversubscribed shared links.  None -- or a flat spec (single rack) --
     # keeps the engine bit-identical to the pre-topology goldens.
@@ -129,11 +120,10 @@ class Simulation:
             if topo.nonuniform:
                 self.topo = topo
         self.tier_bytes: dict[str, float] = {}
-        self.strategy: BaseStrategy = make_strategy(
+        self.strategy: RuntimeAdapter = make_adapter(
             strategy, self.nodes, c_node=cfg.c_node, c_task=cfg.c_task,
             seed=cfg.seed, reference_core=cfg.reference_core,
-            node_order=self.node_order, vectorized=cfg.vectorized,
-            topology=self.topo, batched=cfg.batched)
+            node_order=self.node_order, topology=self.topo)
 
         extra: tuple[int, ...] = ()
         self.nfs_server = cfg.n_nodes
@@ -308,7 +298,7 @@ class Simulation:
                 rec = self._instances[iid]
                 if rec.first_start_t is None:
                     rec.first_start_t = self.time
-        if isinstance(self.strategy, WowStrategy):
+        if isinstance(self.strategy, WowAdapter):
             dps = self.strategy.dps
             assert dps.is_prepared(task.inputs, node), (
                 f"scheduler started task {tid} on unprepared node {node}")
@@ -404,7 +394,7 @@ class Simulation:
         if self.traffic is not None:
             self._traffic_task_done(tid, run.start, self.time, task.cores)
         self.strategy.task_finished(tid, node)
-        if isinstance(self.strategy, WowStrategy):
+        if isinstance(self.strategy, WowAdapter):
             for f in task.outputs:
                 self.strategy.dps.register_file(self.wf.files[f], node)
         for f in task.outputs:
@@ -419,7 +409,7 @@ class Simulation:
                         and consumer not in self.task_runs
                         and consumer not in self.done_tasks):
                     self._submit(self.wf.tasks[consumer])
-        if self.cfg.gc_replicas and isinstance(self.strategy, WowStrategy):
+        if self.cfg.gc_replicas and isinstance(self.strategy, WowAdapter):
             for f in task.inputs:
                 if all(c in self.done_tasks
                        for c in self.wf.files[f].consumers):
@@ -496,7 +486,7 @@ class Simulation:
                 self.repair_flows.pop(fl, None)
         self._redirect_node_io(node)
         lost: list[int] = []
-        if isinstance(self.strategy, WowStrategy):
+        if isinstance(self.strategy, WowAdapter):
             # drop replicas (index-safe); recover lost files by re-running
             # their producers
             lost = self.strategy.dps.drop_node(node)
@@ -712,7 +702,7 @@ class Simulation:
         if any(t in self.task_runs or t in self.pending
                for t in rec.task_ids):
             return      # failure recovery re-opened the instance; keep it
-        wow = isinstance(self.strategy, WowStrategy)
+        wow = isinstance(self.strategy, WowAdapter)
         for tid in rec.task_ids:
             task = self.wf.tasks.pop(tid, None)
             if task is None:
@@ -877,7 +867,7 @@ class Simulation:
         unique = sum(f.size for f in self.wf.files.values())
         cop_bytes = 0
         cops_created = 0
-        if isinstance(self.strategy, WowStrategy):
+        if isinstance(self.strategy, WowAdapter):
             cop_bytes = self.strategy.dps.cop_bytes_total
             cops_created = self.strategy.sched.cops_created
         # the engine's actual surviving node set -- includes elastic-join

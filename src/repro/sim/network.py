@@ -51,8 +51,7 @@ per-freeze bookkeeping stops amortising -- once a shared hierarchy link has
 been seen and a component exceeds ``_VEC_MIN_MEMBERS`` link memberships the
 heap path switches to ``FlowManager._fill_vectorized``, a
 numpy dense-round fill over per-flow link-slot arrays with the same
-(share, insertion order) bottleneck rule (pure-python ``_heap_fill`` is the
-fallback without numpy).  The scan fill is retained as the ``fill="scan"``
+(share, insertion order) bottleneck rule.  The scan fill is retained as the ``fill="scan"``
 reference path (``SimConfig.flow_fill``) -- it *is* the pre-heap engine --
 and all paths are property- and golden-tested against each other.
 """
@@ -63,10 +62,7 @@ import heapq
 import math
 from typing import Hashable
 
-try:                                    # vectorized fill path (optional)
-    import numpy as _np
-except Exception:                       # pragma: no cover - numpy is in CI
-    _np = None
+import numpy as _np
 
 LinkId = tuple[str, int]
 
@@ -258,7 +254,7 @@ class FlowManager:
         self._fill = _FILLS[fill]
         # numpy-backed fast path for the heap fill on welded components;
         # the scan fill stays the untouched pure-python reference
-        self._vec = _np is not None and fill == "heap"
+        self._vec = fill == "heap"
         self._slot: dict[LinkId, int] = {}      # link -> dense slot index
         self._slot_links: list[LinkId] = []     # slot -> link
         # slot -> capacity, snapshotted at slot creation.  Safe to cache:

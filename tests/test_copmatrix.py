@@ -1,6 +1,7 @@
 """Batched COP drain (core/copmatrix.py): mirror + bit-parity test campaign.
 
-Layers of proof that ``batched=True`` changes nothing but speed:
+Layers of proof that the blocked drain makes the decisions of the per-task
+drain it replaced:
 
 * **matrix mirror property test** -- a randomized DPS mutation stream
   (register/replica add+remove/track/untrack/node drop/invalidate/gc);
@@ -8,16 +9,15 @@ Layers of proof that ``batched=True`` changes nothing but speed:
   cell-for-cell (``check_against``), including column recycling after
   ``drop_node``.
 * **kernel unit surface** -- null-column gathers read 0 like
-  ``dict.get(node, 0)``; untracked tasks return the oracle-fallback
-  sentinels; ``SlotColMap`` rebuilds exactly when a version counter moves;
-  ``batched=True`` without ``vectorized`` refuses loudly.
+  ``dict.get(node, 0)``; untracked tasks return the fallback sentinels;
+  the winner and the emptiness witness match brute-force walks;
+  ``SlotColMap`` rebuilds exactly when a version counter moves.
 * **full-sim bit-identity** -- actions (``sim.action_log``), makespans and
-  event counts identical for blocked vs per-task drain across workloads,
-  with churn (failure + elastic join), under a hierarchical topology, and
-  against the frozen reference core; plus a randomized property sweep.
-* **jax twin** -- the jitted winner reduction picks the same nodes as the
-  staged numpy reduction, one-ulp near ties included, and leaves the
-  process-wide x64 flag unchanged (skipped when jax is unavailable).
+  event counts identical to the frozen reference core
+  (``SimConfig(reference_core=True)``) across workloads, with churn
+  (failure + elastic join), under a hierarchical topology, through the
+  sentinel fallthroughs and a constrained pool; plus a randomized
+  property sweep.
 """
 from __future__ import annotations
 
@@ -25,15 +25,10 @@ import random
 
 import pytest
 
-from repro.core import (DataPlacementService, FileSpec, NodeState, TaskSpec,
-                        WowScheduler)
-from repro.core.copmatrix import HAVE_NUMPY
+from repro.core import (DataPlacementService, FileSpec, NodeState, StartCop,
+                        TaskSpec, WowScheduler)
 
 from _hyp import given, settings, st
-
-pytestmark = pytest.mark.skipif(
-    not HAVE_NUMPY, reason="numpy not available: the batched drain is off "
-                           "and the dict oracle is covered elsewhere")
 
 GiB = 1024 ** 3
 MB = 1024 ** 2
@@ -132,26 +127,23 @@ def test_null_column_reads_zero():
 
 
 # --------------------------------------------------------- kernel unit surface
-def _mini_sched(batched=True, n_nodes=4):
+def _mini_sched(n_nodes=4):
     nodes = {i: NodeState(i, 8 * GiB, 8.0) for i in range(n_nodes)}
     dps = DataPlacementService(seed=0)
-    sched = WowScheduler(nodes, dps, batched=batched)
+    sched = WowScheduler(nodes, dps)
     return sched, dps, nodes
 
 
-def test_batched_requires_vectorized():
-    nodes = {0: NodeState(0, 8 * GiB, 8.0)}
-    with pytest.raises(RuntimeError):
-        WowScheduler(nodes, DataPlacementService(seed=0),
-                     vectorized=False, batched=True)
-
-
 def test_batched_defaults_on_with_vectorized():
-    sched, _, _ = _mini_sched(batched=None)
-    assert sched.batched and sched._kernel is not None
-    nodes = {0: NodeState(0, 8 * GiB, 8.0)}
-    off = WowScheduler(nodes, DataPlacementService(seed=0), vectorized=False)
-    assert not off.batched and off._kernel is None
+    """Every scheduler builds the capacity array, the DPS matrix and the
+    blocked kernel over them: there is no other drain."""
+    from repro.core.copmatrix import BlockedDrainKernel
+    from repro.core.nodearray import NodeCapacityArray
+    sched, dps, _ = _mini_sched()
+    assert isinstance(sched._cap_array, NodeCapacityArray)
+    assert isinstance(sched._kernel, BlockedDrainKernel)
+    assert sched._kernel.cap is sched._cap_array
+    assert sched._kernel.mx is dps.matrix
 
 
 def test_untracked_task_returns_fallback_sentinels():
@@ -186,6 +178,60 @@ def test_step2_winner_matches_oracle_sort():
     assert kern.step3_candidates(1, t) == sorted(nodes)
 
 
+def test_step2_winner_matches_locality_sort():
+    """Under a rack topology the winner is the first element of the
+    ``(dps.locality_missing_cost, node)`` sort over the candidates: the
+    kernel's cost row, built file by file, against the per-node cost."""
+    from repro.sim import Topology, TopologySpec
+    rng = random.Random(5)
+    for _ in range(10):
+        sched, dps, nodes = _mini_sched(n_nodes=8)
+        dps.set_topology(Topology(TopologySpec(rack_size=2,
+                                               racks_per_site=2), 8, 1e9))
+        for fid in range(1, 4):
+            hosts = rng.sample(range(8), rng.randint(1, 3))
+            dps.register_file(FileSpec(fid, rng.randint(1, 90) * MB, 0),
+                              hosts[0])
+            for h in hosts[1:]:
+                dps.add_replica(fid, h)
+        t = TaskSpec(id=1, abstract="a", mem=GiB, cores=1.0,
+                     inputs=(1, 2, 3), priority=1.0)
+        sched.submit(t)
+        kern = sched._kernel
+        kern.begin()
+        prepped = dps.prepared_node_set(1)
+        cands = [n for n in nodes if n not in prepped]
+        want = min(cands, key=lambda n: (dps.locality_missing_cost(1, n), n))
+        assert kern.step2_winner(1, t, dps) == want
+
+
+@settings(max_examples=20)
+@given(st.integers(0, 10 ** 6))
+def test_free_slot_fit_node_matches_bruteforce(seed):
+    """The step-2 emptiness witness: some free-COP-slot node whose free
+    resources fit the shape exactly when a walk over the nodes finds one,
+    and the node it names is such a node."""
+    rng = random.Random(seed)
+    nodes = {i: NodeState(i, 8 * GiB, 8.0,
+                          free_mem=rng.randrange(0, 9) * GiB,
+                          free_cores=float(rng.randrange(0, 9)),
+                          active_cops=rng.randrange(0, 3))
+             for i in range(rng.randrange(1, 12))}
+    sched = WowScheduler(nodes, DataPlacementService(seed=0), c_node=2)
+    kern = sched._kernel
+    kern.begin()
+    for _ in range(10):
+        mem, cores = rng.randrange(0, 9) * GiB, float(rng.randrange(0, 9))
+        fitting = [n for n, s in nodes.items()
+                   if s.active_cops < 2 and s.fits(TaskSpec(
+                       id=0, abstract="a", mem=mem, cores=cores))]
+        w = kern.free_slot_fit_node(mem, cores)
+        if fitting:
+            assert w in fitting
+        else:
+            assert w is None
+
+
 def test_slotcolmap_rebuilds_only_on_version_change():
     from repro.core.copmatrix import SlotColMap
     sched, dps, _ = _mini_sched()
@@ -205,15 +251,18 @@ def test_slotcolmap_rebuilds_only_on_version_change():
 
 
 # ------------------------------------------------------- full-sim bit-identity
-def _sim_run(batched, *, workflow="group", scale=0.6, n_nodes=14, seed=0,
-             churn=False, topology=None, dfs="ceph"):
+def _sim_run(reference_core, *, workflow="group", scale=0.6, n_nodes=14,
+             seed=0, churn=False, topology=None, dfs="ceph", patch=None):
     from repro.sim import SimConfig, Simulation
     from repro.workloads import make_workflow
 
     wf = make_workflow(workflow, scale=scale, seed=seed)
     sim = Simulation(wf, SimConfig(n_nodes=n_nodes, dfs=dfs, seed=seed,
-                                   batched=batched, topology=topology),
+                                   reference_core=reference_core,
+                                   topology=topology),
                      "wow")
+    if patch is not None:
+        patch(sim.strategy.sched)
     if churn:
         sim.schedule_failure(15.0, 3)
         sim.schedule_join(30.0, n_nodes)
@@ -225,8 +274,8 @@ def _sim_run(batched, *, workflow="group", scale=0.6, n_nodes=14, seed=0,
                                       "chipseq"])
 @pytest.mark.parametrize("churn", [False, True])
 def test_full_sim_bit_identity(workflow, churn):
-    a = _sim_run(False, workflow=workflow, churn=churn)
-    b = _sim_run(None, workflow=workflow, churn=churn)   # auto: blocked
+    a = _sim_run(True, workflow=workflow, churn=churn)
+    b = _sim_run(False, workflow=workflow, churn=churn)
     assert a == b
 
 
@@ -234,8 +283,8 @@ def test_full_sim_bit_identity_topology():
     from repro.sim import TopologySpec
     topo = TopologySpec(rack_size=4, racks_per_site=2)
     for churn in (False, True):
-        a = _sim_run(False, topology=topo, churn=churn)
-        b = _sim_run(None, topology=topo, churn=churn)
+        a = _sim_run(True, topology=topo, churn=churn)
+        b = _sim_run(False, topology=topo, churn=churn)
         assert a == b
 
 
@@ -254,23 +303,96 @@ def test_blocked_matches_reference_core():
     assert logs[False] == logs[True]
 
 
-@pytest.mark.parametrize("batched", [None, False], ids=["blocked", "dict"])
+def _answer_sentinel(sched, query, sentinel, calls):
+    """Make the kernel's ``query`` answer ``sentinel`` (its "no matrix
+    row" answer) every time, counting the calls."""
+    def answer(*args):
+        calls[query] += 1
+        return sentinel
+    setattr(sched._kernel, query, answer)
+
+
+@pytest.mark.parametrize("query,sentinel",
+                         [("step2_winner", -1), ("step3_candidates", None)],
+                         ids=["step2", "step3"])
+def test_sentinel_falls_through_to_pool_filter(query, sentinel):
+    """A kernel answer of "no matrix row" (-1 in step 2, None in step 3)
+    hands the task to the per-task pool filter; with every answer forced to
+    the sentinel, whole runs on the 8-node testbed (with churn, flat and in
+    racks) still make the reference's decisions."""
+    from repro.sim import TopologySpec
+    calls = {query: 0}
+    for topo in (None, TopologySpec(rack_size=4, racks_per_site=2)):
+        kw = dict(workflow="sarek", scale=0.3, n_nodes=8, churn=True,
+                  topology=topo)
+        want = _sim_run(True, **kw)
+        got = _sim_run(False, patch=lambda s: _answer_sentinel(
+            s, query, sentinel, calls), **kw)
+        assert got == want
+    assert calls[query] > 0
+
+
+def test_constrained_step2_pool_matches_reference():
+    """Task 2's only input sits on node 1 alone.  Task 1's step-2 COP takes
+    node 1's slot as its source, so when step 2 visits task 2 in the same
+    pass its input has no free-slot source: the pool is constrained to the
+    holders (``cop_feasible_targets``), the kernel is bypassed and the
+    per-task probe finds no candidate -- exactly as the reference does."""
+    from repro.core import ReferenceWowScheduler
+
+    def build(cls):
+        nodes = {i: NodeState(i, 8 * GiB, 8.0,
+                              free_cores=0.0 if i == 1 else 8.0)
+                 for i in range(4)}
+        dps = DataPlacementService(seed=0)
+        dps.register_file(FileSpec(1, 10 * MB, 0), 1)
+        dps.register_file(FileSpec(2, 20 * MB, 0), 1)
+        sched = cls(nodes, dps)
+        sched.submit(TaskSpec(id=1, abstract="a", mem=GiB, cores=1.0,
+                              inputs=(1,), priority=2.0))
+        sched.submit(TaskSpec(id=2, abstract="a", mem=GiB, cores=1.0,
+                              inputs=(2,), priority=1.0))
+        sched._step3_speculative_prepare = lambda actions: None
+        return sched
+
+    sched = build(WowScheduler)
+    pools = []
+    probe = sched._step2_probe_task
+
+    def spy(tid, t, feas, pool, actions):
+        pools.append((tid, feas, set(pool)))
+        return probe(tid, t, feas, pool, actions)
+    sched._step2_probe_task = spy
+    got = _plans(sched.schedule())
+    want = _plans(build(ReferenceWowScheduler).schedule())
+    assert pools == [(2, {1}, set())]
+    assert got == want
+    assert [(task, target) for _, task, target, _ in got] == [(1, 0)]
+
+
+def _plans(actions):
+    return [(a.plan.id, a.plan.task_id, a.plan.target, a.plan.transfers)
+            for a in actions if isinstance(a, StartCop)]
+
+
+@pytest.mark.parametrize("racks", [False, True], ids=["blocked", "rack"])
 @pytest.mark.parametrize("workflow,scale", [("sarek", 0.3),
                                             ("rangeland", 0.1)])
-def test_pretest_matches_reference_core(workflow, scale, batched):
+def test_pretest_matches_reference_core(workflow, scale, racks):
     """The drain's emptiness pre-test against the frozen reference, on the
     paper's 8-node testbed with one COP per node, where scarce slots and
     cores leave most visited tasks without a candidate: the skips must be
-    real in both steps and change no decision."""
-    from repro.sim import SimConfig, Simulation
+    real in both steps and change no decision -- flat, and in racks of
+    four, where the kernel's locality cost row picks the step-2 winners."""
+    from repro.sim import SimConfig, Simulation, TopologySpec
     from repro.workloads import make_workflow
 
+    topo = TopologySpec(rack_size=4) if racks else None
     runs = {}
     skipped = {2: 0, 3: 0}
     for ref in (False, True):
         cfg = SimConfig(n_nodes=8, c_node=1, reference_core=ref,
-                        vectorized=None if batched is None else False,
-                        batched=batched)
+                        topology=topo)
         sim = Simulation(make_workflow(workflow, scale=scale), cfg, "wow")
         if not ref:
             sched = sim.strategy.sched
@@ -295,7 +417,7 @@ def test_pretest_matches_reference_core(workflow, scale, batched):
 @given(st.integers(0, 10 ** 6))
 def test_blocked_parity_property(seed):
     """Randomized workloads x cluster sizes x churn x topology: the blocked
-    and per-task drains must agree action-for-action."""
+    drain and the frozen reference must agree action-for-action."""
     from repro.sim import TopologySpec
 
     rng = random.Random(seed)
@@ -309,85 +431,4 @@ def test_blocked_parity_property(seed):
         if rng.random() < 0.5 else None
     kw = dict(workflow=workflow, scale=scale, n_nodes=n_nodes,
               seed=seed % 1000, churn=churn, topology=topo)
-    assert _sim_run(False, **kw) == _sim_run(None, **kw)
-
-
-# ----------------------------------------------------------------- jax twin
-def test_jax_winner_matches_numpy():
-    """Whole-sim parity of the jax twin, which must also leave the
-    process-wide x64 flag as it found it (x64 is scoped to each call)."""
-    jax = pytest.importorskip("jax")
-    from repro.sim import SimConfig, Simulation
-    from repro.workloads import make_workflow
-
-    before = jax.config.jax_enable_x64
-    a = _sim_run(True, workflow="group", scale=0.4, n_nodes=10)
-    b = _sim_run("jax", workflow="group", scale=0.4, n_nodes=10)
-    assert a == b
-    assert jax.config.jax_enable_x64 == before
-    sim = Simulation(make_workflow("group", scale=0.4),
-                     SimConfig(n_nodes=10, batched="jax"), "wow")
-    sim.run()
-    stats = sim.strategy.sched.device_stats
-    assert stats["dispatches"] > 0
-    assert stats["platforms"] == [jax.devices()[0].platform]
-    assert jax.config.jax_enable_x64 == before
-
-
-def _winner_oracle(key, ids):
-    import numpy as np
-    m0 = key.min()
-    return int(np.where(key == m0, ids, np.iinfo(np.int64).max).min())
-
-
-def test_jax_winner_padding_unit():
-    pytest.importorskip("jax")
-    import numpy as np
-    from repro.core.copmatrix import JaxWinner
-    winner = JaxWinner()
-    rng = np.random.default_rng(0)
-    for n in (1, 3, 7, 16, 33):
-        key = rng.integers(0, 5, n).astype(np.float64)
-        ids = rng.permutation(n).astype(np.int64)
-        assert winner(key, ids) == _winner_oracle(key, ids)
-        ikey = rng.integers(0, 5, n).astype(np.int64)
-        assert winner(ikey, ids) == _winner_oracle(ikey, ids)
-    assert winner.dispatches == 10
-
-
-def test_jax_winner_near_ties():
-    """Keys one ulp apart stay apart and exact ties split by id -- the
-    cases the TPU's emulated f64 comparison got wrong."""
-    pytest.importorskip("jax")
-    import numpy as np
-    from repro.core.copmatrix import JaxWinner
-    winner = JaxWinner()
-    rng = np.random.default_rng(1)
-    for trial in range(20):
-        n = 64
-        base = rng.uniform(1e9, 1e11)
-        key = np.full(n, 3 * base)
-        idx = rng.choice(n, 6, replace=False)
-        key[idx[:3]] = base
-        key[idx[3:]] = np.nextafter(base, np.inf)
-        if trial % 2:
-            key[idx[0]] = np.nextafter(base, -np.inf)
-        key[rng.choice(n, 8, replace=False)] = np.inf
-        ids = rng.permutation(n).astype(np.int64)
-        assert winner(key, ids) == _winner_oracle(key, ids)
-
-
-def test_ordered_int64_keeps_order_and_ties():
-    import numpy as np
-    from repro.core.copmatrix import ordered_int64
-    rng = np.random.default_rng(2)
-    base = rng.normal(size=200) * 10.0 ** rng.integers(-5, 12, 200)
-    vals = np.concatenate([
-        base, np.nextafter(base, np.inf), np.nextafter(base, -np.inf),
-        [0.0, -0.0, np.inf, -np.inf, 5e-324, -5e-324, 1.0, 1.0]])
-    ik = ordered_int64(vals)
-    assert ik.dtype == np.int64
-    assert ((ik[:, None] < ik[None, :])
-            == (vals[:, None] < vals[None, :])).all()
-    assert ((ik[:, None] == ik[None, :])
-            == (vals[:, None] == vals[None, :])).all()
+    assert _sim_run(True, **kw) == _sim_run(False, **kw)

@@ -239,48 +239,77 @@ def test_dps_greedy_balances_sources(seed, n_nodes, n_files):
 
 
 # ------------------------------------------- step-2 partial-present sort
-@pytest.mark.parametrize("vectorized", [False, None])
-def test_step2_partial_present_bytes_order(vectorized):
+def _racks_of_two(n_nodes):
+    """Racks of two nodes under one site (cost weights 1 in-rack, 4 across
+    racks): the topology under which the kernel computes its locality cost
+    row."""
+    from repro.sim import Topology, TopologySpec
+    return Topology(TopologySpec(rack_size=2), n_nodes, 1.25e8)
+
+
+@pytest.mark.parametrize("topology", [None, "rack"])
+def test_step2_partial_present_bytes_order(topology):
     """Step-2's *mixed* sort branch: some candidates hold input bytes, some
     none -- the key is ``(task_bytes - present.get(n, 0), n)``, so the node
     missing the fewest bytes wins and equal-missing ties split by node id.
-    (The all-empty and topology branches are pinned elsewhere.)"""
+    Under racks of two the key is the locality-weighted missing cost
+    (``dps.locality_missing_cost``), with the same tie-break."""
     MB = 1024 ** 2
-    nodes = {i: NodeState(i, mem=8 * GiB, cores=8.0) for i in range(4)}
-    dps = DataPlacementService(seed=0)
-    # file A (100 MB) on nodes 2 and 3; file B (50 MB) on node 1; node 0
-    # holds nothing.  Missing bytes: n0=150M, n1=100M, n2=50M, n3=50M.
-    dps.register_file(FileSpec(1, 100 * MB, 0), 2)
-    dps.add_replica(1, 3)
-    dps.register_file(FileSpec(2, 50 * MB, 0), 1)
-    sched = WowScheduler(nodes, dps, c_task=1, vectorized=vectorized)
-    sched.submit(TaskSpec(id=1, abstract="a", mem=GiB, cores=1.0,
-                          inputs=(1, 2), priority=1.0))
+
+    def build(cls):
+        nodes = {i: NodeState(i, mem=8 * GiB, cores=8.0) for i in range(4)}
+        dps = DataPlacementService(seed=0)
+        if topology:
+            dps.set_topology(_racks_of_two(4))
+        # file A (100 MB) on nodes 2 and 3; file B (50 MB) on node 1; node
+        # 0 holds nothing.  Missing bytes: n0=150M, n1=100M, n2=n3=50M;
+        # in racks {0, 1} and {2, 3}: n0=450M, n1=400M, n2=n3=200M.
+        dps.register_file(FileSpec(1, 100 * MB, 0), 2)
+        dps.add_replica(1, 3)
+        dps.register_file(FileSpec(2, 50 * MB, 0), 1)
+        sched = cls(nodes, dps, c_task=1)
+        sched.submit(TaskSpec(id=1, abstract="a", mem=GiB, cores=1.0,
+                              inputs=(1, 2), priority=1.0))
+        return sched, nodes, dps
+
+    sched, nodes, dps = build(WowScheduler)
     actions = sched.schedule()
     cops = [a for a in actions if isinstance(a, StartCop)]
     assert len(cops) == 1
     plan = cops[0].plan
-    # the dict oracle's own key, computed independently
-    present = dps.present_bytes_map(1)
-    tb = dps.task_input_bytes(1)
-    oracle = min(nodes, key=lambda n: (tb - present.get(n, 0), n))
+    # the sort key, computed independently
+    if topology:
+        cost = {n: dps.locality_missing_cost(1, n) for n in nodes}
+        assert cost == {0: 450 * MB, 1: 400 * MB, 2: 200 * MB, 3: 200 * MB}
+    else:
+        present = dps.present_bytes_map(1)
+        cost = {n: dps.task_input_bytes(1) - present.get(n, 0)
+                for n in nodes}
+    oracle = min(nodes, key=lambda n: (cost[n], n))
     assert oracle == 2          # tie between 2 and 3 splits by id
     assert plan.target == 2
+    from repro.core import ReferenceWowScheduler
+    ref = build(ReferenceWowScheduler)[0]
+    assert _cops(actions) == _cops(ref.schedule())
     # node 2 already holds A, so the COP moves exactly file B from node 1
     assert [(t.file_id, t.src, t.dst) for t in plan.transfers] == \
         [(2, 1, 2)]
 
 
 # ------------------------------------------- step-2/3 emptiness pre-test
-def _pretest_sched(cls, free_cores, files, tasks, active_cops=(), **kw):
+def _pretest_sched(cls, free_cores, files, tasks, active_cops=(),
+                   topology=None, **kw):
     """Nodes of 8 GiB and 8 cores with the given free cores (and a busy
     COP slot on the nodes in ``active_cops``), 1 MB files ``{id:
     holders}``, and data-bound tasks ``(id, inputs, priority)`` of one
-    shape, 1 GiB and 1 core."""
+    shape, 1 GiB and 1 core; ``topology="rack"`` puts the nodes in racks
+    of two."""
     nodes = {i: NodeState(i, mem=8 * GiB, cores=8.0, free_cores=fc,
                           active_cops=int(i in active_cops))
              for i, fc in enumerate(free_cores)}
     dps = DataPlacementService(seed=0)
+    if topology:
+        dps.set_topology(_racks_of_two(len(nodes)))
     for fid, holders in files.items():
         dps.register_file(FileSpec(fid, 1024 ** 2, 0), holders[0])
         for n in holders[1:]:
@@ -298,11 +327,11 @@ def _cops(actions):
             for a in actions if isinstance(a, StartCop)]
 
 
-def _schedule_pretest(vectorized, *case, **kw):
+def _schedule_pretest(topology, *case, **kw):
     """One ``schedule()`` of the case on the scheduler under test, with its
     pre-test skips per step, beside the frozen reference's COPs."""
     from repro.core import ReferenceWowScheduler
-    sched = _pretest_sched(WowScheduler, *case, vectorized=vectorized, **kw)
+    sched = _pretest_sched(WowScheduler, *case, topology=topology, **kw)
     skipped = {2: 0, 3: 0}
 
     def counted(step, run_step):
@@ -316,7 +345,8 @@ def _schedule_pretest(vectorized, *case, **kw):
     sched._step3_speculative_prepare = counted(
         3, sched._step3_speculative_prepare)
     got = _cops(sched.schedule())
-    want = _cops(_pretest_sched(ReferenceWowScheduler, *case).schedule())
+    want = _cops(_pretest_sched(ReferenceWowScheduler, *case,
+                                topology=topology).schedule())
     return sched, got, want, skipped
 
 
@@ -336,14 +366,14 @@ def _count_subset_tests(sched, monkeypatch):
     monkeypatch.setattr(_CountingSet, "tests", 0)
 
 
-@pytest.mark.parametrize("vectorized", [False, None])
-def test_step2_shape_dies_mid_pass(vectorized):
+@pytest.mark.parametrize("topology", [None, "rack"])
+def test_step2_shape_dies_mid_pass(topology):
     """Node 0 alone has free cores.  Task 1's COP takes its slot, which
     kills the shape: task 2's probe comes back empty and proves it, and
     tasks 3 and 4, of the same shape, are skipped -- with the reference's
     COPs, ids and transfers unchanged."""
     sched, got, want, skipped = _schedule_pretest(
-        vectorized, [8.0, 0.0, 0.0, 0.0], {1: [1], 2: [2], 3: [3], 4: [2]},
+        topology, [8.0, 0.0, 0.0, 0.0], {1: [1], 2: [2], 3: [3], 4: [2]},
         [(1, (1,), 4.0), (2, (2,), 3.0), (3, (3,), 2.0), (4, (4,), 1.0)])
     assert skipped == {2: 2, 3: 0}
     assert got == want
@@ -351,45 +381,70 @@ def test_step2_shape_dies_mid_pass(vectorized):
     assert [(task, target) for _, task, target, _ in got] == [(1, 0), (2, 3)]
 
 
-@pytest.mark.parametrize("vectorized", [False, None])
-def test_step3_task_prepared_on_every_free_slot_node_is_skipped(vectorized):
+@pytest.mark.parametrize("topology", [None, "rack"])
+def test_step3_task_prepared_on_every_free_slot_node_is_skipped(topology):
     """No node has free cores, and task 1's input is on both nodes: step 3
     sees its prepared set cover the free-slot set and skips it."""
     sched, got, want, skipped = _schedule_pretest(
-        vectorized, [0.0, 0.0], {1: [0, 1]}, [(1, (1,), 1.0)])
+        topology, [0.0, 0.0], {1: [0, 1]}, [(1, (1,), 1.0)])
     assert skipped == {2: 0, 3: 1}
     assert got == want == []
     assert sched.drain_probed == 2            # one visit in each step
 
 
-@pytest.mark.parametrize("vectorized", [False, None])
+@pytest.mark.parametrize("topology", [None, "rack"])
 def test_step3_task_prepared_on_all_but_one_free_slot_node_is_probed(
-        vectorized, monkeypatch):
+        topology, monkeypatch):
     """Task 1 is prepared on three nodes and the free-slot set has three,
     but node 3's slot is busy and node 2 lacks the input: the subset test
     runs, fails, and the probe starts a COP to node 2."""
     case = ([0.0] * 4, {1: [0, 1, 3]}, [(1, (1,), 1.0)])
     sched = _pretest_sched(WowScheduler, *case, active_cops=(3,),
-                           vectorized=vectorized)
+                           topology=topology)
     _count_subset_tests(sched, monkeypatch)
     got = _cops(sched.schedule())
     assert _CountingSet.tests == 1
     assert sched.drain_skipped == 0
     from repro.core import ReferenceWowScheduler
-    ref = _pretest_sched(ReferenceWowScheduler, *case, active_cops=(3,))
+    ref = _pretest_sched(ReferenceWowScheduler, *case, active_cops=(3,),
+                         topology=topology)
     assert got == _cops(ref.schedule())
     assert [(task, target) for _, task, target, _ in got] == [(1, 2)]
 
 
-@pytest.mark.parametrize("vectorized", [False, None])
-def test_step3_length_guard_decides_without_subset_test(vectorized,
+@pytest.mark.parametrize("topology", [None, "rack"])
+def test_step3_length_guard_decides_without_subset_test(topology,
                                                         monkeypatch):
     """Four free-slot nodes against one prepared node: the length guard
     answers and no subset test is made; the task is probed as before."""
     sched = _pretest_sched(WowScheduler, [0.0] * 4, {1: [0]},
-                           [(1, (1,), 1.0)], vectorized=vectorized)
+                           [(1, (1,), 1.0)], topology=topology)
     _count_subset_tests(sched, monkeypatch)
     got = _cops(sched.schedule())
     assert _CountingSet.tests == 0
     assert sched.drain_skipped == 0
     assert [(task, target) for _, task, target, _ in got] == [(1, 1)]
+    from repro.core import ReferenceWowScheduler
+    ref = _pretest_sched(ReferenceWowScheduler, [0.0] * 4, {1: [0]},
+                         [(1, (1,), 1.0)], topology=topology)
+    assert got == _cops(ref.schedule())
+
+
+# ------------------------------------------- one implementation, no switches
+@pytest.mark.parametrize("key", ["vectorized", "batched"])
+def test_scheduler_and_adapter_refuse_removed_switches(key):
+    """The scheduler has one node state and one drain: the keywords that
+    used to select between implementations are unknown."""
+    from repro.core import make_adapter
+    nodes = {0: NodeState(0, mem=8 * GiB, cores=8.0)}
+    with pytest.raises(TypeError, match=key):
+        WowScheduler(nodes, DataPlacementService(seed=0), **{key: False})
+    with pytest.raises(TypeError, match=key):
+        make_adapter("wow", nodes, **{key: False})
+
+
+@pytest.mark.parametrize("key", ["vectorized", "batched"])
+def test_simconfig_refuses_removed_switches(key):
+    from repro.sim import SimConfig
+    with pytest.raises(TypeError, match=key):
+        SimConfig(**{key: False})

@@ -1,22 +1,23 @@
 """Vectorized hot node state (core/nodearray.py): parity + property tests.
 
-Four layers of proof that ``vectorized=True`` changes nothing but speed:
+Four layers of proof that the array node state makes the decisions a walk
+over the ``NodeState`` dict makes:
 
 * ``NodeCapacityArray`` property test -- a randomized add/drop/rejoin/
   mutate stream; after every event the array must equal a from-scratch
   rebuild of the reference dict state, keep canonical (NodeOrder) slot
-  order, and answer every query bit-identically to brute force and to the
-  dict ``CapacityClasses``.
+  order, and answer every query bit-identically to brute force.
 * compaction test -- mass drops push the array through ``_compact`` while
   the same invariants hold.
 * scheduler-stream property test -- a full simulation with node failure +
   elastic join; after *every* ``schedule()`` the array mirrors the live
   ``NodeState`` dict exactly.
-* full-sim bit-identity -- actions (``sim.action_log``) and makespans are
-  identical for ``vectorized=True`` vs ``False`` across all three
-  strategies, with and without churn; plus truncation parity: a
-  multi-shape input-less component past the exact gate is solved via
-  ``_truncate_component`` yet matches the untruncated ``ilp.solve``.
+* full-sim bit-identity -- actions (``sim.action_log``), makespans and
+  event counts are identical to the frozen reference core
+  (``SimConfig(reference_core=True)``) across workflows, with and without
+  churn; plus truncation parity: a multi-shape input-less component past
+  the exact gate is solved via ``_truncate_component`` yet matches the
+  untruncated ``ilp.solve`` and the reference's decisions.
 """
 from __future__ import annotations
 
@@ -25,16 +26,12 @@ import random
 
 import pytest
 
-from repro.core import (HAVE_NUMPY, CapacityClasses, DataPlacementService,
-                        NodeOrder, NodeState, StartTask, TaskSpec,
+from repro.core import (DataPlacementService, NodeOrder, NodeState,
+                        ReferenceWowScheduler, StartTask, TaskSpec,
                         WowScheduler)
 from repro.core.ilp import AssignmentProblem, solve as ilp_solve
 
 from _hyp import given, settings, st
-
-pytestmark = pytest.mark.skipif(
-    not HAVE_NUMPY, reason="numpy not available: the vectorized path is off "
-                           "and the dict path is already covered elsewhere")
 
 GiB = 1024 ** 3
 C_NODE = 2
@@ -47,24 +44,16 @@ def _mirror(nodes: dict[int, NodeState]) -> dict:
 
 def _check_queries(cap, nodes, order, rng) -> None:
     """One random probe shape: every query surface vs brute force over the
-    canonical enumeration, plus the dict CapacityClasses twin."""
+    canonical enumeration."""
     mem = rng.randrange(0, 9) * GiB
     cores = rng.uniform(0.0, 17.0)
     brute = [n for n in order
              if nodes[n].free_mem >= mem and nodes[n].free_cores >= cores]
     assert cap.fitting(mem, cores) == brute
-    assert cap.any_fit(mem, cores) == bool(brute)
-    ids, slots = cap.fitting_with_slots(mem, cores)
-    assert ids == brute
-    assert [int(cap._node_of[s]) for s in slots] == brute
-    dict_cc = CapacityClasses(nodes, order)
-    assert dict_cc.fitting(mem, cores) == brute
-    assert dict_cc.any_fit(mem, cores) == bool(brute)
     assert cap.free_slot_fit_ids(mem, cores) == [
         n for n in brute if nodes[n].active_cops < C_NODE]
-    assert cap.free_slot_total_fit_ids(mem, cores) == [
-        n for n in order if nodes[n].active_cops < C_NODE
-        and nodes[n].mem >= mem and nodes[n].cores >= cores]
+    slots = cap.slots_of(brute)
+    assert [int(cap._node_of[s]) for s in slots] == brute
     sub = [n for n in order if rng.random() < 0.5]
     assert cap.filter_fitting(sub, mem, cores) == [
         n for n in sub
@@ -173,13 +162,11 @@ def test_scheduler_stream_mirrors_nodes(seed):
 
     rng = random.Random(seed)
     wf = make_workflow("group", scale=0.4, seed=seed % 97)
-    sim = Simulation(wf, SimConfig(n_nodes=10, dfs="ceph", vectorized=True),
-                     "wow")
+    sim = Simulation(wf, SimConfig(n_nodes=10, dfs="ceph"), "wow")
     sim.schedule_failure(rng.uniform(5.0, 40.0), rng.randrange(10))
     sim.schedule_join(rng.uniform(10.0, 60.0), 10)
     sched = sim.strategy.sched
     cap = sched._cap_array
-    assert cap is not None
     orig_schedule = sched.schedule
     checks = {"n": 0}
 
@@ -195,34 +182,35 @@ def test_scheduler_stream_mirrors_nodes(seed):
     assert checks["n"] > 0
 
 
-@pytest.mark.parametrize("strat", ["wow", "orig", "cws"])
+@pytest.mark.parametrize("workflow", ["rnaseq", "syn_blast", "chain"])
 @pytest.mark.parametrize("churn", [False, True])
-def test_full_sim_bit_identity(strat, churn):
-    """Actions and makespan identical with vectorized hot state on vs off
-    (for orig/cws the flag only proves the plumbing is inert)."""
+def test_full_sim_bit_identity(workflow, churn):
+    """Actions, makespan and event count identical to the frozen reference
+    core, on workflows the drain tests do not run, through a node failure
+    and an elastic join."""
     from repro.sim import SimConfig, Simulation
     from repro.workloads import make_workflow
 
     runs = {}
-    for vec in (False, True):
-        wf = make_workflow("group", scale=0.6)
+    for ref in (False, True):
+        wf = make_workflow(workflow, scale=0.6)
         sim = Simulation(wf, SimConfig(n_nodes=14, dfs="ceph",
-                                       vectorized=vec), strat)
+                                       reference_core=ref), "wow")
         if churn:
             sim.schedule_failure(15.0, 3)
             sim.schedule_join(30.0, 14)
         r = sim.run()
-        runs[vec] = (sim.action_log, r.makespan, r.sim_steps)
+        runs[ref] = (sim.action_log, r.makespan, r.sim_steps)
     assert runs[True][0] == runs[False][0], "action log diverged"
     assert runs[True][1] == runs[False][1], "makespan diverged"
     assert runs[True][2] == runs[False][2], "event count diverged"
 
 
 # --------------------------------------------------------- truncation parity
-def _trunc_setup(vectorized: bool):
+def _trunc_setup(seed: int, cls=WowScheduler):
     """A multi-shape input-less backlog far beyond cluster capacity, on a
     jittered cluster, past the exact gate -- the truncation path's regime."""
-    rng = random.Random(7)
+    rng = random.Random(seed)
     nodes = {}
     for i in range(12):
         s = NodeState(i, 16 * GiB, 16.0)
@@ -230,7 +218,7 @@ def _trunc_setup(vectorized: bool):
         s.free_cores = float(rng.randrange(2, 5))
         nodes[i] = s
     dps = DataPlacementService(seed=0)
-    sched = WowScheduler(nodes, dps, vectorized=vectorized)
+    sched = cls(nodes, dps)
     shapes = [(4 * GiB, 1.0), (8 * GiB, 2.0), (6 * GiB, 1.5)]
     specs = []
     tid = 0
@@ -248,9 +236,9 @@ def _placed(actions) -> dict[int, int]:
     return {a.task_id: a.node for a in actions if isinstance(a, StartTask)}
 
 
-@pytest.mark.parametrize("vectorized", [True, False])
-def test_truncation_matches_untruncated_solve(vectorized):
-    sched, nodes, specs = _trunc_setup(vectorized)
+@pytest.mark.parametrize("seed", [7, 11])
+def test_truncation_matches_untruncated_solve(seed):
+    sched, nodes, specs = _trunc_setup(seed)
     # oracle: the untruncated tiered solve on a snapshot of the same state
     oracle_nodes = {n: copy.deepcopy(s) for n, s in nodes.items()}
     cand = {t.id: [n for n in range(12)
@@ -267,8 +255,11 @@ def test_truncation_matches_untruncated_solve(vectorized):
 
 
 def test_truncation_vectorized_matches_dict():
-    sched_v, _, _ = _trunc_setup(True)
-    sched_d, _, _ = _trunc_setup(False)
-    assert _placed(sched_v.schedule()) == _placed(sched_d.schedule())
-    assert (sched_v.inputless_stats["trunc_solves"]
-            == sched_d.inputless_stats["trunc_solves"] >= 1)
+    """The truncated solve places what the frozen reference places on the
+    same backlog (its monolithic solve is past its exact gate, so greedy
+    over the untruncated instance)."""
+    sched, _, _ = _trunc_setup(7)
+    ref, _, _ = _trunc_setup(7, ReferenceWowScheduler)
+    placed = _placed(sched.schedule())
+    assert sched.inputless_stats["trunc_solves"] >= 1
+    assert placed == _placed(ref.schedule())

@@ -2,8 +2,8 @@
 
 Four layers:
 
-* ReadySet / NodeOrder / CapacityClasses as data structures, against
-  from-scratch oracles over random operation streams.
+* ReadySet / NodeOrder / the capacity array's fitting query as data
+  structures, against from-scratch oracles over random operation streams.
 * DPS source-feasibility index (`_free_rep` / `_unsourced` / `cop_blocked`)
   against brute-force recomputation over random replica + slot mutations.
 * The scheduler's materialized step-2/3 visit orders against a full sort of
@@ -19,7 +19,7 @@ import random
 
 import pytest
 
-from repro.core import (CapacityClasses, DataPlacementService, FileSpec,
+from repro.core import (DataPlacementService, FileSpec, NodeCapacityArray,
                         NodeOrder, NodeState, ReadySet,
                         ReferenceWowScheduler, ShapeIndex, StartCop,
                         StartTask, TaskSpec, WowScheduler)
@@ -44,27 +44,30 @@ def test_node_order_basic():
     assert 2 in order and 7 not in order and len(order) == 3
 
 
-# ----------------------------------------------------------- CapacityClasses
+# ------------------------------------------------------- capacity classes
 @pytest.mark.parametrize("seed", range(5))
 def test_capacity_classes_match_bruteforce(seed):
+    """The input-less path's capacity classes -- "all nodes whose free
+    resources fit (m, c)", in canonical order -- answered by the capacity
+    array under the scheduler's refresh/drop calls."""
     rng = random.Random(seed)
     nodes = {i: NodeState(i, mem=rng.randint(4, 10), cores=float(
         rng.randint(2, 8))) for i in range(rng.randint(2, 8))}
     order = NodeOrder(nodes)
-    cap = CapacityClasses(nodes, order)
+    cap = NodeCapacityArray(nodes, order)
     for _ in range(60):
         op = rng.randrange(3)
         if op == 0 and nodes:                    # mutate free resources
             n = rng.choice(list(nodes))
             nodes[n].free_mem = rng.randint(0, 10)
             nodes[n].free_cores = float(rng.randint(0, 8))
-            cap.refresh(n)
+            cap.refresh_many([n], nodes)
         elif op == 1:                            # add a node
             n = max(nodes, default=-1) + 1
             nodes[n] = NodeState(n, mem=rng.randint(4, 10),
                                  cores=float(rng.randint(2, 8)))
             order.add(n)
-            cap.refresh(n)
+            cap.add(n, nodes[n])
         elif op == 2 and len(nodes) > 1:         # drop a node
             n = rng.choice(list(nodes))
             del nodes[n]
@@ -74,7 +77,6 @@ def test_capacity_classes_match_bruteforce(seed):
         expect = [n for n in order
                   if nodes[n].free_mem >= mem and nodes[n].free_cores >= cores]
         assert cap.fitting(mem, cores) == expect
-        assert cap.any_fit(mem, cores) == bool(expect)
 
 
 # ----------------------------------------------------------------- ReadySet

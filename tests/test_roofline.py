@@ -43,8 +43,6 @@ def test_collective_cost_model_on_synthetic_hlo():
 
 
 def test_analyzer_loop_and_flops_subprocess():
-    pytest.importorskip("numpy", reason="the subprocess runs jax (and "
-                        "inherits the no-numpy shim via PYTHONPATH)")
     code = textwrap.dedent("""\
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"

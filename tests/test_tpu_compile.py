@@ -7,8 +7,7 @@ VMEM.  Nothing runs, so they say nothing about results or speed.
 
 Shapes are mamba2-780m's published widths (48 SSD heads x 64, state 128,
 chunk 256): the serving prefill (1 x 512 tokens) and the training step
-(4 x 2048 tokens, forward and the reference-VJP backward), plus the
-scheduler's jitted winner reduction over a 1024-node cluster.
+(4 x 2048 tokens, forward and the reference-VJP backward).
 
 The topology is described in a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and under several
@@ -93,11 +92,3 @@ def test_ssd_kernel_grad_compiles_for_v5e(one_chip):
     grad = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)))
     compiled = grad.lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_winner_reduction_compiles_for_v5e(one_chip):
-    from repro.core.copmatrix import _jax_select
-    with jax.enable_x64(True):
-        spec = jax.ShapeDtypeStruct((1024,), jnp.int64, sharding=one_chip)
-        compiled = _jax_select().lower(spec, spec).compile()
-    assert compiled.memory_analysis() is not None

@@ -11,7 +11,7 @@ Covers the traffic PR's guarantees:
 * **determinism + parity** -- the arrival schedule is a pure function of
   the ``TrafficConfig`` (same seed => identical stream), a full traffic
   run replays bit-identically (action log and ``TrafficResult``), and the
-  wow strategy's vectorized/dict paths agree under traffic;
+  wow strategy agrees with the frozen reference core under traffic;
 * **admission semantics** -- arrivals are conserved (admitted + rejected
   == schedule length) and nothing is silently dropped: every admitted
   instance either completes or is reported in ``incomplete`` with a
@@ -161,15 +161,14 @@ def test_traffic_run_replays_bit_identically(strategy, seed):
 
 
 def test_traffic_vectorized_parity():
-    """wow's vectorized and dict hot-state paths agree under traffic."""
-    pytest.importorskip("numpy", reason="vectorized=True requires numpy")
+    """wow and the frozen reference core agree under traffic."""
     tr = _small_traffic(seed=3, max_backlog=4)
     outs = {}
-    for vec in (False, True):
-        sim = Simulation(None, SimConfig(n_nodes=16, vectorized=vec),
+    for ref in (False, True):
+        sim = Simulation(None, SimConfig(n_nodes=16, reference_core=ref),
                          "wow", traffic=tr)
         sim.run()
-        outs[vec] = (repr(sim.action_log),
+        outs[ref] = (repr(sim.action_log),
                      dataclasses.asdict(sim.traffic_result()))
     assert outs[False] == outs[True]
 
