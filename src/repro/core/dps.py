@@ -34,21 +34,35 @@ mutation funnels through ``_idx_add`` / ``_idx_remove`` which keep all six
 indices consistent and record tasks whose prepared-node set changed in a
 dirty set the scheduler drains via :meth:`drain_dirty_tasks`.
 
-Source-feasibility index (DESIGN.md "Indexed ready set"): when the owning
-scheduler activates it via :meth:`sync_free_sources` and then mirrors every
-free-COP-slot transition through :meth:`note_source_freed` /
-:meth:`note_source_busy`, the DPS additionally maintains, per file, the
-number of replicas on free-slot nodes (``_free_rep``) and, per tracked
-task, the number of distinct inputs with *no* free-slot replica
-(``_unsourced``).  :meth:`cop_blocked` then answers "is a COP for this task
-provably infeasible right now?" in O(1): with any unsourced input the only
-feasible targets are free-slot nodes already holding *all* unsourced
-inputs (:meth:`cop_feasible_targets`) -- and a free-slot node holding one
-would have made it sourced, so no such target exists, every probe would
-fail, and steps 2-3 may skip the task without changing any decision.  Tasks whose blocked state may have flipped land in a dirty set
-drained via :meth:`drain_blocked_dirty`.  The index is inert (and free)
-until ``sync_free_sources`` is called; the reference scheduler never calls
-it.
+Source-feasibility partition (the DESIGN.md section of that name): when
+the owning scheduler activates it via :meth:`sync_free_sources` and then
+mirrors every free-COP-slot transition through :meth:`note_source_freed` /
+:meth:`note_source_busy`, the DPS additionally maintains
+
+  * ``_node_wfiles``      node  -> *waited* files (some tracked task has
+                                   them in ``_waiting``) with a replica on
+                                   the node
+  * ``_free_rep``         waited file -> replicas on free-slot nodes
+  * ``_unsourced``        task  -> distinct inputs with *no* free-slot
+                                   replica
+
+:meth:`cop_blocked` then answers "is a COP for this task provably
+infeasible right now?" in O(1): with any unsourced input the only feasible
+targets are free-slot nodes already holding *all* unsourced inputs
+(:meth:`cop_feasible_targets`) -- and a free-slot node holding one would
+have made it sourced, so no such target exists, every probe would fail,
+and steps 2-3 may skip the task without changing any decision.  Tasks
+whose blocked state may have flipped land in a dirty set drained via
+:meth:`drain_blocked_dirty`.
+
+Only waited files are counted: a slot transition walks
+``_node_wfiles[node]``, not every replica on the node.  That changes no
+decision, because a file's free-replica count is read only through its
+waiters (``_unsourced``), and a file that gains its first waiter in
+:meth:`track_task` has its count computed there from its locations -- the
+count it would have had if it had been kept all along.  The index is inert
+(and free) until ``sync_free_sources`` is called; the reference scheduler
+never calls it.
 
 The original from-scratch queries (``is_prepared``, ``prepared_nodes``,
 ``missing_files``, ``missing_bytes``) are retained both as the generic API
@@ -93,6 +107,14 @@ class DataPlacementService:
         # replica additions and removals through the two choke points
         self.replica_writes = 0
         trace.counter("dps.replica_writes", self, attrgetter("replica_writes"))
+        # free-COP-slot transitions mirrored into the source-feasibility
+        # index, and the waited files those transitions walked
+        self.source_transitions = 0
+        self.source_files_walked = 0
+        trace.counter("dps.source_transitions", self,
+                      attrgetter("source_transitions"))
+        trace.counter("dps.source_files_walked", self,
+                      attrgetter("source_files_walked"))
         # ----- reverse indices (see module docstring)
         self._node_files: dict[NodeId, set[int]] = {}
         self._waiting: dict[int, set[int]] = {}
@@ -109,7 +131,8 @@ class DataPlacementService:
         # ----- source-feasibility index (inert until sync_free_sources)
         self._src_active = False
         self._free_src: set[NodeId] = set()            # free-COP-slot mirror
-        self._free_rep: dict[int, int] = {}            # file -> free replicas
+        self._node_wfiles: dict[NodeId, set[int]] = {}  # node -> waited files
+        self._free_rep: dict[int, int] = {}            # waited file -> free reps
         self._unsourced: dict[int, int] = {}           # task -> sourceless inputs
         self._blocked_dirty: set[int] = set()
         # ----- batched-drain matrix (core/copmatrix.py): array mirrors of
@@ -194,23 +217,29 @@ class DataPlacementService:
         return self._topo
 
     # ------------------------------------------------------- index plumbing
-    def _free_rep_up(self, file_id: int) -> None:
-        c = self._free_rep.get(file_id, 0) + 1
-        self._free_rep[file_id] = c
-        if c == 1:
-            for tid in self._waiting.get(file_id, _EMPTY):
-                self._unsourced[tid] -= 1
-                self._blocked_dirty.add(tid)
+    def _free_rep_up(self, files) -> None:
+        """One more free-slot replica of each (waited) file in ``files``."""
+        free_rep = self._free_rep
+        for f in files:
+            c = free_rep.get(f, 0) + 1
+            free_rep[f] = c
+            if c == 1:
+                for tid in self._waiting[f]:
+                    self._unsourced[tid] -= 1
+                    self._blocked_dirty.add(tid)
 
-    def _free_rep_down(self, file_id: int) -> None:
-        c = self._free_rep.get(file_id, 0) - 1
-        if c <= 0:
-            self._free_rep.pop(file_id, None)
-            for tid in self._waiting.get(file_id, _EMPTY):
-                self._unsourced[tid] += 1
-                self._blocked_dirty.add(tid)
-        else:
-            self._free_rep[file_id] = c
+    def _free_rep_down(self, files) -> None:
+        """One free-slot replica fewer of each (waited) file in ``files``."""
+        free_rep = self._free_rep
+        for f in files:
+            c = free_rep[f] - 1
+            if c:
+                free_rep[f] = c
+            else:
+                del free_rep[f]
+                for tid in self._waiting[f]:
+                    self._unsourced[tid] += 1
+                    self._blocked_dirty.add(tid)
 
     def _idx_add(self, file_id: int, node: NodeId) -> None:
         locs = self._locations.setdefault(file_id, set())
@@ -219,8 +248,10 @@ class DataPlacementService:
         self.replica_writes += 1
         locs.add(node)
         self._node_files.setdefault(node, set()).add(file_id)
-        if self._src_active and node in self._free_src:
-            self._free_rep_up(file_id)
+        if self._src_active and file_id in self._waiting:
+            self._node_wfiles.setdefault(node, set()).add(file_id)
+            if node in self._free_src:
+                self._free_rep_up((file_id,))
         spec = self._files.get(file_id)
         size = spec.size if spec is not None else 0
         mx = self._mx
@@ -248,8 +279,10 @@ class DataPlacementService:
         held = self._node_files.get(node)
         if held is not None:
             held.discard(file_id)
-        if self._src_active and node in self._free_src:
-            self._free_rep_down(file_id)
+        if self._src_active and file_id in self._waiting:
+            self._node_wfiles[node].discard(file_id)
+            if node in self._free_src:
+                self._free_rep_down((file_id,))
         spec = self._files.get(file_id)
         size = spec.size if spec is not None else 0
         mx = self._mx
@@ -299,9 +332,15 @@ class DataPlacementService:
         cnt: dict[NodeId, int] = {}
         pbytes: dict[NodeId, int] = {}
         for f, m in mult.items():
-            self._waiting.setdefault(f, set()).add(task_id)
+            locs = self._locations.get(f, _EMPTY)
+            waiting = self._waiting.get(f)
+            if waiting is None:
+                waiting = self._waiting[f] = set()
+                if self._src_active:
+                    self._wait_on(f)
+            waiting.add(task_id)
             size = self._files[f].size if f in self._files else 0
-            for n in self._locations.get(f, _EMPTY):
+            for n in locs:
                 cnt[n] = cnt.get(n, 0) + m
                 pbytes[n] = pbytes.get(n, 0) + size * m
         self._present_cnt[task_id] = cnt
@@ -329,7 +368,9 @@ class DataPlacementService:
             if waiting is not None:
                 waiting.discard(task_id)
                 if not waiting:
-                    self._waiting.pop(f, None)
+                    del self._waiting[f]
+                    if self._src_active:
+                        self._unwait(f)
         self._present_cnt.pop(task_id, None)
         self._present_bytes.pop(task_id, None)
         self._task_bytes.pop(task_id, None)
@@ -349,6 +390,23 @@ class DataPlacementService:
         return dirty
 
     # ------------------------------------------- source-feasibility index
+    def _wait_on(self, file_id: int) -> None:
+        """``file_id`` gained its first waiter: index its holders and count
+        its free-slot replicas (before any waiter's ``_unsourced`` reads
+        the count)."""
+        c = 0
+        for n in self._locations.get(file_id, _EMPTY):
+            self._node_wfiles.setdefault(n, set()).add(file_id)
+            c += n in self._free_src
+        if c:
+            self._free_rep[file_id] = c
+
+    def _unwait(self, file_id: int) -> None:
+        """``file_id`` lost its last waiter: no count of it is read now."""
+        for n in self._locations.get(file_id, _EMPTY):
+            self._node_wfiles[n].discard(file_id)
+        self._free_rep.pop(file_id, None)
+
     def sync_free_sources(self, free_nodes) -> None:
         """Activate (or rebuild) the source-feasibility index against the
         scheduler's current free-COP-slot set.  The owner must afterwards
@@ -356,31 +414,35 @@ class DataPlacementService:
         :meth:`note_source_busy`."""
         self._src_active = True
         self._free_src = set(free_nodes)
+        self._node_wfiles = {}
         self._free_rep = {}
-        for f, locs in self._locations.items():
-            c = sum(1 for n in locs if n in self._free_src)
-            if c:
-                self._free_rep[f] = c
+        for f in self._waiting:
+            self._wait_on(f)
         for tid, mult in self._task_mult.items():
             self._unsourced[tid] = sum(
                 1 for f in mult if self._free_rep.get(f, 0) == 0)
             self._blocked_dirty.add(tid)
 
     def note_source_freed(self, node: NodeId) -> None:
-        """Node gained a free COP slot: its replicas became admissible."""
+        """Node gained a free COP slot: its waited files' replicas there
+        became admissible."""
         if not self._src_active or node in self._free_src:
             return
         self._free_src.add(node)
-        for f in self._node_files.get(node, _EMPTY):
-            self._free_rep_up(f)
+        files = self._node_wfiles.get(node, _EMPTY)
+        self.source_transitions += 1
+        self.source_files_walked += len(files)
+        self._free_rep_up(files)
 
     def note_source_busy(self, node: NodeId) -> None:
         """Node lost its last free COP slot (or left the cluster)."""
         if not self._src_active or node not in self._free_src:
             return
         self._free_src.discard(node)
-        for f in self._node_files.get(node, _EMPTY):
-            self._free_rep_down(f)
+        files = self._node_wfiles.get(node, _EMPTY)
+        self.source_transitions += 1
+        self.source_files_walked += len(files)
+        self._free_rep_down(files)
 
     def cop_blocked(self, task_id: int) -> bool:
         """True iff every COP probe for the (tracked) task is provably
@@ -499,6 +561,7 @@ class DataPlacementService:
                 if fid in self._files:
                     lost.append(fid)
         self._node_files.pop(node, None)
+        self._node_wfiles.pop(node, None)
         self._node_prep_tasks.pop(node, None)
         if self._mx is not None:
             self._mx.drop_node(node)

@@ -448,3 +448,61 @@ def test_simconfig_refuses_removed_switches(key):
     from repro.sim import SimConfig
     with pytest.raises(TypeError, match=key):
         SimConfig(**{key: False})
+
+
+# ------------------------------------- free-COP-slot transitions: the walk
+def _testbed_stream(reference_core=False):
+    """The paper's 8-node testbed (one COP per node, Ceph) under a short
+    stream of the four Table I workflows."""
+    from repro.sim import SimConfig, Simulation, TenantSpec, TrafficConfig
+    tenants = tuple(TenantSpec(wf, workflows=(wf,), scale=0.1, slo=1e9)
+                    for wf in ("rnaseq", "sarek", "chipseq", "rangeland"))
+    traffic = TrafficConfig(tenants=tenants, rate=0.002, n_arrivals=8,
+                            max_backlog=3, seed=11)
+    cfg = SimConfig(n_nodes=8, c_node=1, dfs="ceph",
+                    reference_core=reference_core)
+    return Simulation(None, cfg, "wow", traffic=traffic)
+
+
+def test_slot_transition_walks_exactly_the_waited_files_on_the_node():
+    """Every free-COP-slot transition of a testbed run walks the files that
+    some tracked task waits on and that have a replica on the node -- no
+    other file the node holds."""
+    sim = _testbed_stream()
+    dps = sim.strategy.sched.dps
+    seen = {"transitions": 0, "walked": 0, "held": 0}
+
+    def checked(note):
+        def run(node):
+            waited = {f for inputs in dps._task_inputs.values()
+                      for f in inputs if node in dps.locations(f)}
+            held = sum(1 for f in dps.file_ids()
+                       if node in dps.locations(f))
+            before = (dps.source_transitions, dps.source_files_walked)
+            note(node)
+            steps = dps.source_transitions - before[0]
+            walked = dps.source_files_walked - before[1]
+            if steps:
+                assert (steps, walked) == (1, len(waited)), node
+                seen["transitions"] += 1
+                seen["walked"] += walked
+                seen["held"] += held
+        return run
+    dps.note_source_freed = checked(dps.note_source_freed)
+    dps.note_source_busy = checked(dps.note_source_busy)
+    sim.run()
+    assert seen["transitions"] == dps.source_transitions > 100
+    assert seen["walked"] == dps.source_files_walked
+    # the node holds far more files than its waited ones
+    assert 0 < seen["walked"] < seen["held"] / 2, seen
+
+
+def test_testbed_stream_decisions_match_reference():
+    """The same testbed run makes the frozen reference's decisions."""
+    runs = {}
+    for ref in (False, True):
+        sim = _testbed_stream(reference_core=ref)
+        res = sim.run()
+        runs[ref] = (sim.action_log, repr(res.makespan),
+                     sim.traffic_result())
+    assert runs[False] == runs[True]

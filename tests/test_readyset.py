@@ -131,11 +131,22 @@ def test_readyset_orders_match_oracle(seed):
 
 # --------------------------------------------- DPS source-feasibility index
 def _check_source_index(dps, free):
-    """`_free_rep`/`_unsourced` must equal brute-force recomputation, and
-    `cop_blocked` must imply an empty feasible-target pool."""
-    for f in dps.file_ids():
+    """`_free_rep` (kept for waited files only), `_node_wfiles` and
+    `_unsourced` must equal brute-force recomputation, and `cop_blocked`
+    must imply an empty feasible-target pool."""
+    waited = {f for inputs in dps._task_inputs.values() for f in inputs}
+    assert set(dps._waiting) == waited
+    for f in waited:
         expect = sum(1 for n in dps.locations(f) if n in free)
         assert dps._free_rep.get(f, 0) == expect, f"free_rep[{f}]"
+    assert set(dps._free_rep) <= waited, "count kept for an unwaited file"
+    assert all(c > 0 for c in dps._free_rep.values())
+    want_wfiles = {}
+    for f in waited:
+        for n in dps.locations(f):
+            want_wfiles.setdefault(n, set()).add(f)
+    got_wfiles = {n: fs for n, fs in dps._node_wfiles.items() if fs}
+    assert got_wfiles == want_wfiles, "node_wfiles"
     for tid, inputs in dps._task_inputs.items():
         expect = sum(1 for f in set(inputs)
                      if not (dps.locations(f) & free))
@@ -172,12 +183,15 @@ def test_dps_source_feasibility_index_matches_bruteforce(seed):
         elif op == 2:
             dps.drop_node(node)
         elif op == 3:                       # slot transition
+            walked = dps.source_files_walked
             if node in free:
                 free.discard(node)
                 dps.note_source_busy(node)
             else:
                 free.add(node)
                 dps.note_source_freed(node)
+            assert dps.source_files_walked - walked == len(
+                _waited_on(dps, node))
         elif op == 4 and tracked:
             tid = rng.choice(list(tracked))
             plan = dps.plan_cop(tid, tracked[tid], target=node,
@@ -198,6 +212,136 @@ def test_dps_source_feasibility_index_matches_bruteforce(seed):
             dps.register_file(FileSpec(id=fid, size=rng.randint(1, 100),
                                        producer=-1), node)
         _check_source_index(dps, free)
+
+
+def _waited_on(dps, node):
+    """Brute force: files some tracked task waits on with a replica on
+    ``node``."""
+    return {f for inputs in dps._task_inputs.values() for f in inputs
+            if node in dps.locations(f)}
+
+
+def _source_dps(files, free, n_nodes=4):
+    """1-byte files ``{id: holders}`` on ``n_nodes`` nodes, with the
+    source-feasibility index synced to ``free``; returns the DPS and the
+    mirrored free set, which the caller keeps in step."""
+    dps = DataPlacementService(seed=0)
+    for fid, holders in files.items():
+        dps.register_file(FileSpec(id=fid, size=1, producer=-1), holders[0])
+        for n in holders[1:]:
+            dps.add_replica(fid, n)
+    free = set(free)
+    dps.sync_free_sources(free)
+    _check_source_index(dps, free)
+    return dps, free
+
+
+def _flip(dps, free, node):
+    """One slot transition, mirrored as the scheduler does; returns the
+    files it walked."""
+    walked = dps.source_files_walked
+    if node in free:
+        free.discard(node)
+        dps.note_source_busy(node)
+    else:
+        free.add(node)
+        dps.note_source_freed(node)
+    _check_source_index(dps, free)
+    return dps.source_files_walked - walked
+
+
+def test_first_waiter_counts_free_and_busy_holders():
+    """File 0 sits on free nodes 0 and 2 and on busy node 1; file 1 only on
+    busy node 1.  The first waiter finds file 0's count at 2 and file 1
+    with none, and later slot flips walk only the waited files."""
+    dps, free = _source_dps({0: [0, 1, 2], 1: [1], 2: [0]}, {0, 2, 3})
+    assert dps._free_rep == {} and dps.source_transitions == 0
+    dps.track_task(7, (0, 1))
+    _check_source_index(dps, free)
+    assert dps._free_rep == {0: 2}
+    assert dps._node_wfiles[1] == {0, 1} and dps._node_wfiles[0] == {0}
+    assert dps._unsourced[7] == 1 and dps.cop_blocked(7)
+    assert _flip(dps, free, 1) == 2             # node 1 frees: files 0, 1
+    assert dps._free_rep == {0: 3, 1: 1} and not dps.cop_blocked(7)
+    assert _flip(dps, free, 0) == 1             # file 2 is not waited
+    assert _flip(dps, free, 2) == 1
+    assert dps._free_rep == {0: 1, 1: 1}
+    assert dps.source_transitions == 3
+
+
+def test_last_waiter_leaves_and_a_new_one_sees_unseen_flips():
+    """File 0 loses its last waiter, then slots flip on both of its holders
+    unseen: a new waiter's count is computed from the locations alone."""
+    dps, free = _source_dps({0: [0, 1]}, {0, 1})
+    dps.track_task(1, (0,))
+    dps.track_task(2, (0,))
+    assert dps._free_rep == {0: 2}
+    dps.untrack_task(1)
+    assert dps._free_rep == {0: 2}              # task 2 still waits
+    dps.untrack_task(2)
+    _check_source_index(dps, free)
+    assert dps._free_rep == {} and not any(dps._node_wfiles.values())
+    for node in (0, 1, 0):                      # busy 0, busy 1, free 0
+        assert _flip(dps, free, node) == 0
+    dps.track_task(3, (0,))
+    _check_source_index(dps, free)
+    assert dps._free_rep == {0: 1} and not dps.cop_blocked(3)
+    assert _flip(dps, free, 0) == 1
+    assert dps.cop_blocked(3)
+
+
+def test_unwaited_replica_writes_on_a_free_node_keep_no_count():
+    """Adding or removing a replica of an unwaited file on a free node
+    touches no count; the same write to a waited file does."""
+    dps, free = _source_dps({0: [0], 1: [0]}, {0, 1})
+    dps.track_task(5, (1,))
+    dps.add_replica(0, 1)
+    _check_source_index(dps, free)
+    assert 0 not in dps._free_rep and 1 not in dps._node_wfiles
+    dps.add_replica(1, 1)
+    _check_source_index(dps, free)
+    assert dps._free_rep[1] == 2 and 1 in dps._node_wfiles[1]
+    dps.remove_replica(0, 0)
+    dps.remove_replica(1, 0)
+    _check_source_index(dps, free)
+    assert dps._free_rep == {1: 1}
+    dps.track_task(6, (0,))                     # only node 1 holds file 0
+    _check_source_index(dps, free)
+    assert dps._free_rep == {0: 1, 1: 1}
+
+
+def test_sync_after_tracking_indexes_the_waited_files():
+    """Activating the index over tasks tracked before it counts their files
+    and no others."""
+    dps = DataPlacementService(seed=0)
+    for fid, node in ((0, 0), (1, 1), (2, 1)):
+        dps.register_file(FileSpec(id=fid, size=1, producer=-1), node)
+    dps.track_task(4, (0, 1))
+    free = {1, 2}
+    dps.sync_free_sources(free)
+    _check_source_index(dps, free)
+    assert dps._free_rep == {1: 1}
+    assert dps._node_wfiles == {0: {0}, 1: {1}}
+    assert dps.cop_blocked(4)
+    assert _flip(dps, free, 0) == 1 and not dps.cop_blocked(4)
+
+
+def test_drop_node_with_waited_and_unwaited_files():
+    """Node 0 holds waited files 0 (also on node 1) and 2 (its only copy)
+    and unwaited file 1.  Dropping it, then marking its slot busy as the
+    scheduler does, leaves file 0 sourced from node 1 and task 9 blocked
+    on the lost file 2."""
+    dps, free = _source_dps({0: [0, 1], 1: [0, 2], 2: [0]}, {0, 1, 2})
+    dps.track_task(8, (0,))
+    dps.track_task(9, (0, 2))
+    assert dps._free_rep == {0: 2, 2: 1}
+    assert dps.drop_node(0) == [2]
+    _check_source_index(dps, free)
+    assert 0 not in dps._node_wfiles
+    assert dps._free_rep == {0: 1}
+    assert _flip(dps, free, 0) == 0
+    assert not dps.cop_blocked(8) and dps.cop_blocked(9)
+    assert dps._node_wfiles == {1: {0}}
 
 
 # -------------------------------------- scheduler visit orders vs snapshot

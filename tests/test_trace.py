@@ -130,6 +130,8 @@ def test_on_one_row_per_round_and_counters_add_up(trace_off):
         "drain.tasks_probed": sched.drain_probed,
         "drain.probes_skipped": sched.drain_skipped,
         "dps.replica_writes": sched.dps.replica_writes,
+        "dps.source_transitions": sched.dps.source_transitions,
+        "dps.source_files_walked": sched.dps.source_files_walked,
         "sim.task_starts": sim.task_starts,
         "sim.cops_scanned": sim.cops_scanned,
         "sim.cops_indexed": sim.cops_indexed,
